@@ -9,8 +9,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def setup_devices():
-    """Honor MMLSPARK_TPU_EXAMPLE_CPU=1 -> virtual 8-device CPU mesh."""
-    if os.environ.get("MMLSPARK_TPU_EXAMPLE_CPU") == "1":
+    """``JAX_PLATFORMS=cpu`` -> the virtual 8-device CPU mesh the tests
+    use; otherwise whatever platform JAX finds."""
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
         from mmlspark_tpu.parallel.topology import use_cpu_devices
         use_cpu_devices(8)
     import jax

@@ -32,7 +32,7 @@ def main():
           f"hash {meta.hash[:12]}...)")
 
     # full 10k on a real chip; a smaller draw on the CPU test mesh
-    n = 2048 if os.environ.get("MMLSPARK_TPU_EXAMPLE_CPU") else 10_240
+    n = 2048 if devices[0].platform == "cpu" else 10_240
     images, labels = synth_cifar(n, seed=123_456)   # fresh draw
     df = DataFrame({"image": images})
     scorer = NNModel(model=fn, input_col="image", output_col="scores",

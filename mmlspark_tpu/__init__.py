@@ -16,11 +16,13 @@ from mmlspark_tpu.version import __version__
 
 from mmlspark_tpu.core.dataframe import DataFrame
 from mmlspark_tpu.core.environment import (
-    accelerator_count, describe, environment_info,
+    accelerator_count, describe, environment_info, place_compile_cache,
 )
 from mmlspark_tpu.core.params import Param
 from mmlspark_tpu.core.stage import Transformer, Estimator, Model, Evaluator, PipelineStage
 from mmlspark_tpu.core.pipeline import Pipeline, PipelineModel
+
+place_compile_cache()
 
 __all__ = [
     "__version__",

@@ -47,8 +47,8 @@ def _file_layer() -> Dict[str, Dict[str, Any]]:
 
 def _env_layer(namespace: str) -> Dict[str, Any]:
     if namespace.upper() in _RESERVED:
-        # framework control variables (MMLSPARK_TPU_NATIVE_DIR,
-        # MMLSPARK_TPU_TEST_TPU, ...) are not user config
+        # framework control variables (MMLSPARK_TPU_NATIVE_DIR, ...)
+        # are not user config
         return {}
     prefix = _ENV_PREFIX + namespace.upper() + "_"
     out: Dict[str, Any] = {}

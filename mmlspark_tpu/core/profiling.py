@@ -292,19 +292,6 @@ class CompileLedger:
                     "events": list(self._events)}
 
 
-#: peak dense bf16 TFLOP/s per chip, by ``device_kind`` — the MFU
-#: denominator (same table as ``bench.py``; unknown kinds report
-#: flops/s without a utilization ratio)
-_PEAK_BF16_TFLOPS: Dict[str, float] = {
-    "TPU v4": 275.0,
-    "TPU v5 lite": 197.0,
-    "TPU v5e": 197.0,
-    "TPU v5p": 459.0,
-    "TPU v6 lite": 918.0,
-    "TPU v6e": 918.0,
-}
-
-
 class MfuMeter:
     """Always-on per-bucket MFU estimation.
 
@@ -325,17 +312,16 @@ class MfuMeter:
             peak_tflops * 1e12 if peak_tflops is not None else None)
         self.device_kind: Optional[str] = None
         if peak_tflops is None:
-            try:
-                from mmlspark_tpu.core.environment import (
-                    environment_info,
-                )
-                kind = environment_info().get("device_kind")
-                self.device_kind = kind
-                peak = _PEAK_BF16_TFLOPS.get(str(kind))
-                if peak is not None:
-                    self.peak_flops = peak * 1e12
-            except Exception:  # noqa: BLE001 — accounting is optional
-                pass
+            # the chip's published peak (None on a CPU host: flops/s
+            # without a ratio); an accelerator kind the table does not
+            # know raises — no MFU against a guessed denominator
+            import jax
+            from mmlspark_tpu.core.environment import device_peaks
+            dev = jax.devices()[0]
+            self.device_kind = dev.device_kind
+            peaks = device_peaks(dev.device_kind, dev.platform)
+            if peaks is not None:
+                self.peak_flops = peaks["bf16_tflops"] * 1e12
         self._buckets: Dict[object, Dict[str, float]] = {}
 
     def note(self, bucket: object, seconds: float,

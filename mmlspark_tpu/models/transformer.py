@@ -275,20 +275,27 @@ def _attention(bp, x, cfg: TransformerConfig, ax: _Axes, pos):
     k = jnp.einsum("bsd,dhk->bshk", h, bp["wk"].astype(dt)).astype(jnp.float32)
     v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"].astype(dt)).astype(jnp.float32)
     q, k = _rope(q, pos), _rope(k, pos)
+    if cfg.attention_impl not in ("auto", "dense", "folded", "flash"):
+        raise ValueError(
+            f"unknown attention_impl {cfg.attention_impl!r}")
     if ax.seq:
         # auto_train: the ring module's shared policy resolves to the
         # differentiable folded kernel where it pays off (never the
         # forward-only flash), dense otherwise
+        if cfg.attention_impl == "flash":
+            raise ValueError(
+                "attention_impl='flash' cannot train under a 'seq' "
+                "axis: the ring's flash block kernel is forward-only "
+                "— name 'folded' or 'dense', or leave 'auto'")
         ring_impl = ("auto_train" if cfg.attention_impl == "auto"
-                     else "folded" if cfg.attention_impl == "folded"
-                     else "dense")
+                     else cfg.attention_impl)
         a = ring_attention_local(q, k, v, ax.seq, causal=True,
                                  compute_dtype=mm_dt,
                                  block_impl=ring_impl)
     else:
         from mmlspark_tpu.parallel.pallas_attention import (
-            flash_attention, flash_attention_folded, flash_available,
-            folded_available)
+            _folded_shape_ok, flash_attention, flash_attention_folded,
+            flash_available, folded_available)
         b_, s_, h_, dh_ = q.shape
         impl = cfg.attention_impl
         if impl == "auto":
@@ -306,35 +313,24 @@ def _attention(bp, x, cfg: TransformerConfig, ax: _Axes, pos):
                 impl = "flash"
             else:
                 impl = "dense"
+        elif impl == "folded" and not _folded_shape_ok(s_, s_, dh_, h_):
+            # only "auto" may pick another engine: a NAMED engine that
+            # cannot take the shape raises (and one the platform cannot
+            # run fails in the Pallas lowering) — a run never reports
+            # one engine's name over another engine's numbers
+            raise ValueError(
+                f"attention_impl='folded' cannot take shape (S={s_}, "
+                f"head_dim={dh_}, H*Dh={h_ * dh_}): it needs head_dim "
+                f"% 8 == 0, a 128-tileable S, and H*Dh inside the "
+                f"folded VMEM budget — name 'flash' or 'dense', or "
+                f"leave 'auto'")
         if impl in ("folded", "flash") and mm_dt is not None:
             q, k, v = q.astype(dt), k.astype(dt), v.astype(dt)
-        if impl == "folded" and folded_available(s_, s_, dh_, h_):
+        if impl == "folded":
             a = flash_attention_folded(q, k, v, True)
-        elif impl in ("flash", "folded") and flash_available():
-            if cfg.attention_impl == "folded":
-                # the user named a specific engine and is getting a
-                # different one — say so (silent fallback is reserved
-                # for 'auto'); folded needs head_dim % 8 == 0, a
-                # 128-tileable sequence, AND an (H*Dh x tile) working
-                # set inside the VMEM budget (r4 advisor)
-                import warnings
-                warnings.warn(
-                    f"attention_impl='folded' ineligible at shape "
-                    f"(S={s_}, head_dim={dh_}, H*Dh={h_ * dh_}) — needs "
-                    f"head_dim % 8 == 0, 128-tileable S, and H*Dh "
-                    f"within the folded VMEM budget; falling back to "
-                    f"the lane-padded flash kernel", stacklevel=2)
+        elif impl == "flash":
             a = flash_attention(q, k, v, True)
         else:
-            if cfg.attention_impl in ("folded", "flash"):
-                import warnings
-                warnings.warn(
-                    f"attention_impl={cfg.attention_impl!r} unavailable "
-                    f"(backend {jax.default_backend()!r}, S={s_}, "
-                    f"head_dim={dh_}, H*Dh={h_ * dh_} — needs a TPU "
-                    f"backend and, for 'folded', an eligible "
-                    f"shape/VMEM envelope); using dense attention",
-                    stacklevel=2)
             a = dense_attention(q, k, v, causal=True, compute_dtype=mm_dt)
     o = jnp.einsum("bshk,hkd->bsd", a.astype(dt),
                    bp["wo"].astype(dt)).astype(jnp.float32)
@@ -979,20 +975,17 @@ def build_spmd_train_step(cfg: TransformerConfig, mesh,
     Two interchangeable formulations exist (``impl``):
 
     * ``"shard_map"`` — the manual per-device program (explicit
-      psum/ppermute/all_to_all; maps 1:1 onto ICI). Needs the VMA-era
-      jax: its backward relies on vma types to insert the
-      replicated-parameter grad psums.
+      psum/ppermute/all_to_all; maps 1:1 onto ICI); its backward
+      relies on vma types to insert the replicated-parameter grad
+      psums. ``"auto"`` is this one.
     * ``"pjit"`` — the same math as ONE global GSPMD program
       (:func:`build_pjit_train_step`): XLA inserts every collective
-      from the ``NamedSharding`` annotations, so it runs on pre-VMA
-      jaxes too. Fixed-seed parity between the two is test-pinned
-      wherever a VMA jax exists.
+      from the ``NamedSharding`` annotations. Fixed-seed parity
+      between the two is test-pinned; choosing one is ROADMAP C1.
 
-    ``"auto"`` picks shard_map on a VMA jax and pjit elsewhere —
-    which is what deleted the old loud pre-VMA build failure.
-    ``check_vma=False`` (test-only; see the warning below) always
-    takes the shard_map path: its documented under-reduction boundary
-    is itself pinned by tests.
+    ``check_vma=False`` is test-only (see the warning below) and
+    belongs to the shard_map path, whose documented under-reduction
+    boundary is itself pinned by tests.
 
     Returns ``step(params, velocity, tokens, labels, mask) ->
     (params, velocity, loss)`` where params/velocity are device arrays
@@ -1013,13 +1006,6 @@ def build_spmd_train_step(cfg: TransformerConfig, mesh,
 
     if impl not in ("auto", "shard_map", "pjit"):
         raise ValueError(f"unknown train-step impl {impl!r}")
-    if impl == "auto":
-        from mmlspark_tpu.parallel import compat
-        # check_vma=False is a shard_map-specific contract (the
-        # interpret-mode escape hatch + the documented under-reduction
-        # boundary) — it must keep meaning the manual path
-        impl = ("shard_map" if not check_vma or compat.vma_native()
-                else "pjit")
     if impl == "pjit":
         return build_pjit_train_step(cfg, mesh, learning_rate, momentum,
                                      donate=donate)
@@ -1062,9 +1048,7 @@ def build_spmd_train_step(cfg: TransformerConfig, mesh,
 # explicitly; this one expresses NONE: the same math is written over
 # the global arrays, params/batch arrive with NamedSharding layouts
 # (the identical `param_specs` tree), and XLA/GSPMD inserts the grad
-# allreduces and TP/EP collectives from the annotations. Because no
-# vma typing is involved, it builds and runs on pre-VMA jaxes — the
-# trainer path no longer has a jax-version boundary. The one semantic
+# allreduces and TP/EP collectives from the annotations. The one semantic
 # subtlety is capacity-factor MoE: the manual step computes its
 # capacity C and drops overflow *per rank's token shard*, so the
 # global formulation reproduces that grouping exactly (tokens split
@@ -1093,7 +1077,7 @@ def _ungroup_tokens(g, D: int, Q: int, B: int, S: int):
 
 
 def _pjit_moe_grouped(bp, x, cfg: TransformerConfig, D: int, Q: int,
-                      E_ax: int, wsc=None):
+                      E_ax: int):
     """Capacity-factor token-choice MoE, group-wise: the global twin of
     :func:`_moe_capacity`. Each of the ``D*Q*E_ax`` token groups
     builds its own capacity queues (same engines, same overflow
@@ -1102,14 +1086,6 @@ def _pjit_moe_grouped(bp, x, cfg: TransformerConfig, D: int, Q: int,
     import math
     dt = _compute_dtype(cfg)
     h = _rmsnorm(x, bp["ln2"])
-    # jax-0.4.x XLA:CPU SPMD mis-lowers the grouped top-k/queue/
-    # scatter chains when their operands carry mesh shardings
-    # (repro'd: 1e-3..3e-2 divergence vs the identical eager math on
-    # data x expert meshes) — this fallback formulation therefore pins
-    # the whole capacity/EC block replicated: forward AND backward
-    # then match the unsharded golden exactly. The manual shard_map
-    # formulation keeps the truly-parallel dispatch.
-    h = wsc(h) if wsc is not None else h
     logits = jnp.einsum("bsd,de->bse", h, bp["router"])
     probs = jax.nn.softmax(logits, axis=-1)
     B, S, d = x.shape
@@ -1130,8 +1106,7 @@ def _pjit_moe_grouped(bp, x, cfg: TransformerConfig, D: int, Q: int,
     pg = _token_groups(probs, D, Q)            # [n_rank, T_local, E]
     engine = (_sorted_capacity_queues if cfg.moe_dispatch == "sort"
               else _scatter_capacity_queues)
-    ew1 = wsc(bp["ew1"]) if wsc is not None else bp["ew1"]
-    ew2 = wsc(bp["ew2"]) if wsc is not None else bp["ew2"]
+    ew1, ew2 = bp["ew1"], bp["ew2"]
     if cfg.moe_dispatch not in ("sort", "scatter"):
         raise ValueError(f"unknown moe_dispatch {cfg.moe_dispatch!r}")
     out_groups = []
@@ -1154,7 +1129,6 @@ def _pjit_moe_grouped(bp, x, cfg: TransformerConfig, D: int, Q: int,
         out_groups.append(jnp.concatenate(parts, axis=0))
     ytok = jnp.stack(out_groups)               # [n_rank, T_local, d]
     y = _ungroup_tokens(ytok, D, Q, B, S)
-    y = wsc(y) if wsc is not None else y       # exit the block replicated
     # aux statistics are token-LINEAR, so the global means equal the
     # manual step's pmean-over-token-axes exactly (equal-size groups)
     E_ = cfg.n_experts
@@ -1170,15 +1144,13 @@ def _pjit_moe_grouped(bp, x, cfg: TransformerConfig, D: int, Q: int,
 
 
 def _pjit_moe_expert_choice(bp, x, cfg: TransformerConfig, D: int,
-                            Q: int, E_ax: int, wsc=None):
+                            Q: int, E_ax: int):
     """Expert-choice routing, group-wise: the global twin of
     :func:`_moe_expert_choice` (experts pick their top-C tokens WITHIN
     each rank-shaped token group)."""
     import math
     dt = _compute_dtype(cfg)
     h = _rmsnorm(x, bp["ln2"])
-    # same SPMD-lowering pin as the capacity path (see above)
-    h = wsc(h) if wsc is not None else h
     logits = jnp.einsum("bsd,de->bse", h, bp["router"])
     probs = jax.nn.softmax(logits, axis=-1)
     B, S, d = x.shape
@@ -1193,9 +1165,7 @@ def _pjit_moe_expert_choice(bp, x, cfg: TransformerConfig, D: int,
     C = max(int(math.ceil(cfg.moe_capacity_factor * T_sh / E)), 1)
     hg = _token_groups(h, D, Q).reshape(n_rank * E_ax, T_sh, d)
     pg = _token_groups(probs, D, Q).reshape(n_rank * E_ax, T_sh, E)
-    # same SPMD-lowering pin as the capacity path (see above)
-    ew1 = wsc(bp["ew1"]) if wsc is not None else bp["ew1"]
-    ew2 = wsc(bp["ew2"]) if wsc is not None else bp["ew2"]
+    ew1, ew2 = bp["ew1"], bp["ew2"]
     outs = []
     for g in range(n_rank * E_ax):
         wts, idx = jax.lax.top_k(pg[g].T, min(C, T_sh))  # (E, C)
@@ -1209,7 +1179,6 @@ def _pjit_moe_expert_choice(bp, x, cfg: TransformerConfig, D: int,
                     .add(y.reshape(-1, d) * wts.reshape(-1)[:, None]))
     ytok = jnp.stack(outs).reshape(n_rank, T_local, d)
     y = _ungroup_tokens(ytok, D, Q, B, S)
-    y = wsc(y) if wsc is not None else y       # exit the block replicated
     E_ = cfg.n_experts
     stats = (jnp.zeros(E_, jnp.float32), jnp.zeros(E_, jnp.float32))
     z_stat = jnp.float32(0.0)
@@ -1249,18 +1218,17 @@ def _pjit_moe_dense(bp, x, cfg: TransformerConfig):
     return y, (*f_stat, z_stat)
 
 
-def _pjit_moe(bp, x, cfg: TransformerConfig, D: int, Q: int, E_ax: int,
-              wsc=None):
+def _pjit_moe(bp, x, cfg: TransformerConfig, D: int, Q: int, E_ax: int):
     """MoE branch selection mirroring :func:`_moe`, global form."""
     if cfg.moe_router == "expert_choice":
         if cfg.moe_capacity_factor <= 0:
             raise ValueError("moe_router='expert_choice' needs "
                              "moe_capacity_factor > 0 (defines C)")
-        return _pjit_moe_expert_choice(bp, x, cfg, D, Q, E_ax, wsc)
+        return _pjit_moe_expert_choice(bp, x, cfg, D, Q, E_ax)
     if cfg.moe_router != "token":
         raise ValueError(f"unknown moe_router {cfg.moe_router!r}")
     if cfg.moe_capacity_factor > 0:
-        return _pjit_moe_grouped(bp, x, cfg, D, Q, E_ax, wsc)
+        return _pjit_moe_grouped(bp, x, cfg, D, Q, E_ax)
     return _pjit_moe_dense(bp, x, cfg)
 
 
@@ -1282,7 +1250,7 @@ def _pjit_attention(bp, x, cfg: TransformerConfig, pos):
 
 
 def _pjit_loss(params, tokens, labels, mask, cfg: TransformerConfig,
-               groups: "Tuple[int, int, int]", ce_impl: str, wsc=None):
+               groups: "Tuple[int, int, int]", ce_impl: str):
     """The global-array loss: identical math to ``local_loss`` (same
     CE, same aux/z-loss formulas, group-faithful capacity dispatch)
     with the pipeline schedule flattened to a sequential stage loop —
@@ -1299,7 +1267,7 @@ def _pjit_loss(params, tokens, labels, mask, cfg: TransformerConfig,
             bp = {k: v[s] for k, v in bp_all.items()}
             x = x + _pjit_attention(bp, x, cfg, pos)
             if cfg.n_experts:
-                y, (f, P_, z) = _pjit_moe(bp, x, cfg, D, Q, E_ax, wsc)
+                y, (f, P_, z) = _pjit_moe(bp, x, cfg, D, Q, E_ax)
                 x = x + y
                 if cfg.moe_aux_weight > 0:
                     aux_total = aux_total + cfg.n_experts * jnp.sum(f * P_)
@@ -1348,11 +1316,8 @@ def build_pjit_train_step(cfg: TransformerConfig, mesh,
     """The train step as ONE global GSPMD program (pjit): same
     signature, layouts (:func:`param_specs`), and math as the
     shard_map formulation — XLA inserts every collective from the
-    ``NamedSharding`` annotations, so this builds and runs on pre-VMA
-    jaxes (jax 0.4.x) where the manual step's replication checker
-    cannot. ``build_spmd_train_step(impl="auto")`` selects it there
-    automatically; fixed-seed parity between the formulations is
-    pinned in tests/test_transformer.py wherever a VMA jax exists.
+    ``NamedSharding`` annotations. Fixed-seed parity between the
+    formulations is pinned in tests/test_transformer.py.
 
     The Pallas attention/CE kernels are per-device programs: this
     formulation uses the XLA engines except on a single-device mesh,
@@ -1387,14 +1352,9 @@ def build_pjit_train_step(cfg: TransformerConfig, mesh,
             f"runs the kernel per shard)", stacklevel=2)
         ce_impl = "xla"
 
-    wsc = None
-    if n_dev > 1:
-        def wsc(t, _repl=repl):
-            return jax.lax.with_sharding_constraint(t, _repl)
-
     def step(params, velocity, tokens, labels, mask):
         loss, grads = jax.value_and_grad(_pjit_loss)(
-            params, tokens, labels, mask, cfg, groups, ce_impl, wsc)
+            params, tokens, labels, mask, cfg, groups, ce_impl)
         velocity = jax.tree.map(lambda v, g: momentum * v + g,
                                 velocity, grads)
         params = jax.tree.map(lambda p, v: p - learning_rate * v,
@@ -2195,8 +2155,8 @@ def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
             # page tables/positions replicated — per-shard head-slice
             # grids, no collective in either direction. check_vma is
             # irrelevant here (forward-only, nothing replicated is
-            # produced); False keeps interpret-mode parity tests
-            # runnable on pre-VMA jaxes.
+            # produced); False lets the interpret-mode parity tests
+            # run (see build_spmd_train_step on interpret + vma).
             tp_mesh = cache_sharding.mesh
 
     def _paged_attn(q, k_pool, v_pool, page_tables, pos):

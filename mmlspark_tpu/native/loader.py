@@ -9,14 +9,17 @@ C++ *sources* inside the package and compiles them on first use:
 search order for ``load_library_by_name(name)``:
 1. ``$MMLSPARK_TPU_NATIVE_DIR/lib<name>.so`` (operator-provided prebuilt,
    the ``java.library.path`` analogue),
-2. the package build cache (``native/_build``), rebuilt whenever the
-   source is newer than the cached binary,
+2. the package build cache (``native/_build``), keyed on a hash of
+   the sources and the compiler flags — a binary built from other
+   sources (a stale one copied along with a checkout, whatever its
+   mtime) has another name and is never loaded,
 3. fresh compile via ``g++`` (declared in ``_SOURCES``).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -32,6 +35,7 @@ _SOURCES: Dict[str, List[str]] = {
 _LINK_FLAGS: Dict[str, List[str]] = {
     "mmlbinary": ["-lz"],
 }
+_COMPILE_FLAGS = ["-O2", "-std=c++17", "-shared", "-fPIC", "-pthread"]
 
 _lock = threading.Lock()
 # name -> CDLL, or the Exception a previous attempt raised (negative cache:
@@ -69,17 +73,21 @@ def _find_or_build(name: str) -> str:
     if name not in _SOURCES:
         raise FileNotFoundError(f"unknown native library {name!r}")
     sources = [os.path.join(_SRC_DIR, s) for s in _SOURCES[name]]
-    built = os.path.join(_BUILD_DIR, so_name)
-    if os.path.exists(built) and all(
-            os.path.getmtime(built) >= os.path.getmtime(s) for s in sources):
+    link_flags = _LINK_FLAGS.get(name, [])
+    key = hashlib.sha256("\0".join(_COMPILE_FLAGS + link_flags).encode())
+    for src in sources:
+        with open(src, "rb") as f:
+            key.update(f.read())
+    built = os.path.join(_BUILD_DIR,
+                         f"lib{name}.{key.hexdigest()[:16]}.so")
+    if os.path.exists(built):
         return built
     os.makedirs(_BUILD_DIR, exist_ok=True)
     # compile to a private temp name, then atomically publish: concurrent
     # builders (pytest-xdist, two cold-starting services) must never see
     # a half-written .so
     tmp = f"{built}.tmp.{os.getpid()}"
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           *sources, "-o", tmp, *_LINK_FLAGS.get(name, [])]
+    cmd = ["g++", *_COMPILE_FLAGS, *sources, "-o", tmp, *link_flags]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         try:

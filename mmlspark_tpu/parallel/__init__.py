@@ -1,4 +1,3 @@
-from mmlspark_tpu.parallel import compat as _compat  # jax.shard_map shim
 from mmlspark_tpu.parallel.topology import (
     MeshSpec,
     build_mesh,

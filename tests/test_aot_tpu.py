@@ -1,0 +1,270 @@
+"""Ahead-of-time compilation for the v5e, with no chip: the check to run
+before any chip call.
+
+``jax.experimental.topologies.get_topology_desc("v5e:2x2", "tpu")``
+gives the installed libtpu's compiler a device description to target,
+so Mosaic really lowers every Pallas kernel and XLA:TPU really compiles
+every whole program — a kernel that overflows VMEM or a program that
+cannot be partitioned is rejected here, in the sandbox, instead of on
+billed chip time. Shapes are ``chip_smoke.py``'s (``FULL``); engines are
+NAMED, because ``"auto"`` reads the process's default backend (the CPU
+here) and a named engine does not.
+
+Compilation is not execution: what this cannot see is exactly what
+``chip_smoke.py`` exists for. Marked ``slow`` (minutes of XLA:TPU
+compile on the host); run it alone::
+
+    JAX_PLATFORMS=cpu python -m pytest tests/test_aot_tpu.py -m slow -q
+"""
+
+import dataclasses
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+import chip_smoke  # noqa: E402
+from mmlspark_tpu.models import transformer as T  # noqa: E402
+
+pytestmark = pytest.mark.slow
+
+FULL = chip_smoke.FULL
+NAMED = dataclasses.replace(FULL, attention_impl="folded",
+                            ce_impl="fused", attn_impl="pallas")
+PAGE = 16                                   # TransformerDecoder's default
+PAGES_PER_SLOT = FULL.max_len // PAGE
+N_PAGES = 1 + FULL.n_slots * PAGES_PER_SLOT
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # noqa: BLE001 — no libtpu in this install
+        pytest.skip(f"no TPU compiler to target: {e}")
+
+
+def _mesh(topo, shape: dict) -> Mesh:
+    n = int(np.prod(list(shape.values())))
+    return Mesh(np.array(topo.devices[:n]).reshape(tuple(shape.values())),
+                tuple(shape))
+
+
+def _abstract(tree, sharding):
+    """ShapeDtypeStructs of ``tree`` placed by ``sharding`` (one
+    sharding, or a matching tree of them)."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _n_mosaic(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+# ---------------------------------------------------------------------------
+# each Pallas kernel family, alone, on one device
+
+
+def _compile_on_one(topo, fn, *shapes):
+    one = NamedSharding(_mesh(topo, {"x": 1}), P())
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+class TestKernels:
+    B, S, H, DH = FULL.batch, FULL.seq, FULL.n_heads, FULL.d_head
+
+    @pytest.mark.parametrize("family", ["folded", "flash"])
+    def test_training_attention_fwd_bwd(self, topo, family):
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        attn = (PA.flash_attention_folded if family == "folded"
+                else functools.partial(PA.flash_attention,
+                                       bwd_impl="pallas"))
+        qkv = ((self.B, self.S, self.H, self.DH), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return jnp.sum(attn(q, k, v, True).astype(jnp.float32))
+
+        c = _compile_on_one(topo, jax.grad(loss, argnums=(0, 1, 2)),
+                            qkv, qkv, qkv)
+        assert _n_mosaic(c) == 3            # fwd, dq, dkv
+
+    def test_fused_ce_fwd_bwd(self, topo):
+        from mmlspark_tpu.ops.fused_ce import fused_softmax_xent
+        t = FULL.batch * FULL.seq
+
+        def loss(h, w, lbl):
+            return jnp.sum(fused_softmax_xent(
+                h, w, lbl, compute_dtype=jnp.bfloat16))
+
+        c = _compile_on_one(
+            topo, jax.grad(loss, argnums=(0, 1)),
+            ((t, FULL.d_model), jnp.float32),
+            ((FULL.d_model, FULL.vocab), jnp.float32), ((t,), jnp.int32))
+        assert _n_mosaic(c) == 3            # fwd, dh, dW
+
+    def test_paged_decode_attention(self, topo):
+        from mmlspark_tpu.parallel.pallas_attention import (
+            paged_decode_attention)
+        pool = ((N_PAGES, PAGE, self.H, self.DH), jnp.float32)
+        c = _compile_on_one(
+            topo, functools.partial(paged_decode_attention,
+                                    scale=self.DH ** -0.5, page_size=PAGE),
+            ((FULL.n_slots, self.H, self.DH), jnp.float32), pool, pool,
+            ((FULL.n_slots, PAGES_PER_SLOT), jnp.int32),
+            ((FULL.n_slots,), jnp.int32))
+        assert _n_mosaic(c) == 1
+
+    @pytest.mark.parametrize("s", [8, 128, FULL.max_len])
+    def test_flash_prefill_attention(self, topo, s):
+        from mmlspark_tpu.parallel.pallas_attention import (
+            flash_prefill_attention)
+        qkv = ((1, s, self.H, self.DH), jnp.float32)
+        c = _compile_on_one(topo, flash_prefill_attention, qkv, qkv, qkv)
+        assert _n_mosaic(c) == 1
+
+    @pytest.mark.parametrize("s", [1, 16, 128, FULL.max_len])
+    def test_paged_prefix_prefill_attention(self, topo, s):
+        from mmlspark_tpu.parallel.pallas_attention import (
+            paged_prefix_prefill_attention)
+        pool = ((N_PAGES, PAGE, self.H, self.DH), jnp.float32)
+        c = _compile_on_one(
+            topo, functools.partial(paged_prefix_prefill_attention,
+                                    scale=self.DH ** -0.5, page_size=PAGE),
+            ((s, self.H, self.DH), jnp.float32), pool, pool,
+            ((PAGES_PER_SLOT,), jnp.int32), ((), jnp.int32))
+        assert _n_mosaic(c) == 1
+
+    def test_gbdt_histogram(self, topo):
+        from mmlspark_tpu.gbdt import pallas_hist as PH
+        n, f = FULL.fit_rows, FULL.fit_features
+        n_pad = PH._round_up(n, PH.ROW_TILE)
+        f_pad = PH._round_up(f, PH.F_TILE)
+        c = _compile_on_one(
+            topo, functools.partial(PH.build_histogram_pallas,
+                                    n_features=f, n_bins=256),
+            ((f_pad, n_pad), jnp.int32), ((n,), jnp.float32),
+            ((n,), jnp.float32), ((n,), jnp.bool_))
+        assert _n_mosaic(c) == 1
+
+
+# ---------------------------------------------------------------------------
+# the smoke's whole programs
+
+
+def _train_compiled(topo, mesh_shape: dict, sz):
+    mesh = _mesh(topo, mesh_shape)
+    cfg = chip_smoke.transformer_config(sz, "bfloat16")
+    step = T.build_spmd_train_step(cfg, mesh, learning_rate=0.01)
+    shardings = jax.tree.map(
+        lambda s: NamedSharding(mesh, s), T.param_specs(cfg, mesh),
+        is_leaf=lambda s: isinstance(s, P))
+    params = _abstract(jax.eval_shape(lambda: T.init_params(cfg, 0)),
+                       shardings)
+    data = NamedSharding(mesh, P("data", None))
+    tok = jax.ShapeDtypeStruct((sz.batch, sz.seq), jnp.int32,
+                               sharding=data)
+    mask = jax.ShapeDtypeStruct((sz.batch, sz.seq), jnp.float32,
+                                sharding=data)
+    return step.lower(params, params, tok, tok, mask).compile()
+
+
+class TestTrainPrograms:
+    def test_one_chip_step_holds_the_kernels(self, topo):
+        """``train`` at full depth: the compiled text carries exactly
+        the Pallas calls ``chip_smoke.phase_train`` asserts."""
+        from mmlspark_tpu.ops import fused_ce
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        calls = chip_smoke._pallas_calls(
+            _train_compiled(topo, {"data": 1}, NAMED).as_text())
+
+        def n(fn):
+            return sum(c for name, c in calls.items()
+                       if f"jit({fn.__name__})" in name)
+
+        L = NAMED.n_layers
+        assert (n(PA._ffwd_call), n(PA._fbwd_call)) == (L, 2 * L), calls
+        assert (n(fused_ce._fwd_call), n(fused_ce._bwd_call)) == (1, 2), \
+            calls
+
+    def test_data2_model2_step(self, topo):
+        """``train4`` (depth cut to 2: every layer is the same
+        program text)."""
+        sz = dataclasses.replace(NAMED, n_layers=2)
+        c = _train_compiled(topo, {"data": 2, "model": 2}, sz)
+        assert _n_mosaic(c) == 2 * 3 + 3    # per layer fwd+2 bwd, CE 3
+
+
+def _decode_programs(topo, mesh_shape, sz):
+    """AOT-compile the decoder's program set — the step and every
+    bucket of both prefills, what ``warmup()`` compiles — the way
+    ``TransformerDecoder`` builds them."""
+    from mmlspark_tpu.parallel.sharding import bucket_ladder
+    cfg = chip_smoke.transformer_config(sz, "float32")
+    if mesh_shape is None:
+        mesh = _mesh(topo, {"x": 1})
+        repl = cache_sh = NamedSharding(mesh, P())
+        param_sh = repl
+        cache_sharding = None
+    else:
+        mesh = _mesh(topo, mesh_shape)
+        repl = NamedSharding(mesh, P())
+        cache_sharding = cache_sh = NamedSharding(
+            mesh, T.decode_cache_spec(mesh))
+        param_sh = jax.tree.map(
+            lambda s: NamedSharding(mesh, s),
+            T.decode_param_specs(cfg, mesh),
+            is_leaf=lambda s: isinstance(s, P))
+    params = _abstract(jax.eval_shape(lambda: T.init_params(cfg, 0)),
+                       param_sh)
+    cache = _abstract(jax.eval_shape(
+        lambda: T.init_paged_kv_cache(cfg, N_PAGES, PAGE)), cache_sh)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
+
+    kw = dict(cache_sharding=cache_sharding, attn_impl=sz.attn_impl)
+    prefill = T.build_paged_prefill(cfg, PAGE, PAGES_PER_SLOT, **kw)
+    prefix = T.build_paged_prefix_prefill(cfg, PAGE, PAGES_PER_SLOT, **kw)
+    step = T.build_paged_decode_step(cfg, sz.n_slots, PAGE,
+                                     PAGES_PER_SLOT, **kw)
+    out = {"step": step.lower(
+        params, cache, arg((sz.n_slots,)), arg((sz.n_slots,)),
+        arg((sz.n_slots, PAGES_PER_SLOT))).compile()}
+    for s in bucket_ladder(sz.max_len):
+        out[f"prefill_{s}"] = prefill.lower(
+            params, cache, arg((s,)), arg((PAGES_PER_SLOT,)),
+            arg(())).compile()
+        out[f"prefix_{s}"] = prefix.lower(
+            params, cache, arg((s,)), arg((PAGES_PER_SLOT,)), arg(()),
+            arg(())).compile()
+    return out
+
+
+class TestServePrograms:
+    """Depth cut to 2 (the layers repeat one program text); widths,
+    pool and buckets are the smoke's."""
+
+    SZ = dataclasses.replace(NAMED, n_layers=2)
+
+    @pytest.mark.parametrize("mesh_shape", [None, {"model": 4}],
+                             ids=["one_chip", "model4"])
+    def test_decoder_programs(self, topo, mesh_shape):
+        progs = _decode_programs(topo, mesh_shape, self.SZ)
+        assert len(progs) == 23             # what warmup() reports
+        for name, c in progs.items():
+            # one attention kernel per layer in every program
+            assert _n_mosaic(c) == self.SZ.n_layers, name

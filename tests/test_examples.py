@@ -25,7 +25,7 @@ EX_DIR = os.path.join(os.path.dirname(os.path.dirname(
 @pytest.mark.slow
 @pytest.mark.parametrize("script", EXAMPLES)
 def test_example_runs(script):
-    env = dict(os.environ, MMLSPARK_TPU_EXAMPLE_CPU="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run([sys.executable, os.path.join(EX_DIR, script)],
                           capture_output=True, text=True, env=env,
                           timeout=540)

@@ -123,6 +123,23 @@ class TestLoader:
             NativeLoader.load_library_by_name("no_such_lib")
 
     @needs_native
+    def test_build_is_keyed_on_sources_not_mtime(self, tmp_path,
+                                                 monkeypatch):
+        """A binary that merely SITS in the build dir (copied along
+        with a checkout, newer than the sources) is never loaded: the
+        cached name carries a hash of the sources and the flags."""
+        import ctypes
+        from mmlspark_tpu.native import loader
+        monkeypatch.setattr(loader, "_BUILD_DIR", str(tmp_path))
+        stale = tmp_path / "libmmlbinary.so"
+        stale.write_bytes(b"not built from these sources")
+        built = loader._find_or_build("mmlbinary")
+        assert built != str(stale)
+        assert os.path.dirname(built) == str(tmp_path)
+        assert ctypes.CDLL(built).mml_abi_version() == 1
+        assert loader._find_or_build("mmlbinary") == built   # by key
+
+    @needs_native
     def test_cached_handle_identity(self):
         from mmlspark_tpu.native.loader import NativeLoader
         a = NativeLoader.load_library_by_name("mmlbinary")

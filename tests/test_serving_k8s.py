@@ -133,7 +133,7 @@ class TestEntrypointFleet:
         return path
 
     def test_fleet_serves_and_fails_over(self, model_dir):
-        env_base = dict(os.environ, MMLSPARK_TPU_SERVING_CPU="1")
+        env_base = dict(os.environ, JAX_PLATFORMS="cpu")
         procs = []
         try:
             coord = subprocess.Popen(
@@ -190,7 +190,7 @@ class TestEntrypointFleet:
         must REPLAY (not re-execute) when the client retry lands on the
         restarted worker — the durable-journal path the k8s manifests
         enable via JOURNAL_PATH on a PVC mount."""
-        env_base = dict(os.environ, MMLSPARK_TPU_SERVING_CPU="1")
+        env_base = dict(os.environ, JAX_PLATFORMS="cpu")
         jpath = str(tmp_path / "journal" / "worker-0.jsonl")
 
         def spawn_worker():

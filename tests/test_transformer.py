@@ -590,23 +590,12 @@ class TestSpmdTrainStep:
 
 
 class TestPjitFormulation:
-    """The pjit (global GSPMD) train step — the formulation that runs
-    on pre-VMA jaxes (ISSUE 14). On THIS container's jax the whole
-    TestSpmdTrainStep suite above already exercises it via
-    ``impl="auto"``; these pin the selection contract itself."""
-
-    def test_explicit_pjit_impl_builds_anywhere(self):
-        cfg = T.TransformerConfig(**_DENSE, layers_per_stage=2)
-        mesh = submesh({"data": 2, "model": 2})
-        step = T.build_spmd_train_step(cfg, mesh, 0.1, 0.9, donate=False,
-                                       impl="pjit")
-        rng = np.random.default_rng(3)
-        tokens, labels, mask = T.make_batch(rng, cfg, 8, 16)
-        sp = T.shard_params(T.init_params(cfg, 0), cfg, mesh)
-        sv = T.shard_params(
-            jax.tree.map(jnp.zeros_like, T.init_params(cfg, 0)), cfg, mesh)
-        _, _, loss = step(sp, sv, tokens, labels, mask)
-        assert np.isfinite(float(loss))
+    """The pjit (global GSPMD) train step. ``impl="auto"`` is the
+    shard_map formulation, so TestSpmdTrainStep above never reaches
+    this one: these pin the selection contract and the parity of the
+    two formulations. (``check_vma=False`` belongs to the shard_map
+    path; its under-reduction boundary is pinned in
+    tests/test_fused_ce.py::test_check_vma_false_multishard_guard.)"""
 
     def test_unknown_impl_refused(self):
         cfg = T.TransformerConfig(**_DENSE)
@@ -614,38 +603,40 @@ class TestPjitFormulation:
             T.build_spmd_train_step(cfg, submesh({"data": 2}),
                                     impl="magic")
 
-    def test_check_vma_false_keeps_shard_map_path(self):
-        """check_vma=False is a shard_map-specific contract (the
-        documented under-reduction boundary): the auto selection must
-        not silently reroute it to pjit — where the boundary does not
-        exist and its guard test would lie."""
-        cfg = T.TransformerConfig(**_DENSE, layers_per_stage=1)
+    def test_auto_is_shard_map(self):
+        """``impl="auto"`` builds the manual program: a shard_map in
+        the jaxpr, which the GSPMD formulation never has."""
+        cfg = T.TransformerConfig(**_DENSE)
         mesh = submesh({"data": 2})
-        step = T.build_spmd_train_step(cfg, mesh, 0.1, 0.0, donate=False,
-                                       check_vma=False)
-        rng = np.random.default_rng(1)
+        rng = np.random.default_rng(3)
         tokens, labels, mask = T.make_batch(rng, cfg, 4, 16)
-        params = T.init_params(cfg, seed=0)
-        _, g = jax.value_and_grad(T.reference_loss)(
-            params, tokens, labels, mask, cfg)
-        ref_head = params["head"] - 0.1 * g["head"]
-        sp = T.shard_params(params, cfg, mesh)
-        sv = T.shard_params(jax.tree.map(jnp.zeros_like, params), cfg, mesh)
-        sp, sv, _ = step(sp, sv, tokens, labels, mask)
-        # the shard_map check_rep=False boundary: replicated-param
-        # grads under-reduce — exactly what proves the manual path ran
-        assert float(jnp.abs(sp["head"] - ref_head).max()) > 1e-4
+        sp = T.shard_params(T.init_params(cfg, 0), cfg, mesh)
+        for impl, expect in (("auto", True), ("pjit", False)):
+            step = T.build_spmd_train_step(cfg, mesh, donate=False,
+                                           impl=impl)
+            jaxpr = str(jax.make_jaxpr(step)(sp, sp, tokens, labels,
+                                             mask))
+            assert ("shard_map" in jaxpr) is expect, impl
 
-    def test_pjit_matches_shard_map_fixed_seed(self):
-        """Fixed-seed parity between the two formulations — pinned
-        wherever a VMA jax exists (the only place both can build)."""
-        from mmlspark_tpu.parallel import compat
-        if not compat.vma_native():
-            pytest.skip("shard_map formulation needs a VMA jax; on "
-                        "this jax the pjit path is pinned against the "
-                        "unsharded golden instead (TestSpmdTrainStep)")
-        cfg = T.TransformerConfig(**_DENSE, layers_per_stage=2)
-        mesh = submesh({"data": 2, "model": 2})
+    @pytest.mark.parametrize("moe, shape", [
+        pytest.param({}, {"data": 2, "model": 2}, id="dense"),
+        # the MoE blocks run with their operands' mesh shardings (no
+        # replication pin): capacity queues and expert choice must
+        # still match the manual all_to_all dispatch drop-for-drop
+        pytest.param(
+            {"n_experts": 4, "moe_capacity_factor": 1.5, "moe_top_k": 2,
+             "moe_aux_weight": 0.01, "moe_zloss_weight": 1e-3},
+            {"data": 2, "expert": 2}, id="capacity",
+            marks=pytest.mark.slow),
+        pytest.param(
+            {"n_experts": 4, "moe_capacity_factor": 1.0,
+             "moe_router": "expert_choice"}, {"data": 2, "expert": 2},
+            id="expert_choice", marks=pytest.mark.slow),
+    ])
+    def test_pjit_matches_shard_map_fixed_seed(self, moe, shape):
+        """Fixed-seed parity between the two formulations."""
+        cfg = T.TransformerConfig(**_DENSE, layers_per_stage=2, **moe)
+        mesh = submesh(shape)
         rng = np.random.default_rng(7)
         tokens, labels, mask = T.make_batch(rng, cfg, 8, 16)
         params = T.init_params(cfg, seed=0)

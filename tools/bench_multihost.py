@@ -46,17 +46,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 def _ensure_devices(n: int) -> None:
     """Must run before the jax backend initializes."""
-    from mmlspark_tpu.parallel.topology import bump_host_device_count
-    flags = bump_host_device_count(os.environ.get("XLA_FLAGS", ""), n)
+    from mmlspark_tpu.parallel.topology import use_cpu_devices
+    flags = os.environ.get("XLA_FLAGS", "")
     if "xla_cpu_multi_thread_eigen" not in flags:
         # one worker thread per virtual device: the devices, not the
         # shared eigen pool, are the unit of parallelism — otherwise a
         # "1-device" baseline silently uses every core and the curve
         # measures nothing
-        flags += " --xla_cpu_multi_thread_eigen=false"
-    os.environ["XLA_FLAGS"] = flags
-    if os.environ.get("MMLSPARK_TPU_BENCH_TPU") != "1":
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["XLA_FLAGS"] = (
+            flags + " --xla_cpu_multi_thread_eigen=false").strip()
+    # a virtual-CPU-device drill by design: the platform is assigned,
+    # not defaulted — bench.py spawns this while it holds the chip
+    use_cpu_devices(n)
 
 
 # ---------------------------------------------------------------------------
@@ -396,6 +397,7 @@ def dcn_drill(timeout: float = 300.0, smoke: bool = True) -> dict:
         cmd.append("--smoke")
     try:
         p = subprocess.run(cmd, capture_output=True, text=True,
+                           env=dict(os.environ, JAX_PLATFORMS="cpu"),
                            timeout=timeout * 3)
     except subprocess.TimeoutExpired as e:
         return {"passed": False,

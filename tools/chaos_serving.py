@@ -115,7 +115,6 @@ while True:
 
 DECODE_WORKER_SCRIPT = """
 import os, sys, time
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 from mmlspark_tpu.models import transformer as T
 from mmlspark_tpu.serving import DecodeScheduler, ServingServer, \\
     TransformerDecoder
@@ -208,7 +207,10 @@ while True:
 
 def spawn_worker(coord_url: str, journal: str,
                  script: str = WORKER_SCRIPT, *extra) -> "subprocess.Popen":
-    env = dict(os.environ, PYTHONPATH=REPO)
+    # several workers share this host and the drill's models are
+    # host-side: a CPU drill by design, so the platform is assigned
+    # (one process per chip — N workers cannot share one)
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     p = subprocess.Popen(
         [sys.executable, "-c", script, coord_url, *extra, journal],
         stdout=subprocess.PIPE, env=env, text=True)

@@ -22,10 +22,10 @@ genuine cross-process collectives:
   ``{"pipe": 2, "data": 4}`` mesh whose pipe axis IS the process
   boundary: stage-0 weights live wholly on process 0, stage-1 on
   process 1, activations cross DCN every layer-stage hop. The loss
-  tracks the single-process reference under a DOCUMENTED loose 5e-2
-  tolerance only: this jaxlib's cross-process lowering of
-  pipe-sharded params is rank-divergent (~1e-4/step drift) — the
-  strict <= 1e-6 parity contract rides the fit phase above.
+  tracks the single-process reference under a loose 5e-2 tolerance
+  only: on jaxlib 0.9.0 the two differ by ~1e-2 a step (cause not
+  established) — the strict <= 1e-6 parity contract rides the fit
+  phase above.
 * **checkpoint** — both processes cooperatively save ONE sharded
   checkpoint directory (``io/checkpoint.save_sharded``'s per-slice
   ownership + barriers); the parent then restores it single-process
@@ -211,21 +211,21 @@ def run_worker(pid: int, port: int, out_path: str, ref_path: str,
         "losses": losses, "ref_losses": ref["pipe_losses"],
         "max_loss_diff": pipe_diff,
         "stage0_devices_all_on_process0": bool(stage0_local),
-        # jaxlib-0.4.36's cross-process CPU lowering of PIPE-sharded
-        # stage params is rank-divergent (two ranks report different
-        # values for a replicated loss — measured ~8e-4; the pure
-        # data-parallel fit above is rank-consistent and <= 1e-6).
-        # The stage split across processes is still real (stage-0
-        # weights live wholly on process 0) and the trajectory tracks
-        # the single-process reference; the gate therefore rides a
-        # documented loose tolerance here, and the strict <= 1e-6
-        # parity contract rides the fit phase.
+        # Measured on jaxlib 0.9.0 (gloo, 2 x 4 CPU devices): the
+        # pipe-split trajectory is off the single-process reference
+        # by 7e-3 after the first step and 2.3e-2 after the second —
+        # more than rounding, cause not established (the pure
+        # data-parallel fit above is <= 1e-6). The stage split across
+        # processes is still real (stage-0 weights live wholly on
+        # process 0) and the loss falls alongside the reference, so
+        # the gate rides a loose tolerance here and the strict
+        # <= 1e-6 parity contract rides the fit phase.
         "tolerance": 5e-2,
         "tolerance_justification": (
             "pipe-sharded params under gloo cross-process lowering "
-            "drift ~1e-4/step on this jaxlib (rank-divergent "
-            "replicated outputs); strict parity is gated on the "
-            "data-parallel fit phase"),
+            "differ from the single-process reference by ~1e-2/step "
+            "on jaxlib 0.9.0 (cause not established); strict parity "
+            "is gated on the data-parallel fit phase"),
         "ok": pipe_diff <= 5e-2 and bool(stage0_local)}
 
     # -- phase: cooperative 2-process sharded checkpoint save --------------
@@ -259,7 +259,7 @@ def _free_port() -> int:
 def _spawn(args, timeout, tag):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)      # workers set their own device count
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"    # a gloo/CPU drill by design
     t0 = time.time()
     try:
         p = subprocess.run(
@@ -294,7 +294,7 @@ def run_drill(timeout: float = 300.0, smoke: bool = False) -> dict:
     port = _free_port()
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     procs = []
     t0 = time.time()
     for pid in range(2):
