@@ -10,6 +10,7 @@ device traces viewable in TensorBoard/Perfetto via the jax profiler.
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 from typing import Callable, Dict, Iterator, Optional
 
@@ -26,29 +27,97 @@ def device_trace(log_dir: str) -> Iterator[None]:
         yield
 
 
-@contextlib.contextmanager
-def timed_span(name: str, logger=None) -> Iterator[dict]:
-    """Wall-clock span that also annotates the device trace.
+_SINK = threading.local()        # .spans: the list its thread's owner reads
+_TRACE_ANNOTATION = None
 
-    Yields a dict whose ``seconds`` key is filled on exit; logs through
-    the framework logger when ``logger`` is None.
+
+def _trace_annotation():
+    # jax is imported on first use: this module stays importable (and
+    # StageTimings usable) on hosts that never touch a device
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION
+
+
+class span:
+    """One named unit of host work, on the profiler's clock and ours.
+
+    ``with span("decode.emit", slot=3) as sp:`` opens a
+    ``jax.profiler.TraceAnnotation(name, **attrs)`` while a profiler
+    session runs (``jax.profiler.trace``, ``POST /profile``, the
+    benchmark's ``--trace 1``), so the span lies in the device trace
+    beside the ops it waited for, on that trace's clock; with no session
+    the annotation costs one atomic check. Either way it reads
+    ``time.perf_counter_ns()`` on entry and on exit and appends ``(name,
+    t0_ns, t1_ns, attrs)`` to the list its thread's owner opened with
+    :func:`collect`; outside any owner the tuple is dropped (the times
+    stay on ``sp.t0``/``sp.t1``). ``sp.attrs`` may be replaced inside
+    the block with what is only known at its end. No log line, no lock.
+
+    ``time.perf_counter`` and ``time.monotonic``, which
+    :class:`~mmlspark_tpu.core.tracing.Tracer` reads, are the same clock
+    on Linux (``CLOCK_MONOTONIC``): a span's nanoseconds times 1e-9 are
+    seconds on the tracer's clock, and the serving loop hands them over
+    as such.
     """
-    import jax
-    out = {"name": name, "seconds": None}
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(name):
-        yield out
-    out["seconds"] = time.perf_counter() - t0
-    if logger is None:
-        from mmlspark_tpu.core.logs import get_logger
-        logger = get_logger("profiling")
-    logger.info("%s: %.3fs", name, out["seconds"])
+
+    __slots__ = ("name", "attrs", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs or None
+        self.t0 = self.t1 = 0
+        self._ann = None
+
+    def __enter__(self) -> "span":
+        ann = _TRACE_ANNOTATION or _trace_annotation()
+        if ann.is_enabled():
+            self._ann = ann(self.name, **(self.attrs or {}))
+            self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
+        spans = getattr(_SINK, "spans", None)
+        if spans is not None:
+            spans.append((self.name, self.t0, self.t1, self.attrs))
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1 - self.t0) * 1e-9
+
+
+class collect:
+    """Own the spans this thread closes inside the block: ``with
+    collect() as spans`` yields the list every :class:`span` exit on
+    this thread appends to, whatever module opened it (the decode loop
+    owns a pass this way, and the decoder's dispatch and fetch land in
+    it with no argument threaded through). Owners nest; the outer one
+    is restored on exit."""
+
+    __slots__ = ("spans", "_outer")
+
+    def __enter__(self) -> list:
+        self._outer = getattr(_SINK, "spans", None)
+        self.spans = _SINK.spans = []
+        return self.spans
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        _SINK.spans = self._outer
+        return False
 
 
 class StageTimings:
     """Thread-safe per-stage wall-clock accumulator for hot loops.
 
-    Where :func:`timed_span` logs one span, this aggregates millions:
+    Where :class:`span` records one interval, this aggregates millions:
     each ``span(name)`` adds one sample to the named stage's running
     count/total, and :meth:`snapshot` returns a JSON-able summary —
     the backing store for the serving data plane's per-stage timings in

@@ -267,9 +267,17 @@ class FlightRecorder:
     fixed-size list used circularly — recording is one store + one
     index bump under the stripe lock, and old spans are overwritten in
     place (a flight recorder, not a log: history exists to be *seized*
-    at capture time, not kept)."""
+    at capture time, not kept).
 
-    def __init__(self, capacity: int = 8192, stripes: int = 16):
+    The default of 16,384 spans holds a whole benchmark run of the
+    decode loop with room to spare: some 3,500 ``decode.pass`` spans
+    (each under a trace id of its own, so they spread over the stripes)
+    beside the spans of some 75 requests, about 270 a stripe of 1,024.
+    A pass span with its phases is about 3.5 KB, a request's child
+    span about 0.9 KB: such a run leaves some 13 MB of host memory in
+    the ring, and a ring full of passes would hold under 60 MB."""
+
+    def __init__(self, capacity: int = 16384, stripes: int = 16):
         self.stripes = max(int(stripes), 1)
         per = max(int(capacity) // self.stripes, 16)
         self.capacity = per * self.stripes
@@ -299,6 +307,23 @@ class FlightRecorder:
         found.sort(key=lambda sp: sp.t0)
         return found
 
+    def scan(self, name: str, t0: float = float("-inf"),
+             t1: float = float("inf")) -> List[Span]:
+        """Every recorded span called ``name`` that STARTED in ``[t0,
+        t1)`` and is still in its ring, from all stripes, sorted by
+        start time: the read by name and time (a whole run's
+        ``decode.pass`` spans, after the server has stopped). Each
+        stripe is locked only while its ring is copied."""
+        found: List[Span] = []
+        for s in range(self.stripes):
+            with self._locks[s]:
+                ring = list(self._rings[s])
+            found.extend(sp for sp in ring
+                         if sp is not None and sp.name == name
+                         and t0 <= sp.t0 < t1)
+        found.sort(key=lambda sp: sp.t0)
+        return found
+
 
 class Tracer:
     """Span factory + flight recorder + tail-sampled slow-trace store.
@@ -311,7 +336,7 @@ class Tracer:
     """
 
     def __init__(self, clock: Clock = SYSTEM_CLOCK,
-                 capacity: int = 8192, store_capacity: int = 128,
+                 capacity: int = 16384, store_capacity: int = 128,
                  default_slow_ms: Optional[float] = 250.0):
         self.clock = clock
         self.recorder = FlightRecorder(capacity)
@@ -384,17 +409,27 @@ class Tracer:
         if capture and (span.parent_id is None or span.remote):
             self._maybe_capture(span)
 
-    def add(self, name: str, t0: float, t1: float, parent: Span,
-            status: str = "ok", **attrs) -> Span:
-        """Record an already-completed child span with explicit
-        timestamps — the shape the serving pipeline needs, where one
-        batch-level measurement (assemble, dispatch, encode) becomes a
-        child of every live request's root without re-running clocks
-        per request."""
-        sp = Span(name, parent.trace_id, parent.span_id, t0, attrs or None)
+    def add(self, name: str, t0: float, t1: float,
+            parent: Optional[Span], status: str = "ok",
+            capture: bool = True, **attrs) -> Span:
+        """Record an already-completed span with explicit timestamps —
+        the shape the serving pipeline needs, where one batch-level
+        measurement (assemble, dispatch, encode) becomes a child of
+        every live request's root without re-running clocks per
+        request. ``parent=None`` records a ROOT under a fresh trace id
+        (one pass of the decode loop, which belongs to no single
+        request) and, unless ``capture=False``, runs the tail-capture
+        decision for it."""
+        if parent is None:
+            sp = Span(name, new_trace_id(), None, t0, attrs or None)
+        else:
+            sp = Span(name, parent.trace_id, parent.span_id, t0,
+                      attrs or None)
         sp.t1 = t1
         sp.status = status
         self._record(sp)
+        if parent is None and capture:
+            self._maybe_capture(sp)
         return sp
 
     def event(self, name: str, t: float, parent: Span,
@@ -461,15 +496,19 @@ class Tracer:
         with self._store_lock:
             self._store.pop(root.trace_id, None)
             self._store[root.trace_id] = trace
-            # per-reason quota: an overload storm produces THOUSANDS of
-            # identical shed/error captures per second, and pure global
-            # LRU would churn out the genuinely interesting slow traces
-            # within seconds of an incident starting — exactly when the
-            # operator needs them. Each reason evicts its own oldest
-            # first; the global cap still bounds the store.
+            # per-reason, per-route quota: an overload storm produces
+            # THOUSANDS of identical shed/error captures per second,
+            # and pure global LRU would churn out the genuinely
+            # interesting slow traces within seconds of an incident
+            # starting — exactly when the operator needs them. Each
+            # reason evicts its own oldest first, route by route (a
+            # decode worker's requests all last seconds and are all
+            # "slow": they must not churn out the decode loop's rare
+            # stalls); the global cap still bounds the store.
             quota = max(self.store_capacity // 4, 8)
             same = [t["trace_id"] for t in self._store.values()
-                    if t["reason"] == trace["reason"]]
+                    if t["reason"] == trace["reason"]
+                    and t["route"] == route]
             if len(same) > quota:
                 self._store.pop(same[0], None)
             while len(self._store) > self.store_capacity:

@@ -239,9 +239,19 @@ def _psum_if(x, axis):
     return jax.lax.psum(x, axis) if axis else x
 
 
+# Every part of the block and of the programs built from it runs under
+# a ``jax.named_scope`` of its own: embed, norm, attn.qkv, attn.core,
+# attn.out, ffn, moe, head, ce, optimizer, and in the decode programs
+# kv.write and kv.gather. A scope is metadata (each op's ``op_name``,
+# read from the compiled text or a profiler trace); the backward of a
+# scoped part carries it as ``transpose(jvp(<scope>))``. The names are
+# public: PERF.md's split of a step's device time reads them.
+
+
 def _rmsnorm(x, g, eps=1e-6):
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * g
+    with jax.named_scope("norm"):
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + eps) * g
 
 
 def _rope(x, pos):
@@ -270,14 +280,31 @@ def _attention(bp, x, cfg: TransformerConfig, ax: _Axes, pos):
     # scores); rope/softmax and the residual stream stay f32
     dt = _compute_dtype(cfg)
     mm_dt = dt if dt != jnp.float32 else None
-    h = _rmsnorm(x, bp["ln1"]).astype(dt)
-    q = jnp.einsum("bsd,dhk->bshk", h, bp["wq"].astype(dt)).astype(jnp.float32)
-    k = jnp.einsum("bsd,dhk->bshk", h, bp["wk"].astype(dt)).astype(jnp.float32)
-    v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"].astype(dt)).astype(jnp.float32)
-    q, k = _rope(q, pos), _rope(k, pos)
     if cfg.attention_impl not in ("auto", "dense", "folded", "flash"):
         raise ValueError(
             f"unknown attention_impl {cfg.attention_impl!r}")
+    h = _rmsnorm(x, bp["ln1"])
+    with jax.named_scope("attn.qkv"):
+        h = h.astype(dt)
+        q = jnp.einsum("bsd,dhk->bshk", h,
+                       bp["wq"].astype(dt)).astype(jnp.float32)
+        k = jnp.einsum("bsd,dhk->bshk", h,
+                       bp["wk"].astype(dt)).astype(jnp.float32)
+        v = jnp.einsum("bsd,dhk->bshk", h,
+                       bp["wv"].astype(dt)).astype(jnp.float32)
+        q, k = _rope(q, pos), _rope(k, pos)
+    with jax.named_scope("attn.core"):
+        a = _attention_core(q, k, v, cfg, ax, dt, mm_dt)
+    with jax.named_scope("attn.out"):
+        o = jnp.einsum("bshk,hkd->bsd", a.astype(dt),
+                       bp["wo"].astype(dt)).astype(jnp.float32)
+        return _psum_if(o, ax.model)
+
+
+def _attention_core(q, k, v, cfg: TransformerConfig, ax: _Axes, dt,
+                    mm_dt):
+    """The train step's attention over roped q/k and v, by
+    ``cfg.attention_impl`` (scope ``attn.core`` at the call)."""
     if ax.seq:
         # auto_train: the ring module's shared policy resolves to the
         # differentiable folded kernel where it pays off (never the
@@ -332,19 +359,19 @@ def _attention(bp, x, cfg: TransformerConfig, ax: _Axes, pos):
             a = flash_attention(q, k, v, True)
         else:
             a = dense_attention(q, k, v, causal=True, compute_dtype=mm_dt)
-    o = jnp.einsum("bshk,hkd->bsd", a.astype(dt),
-                   bp["wo"].astype(dt)).astype(jnp.float32)
-    return _psum_if(o, ax.model)
+    return a
 
 
 def _mlp(bp, x, ax: _Axes, cfg: TransformerConfig):
     dt = _compute_dtype(cfg)
-    h = _rmsnorm(x, bp["ln2"]).astype(dt)
-    z = jax.nn.relu(jnp.einsum("bsd,df->bsf", h, bp["w1"].astype(dt))
-                    + bp["b1"].astype(dt))
-    y = jnp.einsum("bsf,fd->bsd", z,
-                   bp["w2"].astype(dt)).astype(jnp.float32)
-    return _psum_if(y, ax.model) + bp["b2"]
+    h = _rmsnorm(x, bp["ln2"])
+    with jax.named_scope("ffn"):
+        h = h.astype(dt)
+        z = jax.nn.relu(jnp.einsum("bsd,df->bsf", h, bp["w1"].astype(dt))
+                        + bp["b1"].astype(dt))
+        y = jnp.einsum("bsf,fd->bsd", z,
+                       bp["w2"].astype(dt)).astype(jnp.float32)
+        return _psum_if(y, ax.model) + bp["b2"]
 
 
 def _route_top_k(probs, k: int):
@@ -702,7 +729,8 @@ def _stage(stage_blocks, x, cfg: TransformerConfig, ax: _Axes, pos):
     for bp in stage_blocks:
         x = x + _attention(bp, x, cfg, ax, pos)
         if cfg.n_experts:
-            y, (f, P, z) = _moe(bp, x, cfg, ax)
+            with jax.named_scope("moe"):
+                y, (f, P, z) = _moe(bp, x, cfg, ax)
             x = x + y
             fs.append(f)
             Ps.append(P)
@@ -746,7 +774,8 @@ def local_loss(params, tokens, labels, mask, cfg: TransformerConfig,
     Z_acc = jnp.zeros(n_blk, jnp.float32)
     for t in range(m + p_size - 1):
         if t < m:
-            inp = params["embed"][tok_mb[t]]             # [mb, S_loc, D]
+            with jax.named_scope("embed"):
+                inp = params["embed"][tok_mb[t]]         # [mb, S_loc, D]
             state = jnp.where(p_rank == 0, inp, state)
         state, f_t, p_t, z_t = _stage(stage_blocks, state, cfg, ax, pos)
         if cfg.n_experts and (cfg.moe_aux_weight > 0
@@ -779,31 +808,34 @@ def local_loss(params, tokens, labels, mask, cfg: TransformerConfig,
         # never reaches HBM, and the only large write is one
         # compute-dtype logits copy for the backward (ops/fused_ce.py)
         from mmlspark_tpu.ops.fused_ce import fused_softmax_xent
-        ce = fused_softmax_xent(
-            h.reshape(b_loc * s_loc, cfg.d_model), params["head"],
-            labels.reshape(b_loc * s_loc), compute_dtype=dt,
-            interpret=ce_impl == "fused_interpret",
-        ).reshape(b_loc, s_loc)
+        with jax.named_scope("ce"):     # the head is inside the kernel
+            ce = fused_softmax_xent(
+                h.reshape(b_loc * s_loc, cfg.d_model), params["head"],
+                labels.reshape(b_loc * s_loc), compute_dtype=dt,
+                interpret=ce_impl == "fused_interpret",
+            ).reshape(b_loc, s_loc)
     else:
         # the vocab head is a third of a small LM's forward FLOPs: run
         # the matmul with bf16 inputs + f32 MXU accumulation. The logits
         # COME OUT f32 (preferred_element_type), so there is no separate
         # upcast pass over [b, s, vocab] — the trap that made a plain
         # bf16 head slower
-        if dt != jnp.float32:
-            logits = jnp.einsum("bsd,dv->bsv", h.astype(dt),
-                                params["head"].astype(dt),
-                                preferred_element_type=jnp.float32)
-        else:
-            logits = jnp.einsum("bsd,dv->bsv", h, params["head"])
+        with jax.named_scope("head"):
+            if dt != jnp.float32:
+                logits = jnp.einsum("bsd,dv->bsv", h.astype(dt),
+                                    params["head"].astype(dt),
+                                    preferred_element_type=jnp.float32)
+            else:
+                logits = jnp.einsum("bsd,dv->bsv", h, params["head"])
         # fused CE: logsumexp - gold logit. log_softmax would
         # materialize a second [b, s, vocab] array (logp) just to gather
         # one column — at 32k vocab that is a gigabyte of pure HBM
         # traffic per step
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, labels[..., None],
-                                   axis=-1)[..., 0]
-        ce = lse - gold
+        with jax.named_scope("ce"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, labels[..., None],
+                                       axis=-1)[..., 0]
+            ce = lse - gold
     is_last = (p_rank == p_size - 1).astype(jnp.float32)
     loss_sum = jnp.sum(ce * mask) * is_last
     count = jnp.sum(mask) * is_last
@@ -1017,10 +1049,11 @@ def build_spmd_train_step(cfg: TransformerConfig, mesh,
     def local_step(params, velocity, tokens, labels, mask):
         loss, grads = jax.value_and_grad(local_loss)(
             params, tokens, labels, mask, cfg, ax)
-        velocity = jax.tree.map(lambda v, g: momentum * v + g,
-                                velocity, grads)
-        params = jax.tree.map(lambda p, v: p - learning_rate * v,
-                              params, velocity)
+        with jax.named_scope("optimizer"):
+            velocity = jax.tree.map(lambda v, g: momentum * v + g,
+                                    velocity, grads)
+            params = jax.tree.map(lambda p, v: p - learning_rate * v,
+                                  params, velocity)
         return params, velocity, loss
 
     # check_vma=False exists ONLY for interpret-mode Pallas kernels in
@@ -1239,14 +1272,21 @@ def _pjit_attention(bp, x, cfg: TransformerConfig, pos):
     programs and stay with the shard_map formulation."""
     dt = _compute_dtype(cfg)
     mm_dt = dt if dt != jnp.float32 else None
-    h = _rmsnorm(x, bp["ln1"]).astype(dt)
-    q = jnp.einsum("bsd,dhk->bshk", h, bp["wq"].astype(dt)).astype(jnp.float32)
-    k = jnp.einsum("bsd,dhk->bshk", h, bp["wk"].astype(dt)).astype(jnp.float32)
-    v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"].astype(dt)).astype(jnp.float32)
-    q, k = _rope(q, pos), _rope(k, pos)
-    a = dense_attention(q, k, v, causal=True, compute_dtype=mm_dt)
-    return jnp.einsum("bshk,hkd->bsd", a.astype(dt),
-                      bp["wo"].astype(dt)).astype(jnp.float32)
+    h = _rmsnorm(x, bp["ln1"])
+    with jax.named_scope("attn.qkv"):
+        h = h.astype(dt)
+        q = jnp.einsum("bsd,dhk->bshk", h,
+                       bp["wq"].astype(dt)).astype(jnp.float32)
+        k = jnp.einsum("bsd,dhk->bshk", h,
+                       bp["wk"].astype(dt)).astype(jnp.float32)
+        v = jnp.einsum("bsd,dhk->bshk", h,
+                       bp["wv"].astype(dt)).astype(jnp.float32)
+        q, k = _rope(q, pos), _rope(k, pos)
+    with jax.named_scope("attn.core"):
+        a = dense_attention(q, k, v, causal=True, compute_dtype=mm_dt)
+    with jax.named_scope("attn.out"):
+        return jnp.einsum("bshk,hkd->bsd", a.astype(dt),
+                          bp["wo"].astype(dt)).astype(jnp.float32)
 
 
 def _pjit_loss(params, tokens, labels, mask, cfg: TransformerConfig,
@@ -1258,7 +1298,8 @@ def _pjit_loss(params, tokens, labels, mask, cfg: TransformerConfig,
     unchanged."""
     D, Q, E_ax = groups
     B, S = tokens.shape
-    x = params["embed"][tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
     pos = jnp.arange(S)
     aux_total = jnp.float32(0.0)
     z_total = jnp.float32(0.0)
@@ -1267,7 +1308,8 @@ def _pjit_loss(params, tokens, labels, mask, cfg: TransformerConfig,
             bp = {k: v[s] for k, v in bp_all.items()}
             x = x + _pjit_attention(bp, x, cfg, pos)
             if cfg.n_experts:
-                y, (f, P_, z) = _pjit_moe(bp, x, cfg, D, Q, E_ax)
+                with jax.named_scope("moe"):
+                    y, (f, P_, z) = _pjit_moe(bp, x, cfg, D, Q, E_ax)
                 x = x + y
                 if cfg.moe_aux_weight > 0:
                     aux_total = aux_total + cfg.n_experts * jnp.sum(f * P_)
@@ -1275,32 +1317,37 @@ def _pjit_loss(params, tokens, labels, mask, cfg: TransformerConfig,
                     z_total = z_total + z
             else:
                 dt = _compute_dtype(cfg)
-                h = _rmsnorm(x, bp["ln2"]).astype(dt)
-                z = jax.nn.relu(
-                    jnp.einsum("bsd,df->bsf", h, bp["w1"].astype(dt))
-                    + bp["b1"].astype(dt))
-                y = jnp.einsum("bsf,fd->bsd", z,
-                               bp["w2"].astype(dt)).astype(jnp.float32)
+                h = _rmsnorm(x, bp["ln2"])
+                with jax.named_scope("ffn"):
+                    h = h.astype(dt)
+                    z = jax.nn.relu(
+                        jnp.einsum("bsd,df->bsf", h, bp["w1"].astype(dt))
+                        + bp["b1"].astype(dt))
+                    y = jnp.einsum("bsf,fd->bsd", z,
+                                   bp["w2"].astype(dt)).astype(jnp.float32)
                 x = x + y + bp["b2"]
     h = _rmsnorm(x, params["final_norm"])
     dt = _compute_dtype(cfg)
     if ce_impl in ("fused", "fused_interpret"):
         from mmlspark_tpu.ops.fused_ce import fused_softmax_xent
-        ce = fused_softmax_xent(
-            h.reshape(B * S, cfg.d_model), params["head"],
-            labels.reshape(B * S), compute_dtype=dt,
-            interpret=ce_impl == "fused_interpret").reshape(B, S)
+        with jax.named_scope("ce"):
+            ce = fused_softmax_xent(
+                h.reshape(B * S, cfg.d_model), params["head"],
+                labels.reshape(B * S), compute_dtype=dt,
+                interpret=ce_impl == "fused_interpret").reshape(B, S)
     else:
-        if dt != jnp.float32:
-            logits = jnp.einsum("bsd,dv->bsv", h.astype(dt),
-                                params["head"].astype(dt),
-                                preferred_element_type=jnp.float32)
-        else:
-            logits = jnp.einsum("bsd,dv->bsv", h, params["head"])
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, labels[..., None],
-                                   axis=-1)[..., 0]
-        ce = lse - gold
+        with jax.named_scope("head"):
+            if dt != jnp.float32:
+                logits = jnp.einsum("bsd,dv->bsv", h.astype(dt),
+                                    params["head"].astype(dt),
+                                    preferred_element_type=jnp.float32)
+            else:
+                logits = jnp.einsum("bsd,dv->bsv", h, params["head"])
+        with jax.named_scope("ce"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, labels[..., None],
+                                       axis=-1)[..., 0]
+            ce = lse - gold
     loss = jnp.sum(ce * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     if cfg.n_experts and cfg.moe_aux_weight > 0:
         loss = loss + cfg.moe_aux_weight * aux_total
@@ -1355,10 +1402,11 @@ def build_pjit_train_step(cfg: TransformerConfig, mesh,
     def step(params, velocity, tokens, labels, mask):
         loss, grads = jax.value_and_grad(_pjit_loss)(
             params, tokens, labels, mask, cfg, groups, ce_impl)
-        velocity = jax.tree.map(lambda v, g: momentum * v + g,
-                                velocity, grads)
-        params = jax.tree.map(lambda p, v: p - learning_rate * v,
-                              params, velocity)
+        with jax.named_scope("optimizer"):
+            velocity = jax.tree.map(lambda v, g: momentum * v + g,
+                                    velocity, grads)
+            params = jax.tree.map(lambda p, v: p - learning_rate * v,
+                                  params, velocity)
         return params, velocity, loss
 
     return jax.jit(
@@ -1568,6 +1616,11 @@ def _decode_ffn(bp, h, cfg: TransformerConfig):
     parity golden). Compute scales with ``n_experts``, acceptable at
     decode's tiny token counts; ``moe_capacity_factor`` is ignored
     here by design."""
+    with jax.named_scope("moe" if cfg.n_experts else "ffn"):
+        return _decode_ffn_body(bp, h, cfg)
+
+
+def _decode_ffn_body(bp, h, cfg: TransformerConfig):
     shape = h.shape
     hf = h.reshape(-1, shape[-1])
     if cfg.n_experts:
@@ -1752,28 +1805,34 @@ def build_prefill(cfg: TransformerConfig, donate: bool = True,
     attn = _make_inflight_attn(cfg, attn_impl, cache_sharding)
 
     def prefill(params, cache, tokens, slot, length):
-        x = params["embed"][tokens][None]              # [1, S, D]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens][None]          # [1, S, D]
         pos = jnp.arange(tokens.shape[0])
         ck, cv = cache["k"], cache["v"]
         for l, bp in enumerate(_decode_block_params(params, cfg)):
             h = _rmsnorm(x, bp["ln1"])
-            q = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wq"]), pos)
-            k = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wk"]), pos)
-            v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"])
-            # [S, H, Dh] -> this layer's slot lane, rows [0, S)
-            ck = jax.lax.dynamic_update_slice(
-                ck, k[0][None, None], (l, slot, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v[0][None, None], (l, slot, 0, 0, 0))
-            a = attn(q, k, v)
-            x = x + jnp.einsum("bshk,hkd->bsd", a, bp["wo"])
+            with jax.named_scope("attn.qkv"):
+                q = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wq"]), pos)
+                k = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wk"]), pos)
+                v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"])
+            with jax.named_scope("kv.write"):
+                # [S, H, Dh] -> this layer's slot lane, rows [0, S)
+                ck = jax.lax.dynamic_update_slice(
+                    ck, k[0][None, None], (l, slot, 0, 0, 0))
+                cv = jax.lax.dynamic_update_slice(
+                    cv, v[0][None, None], (l, slot, 0, 0, 0))
+            with jax.named_scope("attn.core"):
+                a = attn(q, k, v)
+            with jax.named_scope("attn.out"):
+                x = x + jnp.einsum("bshk,hkd->bsd", a, bp["wo"])
             x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
         h = _rmsnorm(x[0], params["final_norm"])       # [S, D]
-        last = jax.lax.dynamic_index_in_dim(h, length - 1, axis=0,
-                                            keepdims=False)
-        logits = last @ params["head"]
-        return ({"k": ck, "v": cv},
-                jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        with jax.named_scope("head"):
+            last = jax.lax.dynamic_index_in_dim(h, length - 1, axis=0,
+                                                keepdims=False)
+            logits = last @ params["head"]
+            return ({"k": ck, "v": cv},
+                    jnp.argmax(logits, -1).astype(jnp.int32), logits)
 
     kw = {}
     out_sh = _decode_out_shardings(cache_sharding)
@@ -1821,24 +1880,32 @@ def _dense_step_body(params, cfg: TransformerConfig, ck, cv, tokens,
     cache — the body :func:`build_decode_step` jits and
     :func:`build_draft_propose` unrolls ``k`` times in one program."""
     scale = cfg.d_head ** -0.5
-    x = params["embed"][tokens]                        # [N, D]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]                    # [N, D]
     mask = idx[None, None, :] <= pos[:, None, None]    # [N, 1, S]
     for l, bp in enumerate(_decode_block_params(params, cfg)):
         h = _rmsnorm(x, bp["ln1"])
-        q = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wq"]), pos)
-        k = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wk"]), pos)
-        v = jnp.einsum("nd,dhk->nhk", h, bp["wv"])
-        ck = ck.at[l, rows, pos].set(k)
-        cv = cv.at[l, rows, pos].set(v)
-        s = jnp.einsum("nhk,nshk->nhs", q, ck[l]) * scale
-        s = jnp.where(mask, s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        a = jnp.einsum("nhs,nshk->nhk", p, cv[l])
-        x = x + jnp.einsum("nhk,hkd->nd", a, bp["wo"])
+        with jax.named_scope("attn.qkv"):
+            q = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wq"]), pos)
+            k = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wk"]), pos)
+            v = jnp.einsum("nd,dhk->nhk", h, bp["wv"])
+        with jax.named_scope("kv.write"):
+            ck = ck.at[l, rows, pos].set(k)
+            cv = cv.at[l, rows, pos].set(v)
+        with jax.named_scope("kv.gather"):
+            lk, lv = ck[l], cv[l]
+        with jax.named_scope("attn.core"):
+            s = jnp.einsum("nhk,nshk->nhs", q, lk) * scale
+            s = jnp.where(mask, s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            a = jnp.einsum("nhs,nshk->nhk", p, lv)
+        with jax.named_scope("attn.out"):
+            x = x + jnp.einsum("nhk,hkd->nd", a, bp["wo"])
         x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
     h = _rmsnorm(x, params["final_norm"])
-    logits = h @ params["head"]
-    return ck, cv, jnp.argmax(logits, -1).astype(jnp.int32), logits
+    with jax.named_scope("head"):
+        logits = h @ params["head"]
+        return ck, cv, jnp.argmax(logits, -1).astype(jnp.int32), logits
 
 
 # ---------------------------------------------------------------------------
@@ -1898,40 +1965,51 @@ def build_paged_prefill(cfg: TransformerConfig, page_size: int,
     page_size, pages_per_slot = int(page_size), int(pages_per_slot)
     attn = _make_inflight_attn(cfg, attn_impl, cache_sharding)
 
+    def write_kv(ck, cv, k, v, l, page_table):
+        S = k.shape[1]
+        if S >= page_size:
+            n_chunks = S // page_size
+            kc = k[0].reshape(n_chunks, page_size,
+                              cfg.n_heads, cfg.d_head)
+            vc = v[0].reshape(n_chunks, page_size,
+                              cfg.n_heads, cfg.d_head)
+            ck = ck.at[l, page_table[:n_chunks]].set(kc)
+            cv = cv.at[l, page_table[:n_chunks]].set(vc)
+        else:
+            # a sub-page bucket: one partial write into the first
+            # claimed page, rows [0, S)
+            ck = jax.lax.dynamic_update_slice(
+                ck, k[0][None, None], (l, page_table[0], 0, 0, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cv, v[0][None, None], (l, page_table[0], 0, 0, 0))
+        return ck, cv
+
     def prefill(params, cache, tokens, page_table, length):
         S = tokens.shape[0]
-        x = params["embed"][tokens][None]              # [1, S, D]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens][None]          # [1, S, D]
         pos = jnp.arange(S)
         ck, cv = cache["k"], cache["v"]
         for l, bp in enumerate(_decode_block_params(params, cfg)):
             h = _rmsnorm(x, bp["ln1"])
-            q = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wq"]), pos)
-            k = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wk"]), pos)
-            v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"])
-            if S >= page_size:
-                n_chunks = S // page_size
-                kc = k[0].reshape(n_chunks, page_size,
-                                  cfg.n_heads, cfg.d_head)
-                vc = v[0].reshape(n_chunks, page_size,
-                                  cfg.n_heads, cfg.d_head)
-                ck = ck.at[l, page_table[:n_chunks]].set(kc)
-                cv = cv.at[l, page_table[:n_chunks]].set(vc)
-            else:
-                # a sub-page bucket: one partial write into the first
-                # claimed page, rows [0, S)
-                ck = jax.lax.dynamic_update_slice(
-                    ck, k[0][None, None], (l, page_table[0], 0, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cv, v[0][None, None], (l, page_table[0], 0, 0, 0))
-            a = attn(q, k, v)
-            x = x + jnp.einsum("bshk,hkd->bsd", a, bp["wo"])
+            with jax.named_scope("attn.qkv"):
+                q = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wq"]), pos)
+                k = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wk"]), pos)
+                v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"])
+            with jax.named_scope("kv.write"):
+                ck, cv = write_kv(ck, cv, k, v, l, page_table)
+            with jax.named_scope("attn.core"):
+                a = attn(q, k, v)
+            with jax.named_scope("attn.out"):
+                x = x + jnp.einsum("bshk,hkd->bsd", a, bp["wo"])
             x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
         h = _rmsnorm(x[0], params["final_norm"])       # [S, D]
-        last = jax.lax.dynamic_index_in_dim(h, length - 1, axis=0,
-                                            keepdims=False)
-        logits = last @ params["head"]
-        return ({"k": ck, "v": cv},
-                jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        with jax.named_scope("head"):
+            last = jax.lax.dynamic_index_in_dim(h, length - 1, axis=0,
+                                                keepdims=False)
+            logits = last @ params["head"]
+            return ({"k": ck, "v": cv},
+                    jnp.argmax(logits, -1).astype(jnp.int32), logits)
 
     kw = {}
     out_sh = _decode_out_shardings(cache_sharding)
@@ -2020,9 +2098,45 @@ def build_paged_prefix_prefill(cfg: TransformerConfig, page_size: int,
             check_vma=False)
         return f(q, k_pool, v_pool, page_table, hit_len)
 
+    def write_kv(ck, cv, k, v, l, page_table, start_page):
+        S = k.shape[0]
+        if S >= page_size:
+            # hit_len is page-aligned: suffix chunk c fills page
+            # table[start_page + c] exactly. The bucket can
+            # overshoot the lane end (start_page + n_chunks >
+            # pages_per_slot when hit_len + S_pad > max_len) — a
+            # clamped dynamic_slice would silently re-aim those
+            # chunks at EARLIER table entries, i.e. write padding
+            # over the SHARED prefix pages, so overflow chunks
+            # route to the scratch page instead (the verify step's
+            # overshoot convention).
+            n_chunks = S // page_size
+            cpos = start_page + jnp.arange(n_chunks)
+            pgs = jnp.where(
+                cpos < pages_per_slot,
+                page_table[jnp.minimum(cpos, pages_per_slot - 1)],
+                0)
+            ck = ck.at[l, pgs].set(
+                k.reshape(n_chunks, page_size,
+                          cfg.n_heads, cfg.d_head))
+            cv = cv.at[l, pgs].set(
+                v.reshape(n_chunks, page_size,
+                          cfg.n_heads, cfg.d_head))
+        else:
+            # a sub-page suffix bucket: one partial write into the
+            # first private page, rows [0, S)
+            pg = jax.lax.dynamic_index_in_dim(
+                page_table, start_page, keepdims=False)
+            ck = jax.lax.dynamic_update_slice(
+                ck, k[None, None], (l, pg, 0, 0, 0))
+            cv = jax.lax.dynamic_update_slice(
+                cv, v[None, None], (l, pg, 0, 0, 0))
+        return ck, cv
+
     def prefill(params, cache, tokens, page_table, length, hit_len):
         S = tokens.shape[0]
-        x = params["embed"][tokens]                    # [S, D]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]                # [S, D]
         pos = hit_len + jnp.arange(S)                  # virtual rows
         start_page = hit_len // page_size
         ck, cv = cache["k"], cache["v"]
@@ -2033,62 +2147,41 @@ def build_paged_prefix_prefill(cfg: TransformerConfig, page_size: int,
             else idx[None, None, :] <= pos[:, None, None]  # [S, 1, V]
         for l, bp in enumerate(_decode_block_params(params, cfg)):
             h = _rmsnorm(x, bp["ln1"])
-            q = _rope_at(jnp.einsum("sd,dhk->shk", h, bp["wq"]), pos)
-            k = _rope_at(jnp.einsum("sd,dhk->shk", h, bp["wk"]), pos)
-            v = jnp.einsum("sd,dhk->shk", h, bp["wv"])
-            if S >= page_size:
-                # hit_len is page-aligned: suffix chunk c fills page
-                # table[start_page + c] exactly. The bucket can
-                # overshoot the lane end (start_page + n_chunks >
-                # pages_per_slot when hit_len + S_pad > max_len) — a
-                # clamped dynamic_slice would silently re-aim those
-                # chunks at EARLIER table entries, i.e. write padding
-                # over the SHARED prefix pages, so overflow chunks
-                # route to the scratch page instead (the verify step's
-                # overshoot convention).
-                n_chunks = S // page_size
-                cpos = start_page + jnp.arange(n_chunks)
-                pgs = jnp.where(
-                    cpos < pages_per_slot,
-                    page_table[jnp.minimum(cpos, pages_per_slot - 1)],
-                    0)
-                ck = ck.at[l, pgs].set(
-                    k.reshape(n_chunks, page_size,
-                              cfg.n_heads, cfg.d_head))
-                cv = cv.at[l, pgs].set(
-                    v.reshape(n_chunks, page_size,
-                              cfg.n_heads, cfg.d_head))
-            else:
-                # a sub-page suffix bucket: one partial write into the
-                # first private page, rows [0, S)
-                pg = jax.lax.dynamic_index_in_dim(
-                    page_table, start_page, keepdims=False)
-                ck = jax.lax.dynamic_update_slice(
-                    ck, k[None, None], (l, pg, 0, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cv, v[None, None], (l, pg, 0, 0, 0))
+            with jax.named_scope("attn.qkv"):
+                q = _rope_at(jnp.einsum("sd,dhk->shk", h, bp["wq"]), pos)
+                k = _rope_at(jnp.einsum("sd,dhk->shk", h, bp["wk"]), pos)
+                v = jnp.einsum("sd,dhk->shk", h, bp["wv"])
+            with jax.named_scope("kv.write"):
+                ck, cv = write_kv(ck, cv, k, v, l, page_table,
+                                  start_page)
             # attend over the whole virtual lane: shared prefix rows
             # are read from their pages, suffix rows were just written
             if use_flash:
-                a = _flash_lane_attn(q, ck[l], cv[l], page_table,
-                                     hit_len)
+                with jax.named_scope("kv.gather"):
+                    lk, lv = ck[l], cv[l]
+                with jax.named_scope("attn.core"):
+                    a = _flash_lane_attn(q, lk, lv, page_table, hit_len)
             else:
-                lk = ck[l, page_table].reshape(V, cfg.n_heads,
-                                               cfg.d_head)
-                lv = cv[l, page_table].reshape(V, cfg.n_heads,
-                                               cfg.d_head)
-                s = jnp.einsum("shk,vhk->shv", q, lk) * scale
-                s = jnp.where(mask, s, -1e30)
-                p = jax.nn.softmax(s, axis=-1)
-                a = jnp.einsum("shv,vhk->shk", p, lv)
-            x = x + jnp.einsum("shk,hkd->sd", a, bp["wo"])
+                with jax.named_scope("kv.gather"):
+                    lk = ck[l, page_table].reshape(V, cfg.n_heads,
+                                                   cfg.d_head)
+                    lv = cv[l, page_table].reshape(V, cfg.n_heads,
+                                                   cfg.d_head)
+                with jax.named_scope("attn.core"):
+                    s = jnp.einsum("shk,vhk->shv", q, lk) * scale
+                    s = jnp.where(mask, s, -1e30)
+                    p = jax.nn.softmax(s, axis=-1)
+                    a = jnp.einsum("shv,vhk->shk", p, lv)
+            with jax.named_scope("attn.out"):
+                x = x + jnp.einsum("shk,hkd->sd", a, bp["wo"])
             x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
         h = _rmsnorm(x, params["final_norm"])          # [S, D]
-        last = jax.lax.dynamic_index_in_dim(
-            h, length - 1 - hit_len, axis=0, keepdims=False)
-        logits = last @ params["head"]
-        return ({"k": ck, "v": cv},
-                jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        with jax.named_scope("head"):
+            last = jax.lax.dynamic_index_in_dim(
+                h, length - 1 - hit_len, axis=0, keepdims=False)
+            logits = last @ params["head"]
+            return ({"k": ck, "v": cv},
+                    jnp.argmax(logits, -1).astype(jnp.int32), logits)
 
     kw = {}
     out_sh = _decode_out_shardings(cache_sharding)
@@ -2180,33 +2273,46 @@ def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
         return f(q, k_pool, v_pool, page_tables, pos)
 
     def step(params, cache, tokens, pos, page_tables):
-        x = params["embed"][tokens]                    # [N, D]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]                # [N, D]
         ck, cv = cache["k"], cache["v"]
         mask = idx[None, None, :] <= pos[:, None, None]  # [N, 1, V]
         pg = page_tables[rows, pos // page_size]       # [N]
         row = pos % page_size
         for l, bp in enumerate(_decode_block_params(params, cfg)):
             h = _rmsnorm(x, bp["ln1"])
-            q = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wq"]), pos)
-            k = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wk"]), pos)
-            v = jnp.einsum("nd,dhk->nhk", h, bp["wv"])
-            ck = ck.at[l, pg, row].set(k)
-            cv = cv.at[l, pg, row].set(v)
+            with jax.named_scope("attn.qkv"):
+                q = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wq"]), pos)
+                k = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wk"]), pos)
+                v = jnp.einsum("nd,dhk->nhk", h, bp["wv"])
+            with jax.named_scope("kv.write"):
+                ck = ck.at[l, pg, row].set(k)
+                cv = cv.at[l, pg, row].set(v)
             if use_pallas:
-                a = _paged_attn(q, ck[l], cv[l], page_tables, pos)
+                # the layer's slice of each pool, handed to the kernel
+                with jax.named_scope("kv.gather"):
+                    lk, lv = ck[l], cv[l]
+                with jax.named_scope("attn.core"):
+                    a = _paged_attn(q, lk, lv, page_tables, pos)
             else:
-                lk = _gather_lane(ck[l], page_tables, n_slots, V, cfg)
-                lv = _gather_lane(cv[l], page_tables, n_slots, V, cfg)
-                s = jnp.einsum("nhk,nshk->nhs", q, lk) * scale
-                s = jnp.where(mask, s, -1e30)
-                p = jax.nn.softmax(s, axis=-1)
-                a = jnp.einsum("nhs,nshk->nhk", p, lv)
-            x = x + jnp.einsum("nhk,hkd->nd", a, bp["wo"])
+                with jax.named_scope("kv.gather"):
+                    lk = _gather_lane(ck[l], page_tables, n_slots, V,
+                                      cfg)
+                    lv = _gather_lane(cv[l], page_tables, n_slots, V,
+                                      cfg)
+                with jax.named_scope("attn.core"):
+                    s = jnp.einsum("nhk,nshk->nhs", q, lk) * scale
+                    s = jnp.where(mask, s, -1e30)
+                    p = jax.nn.softmax(s, axis=-1)
+                    a = jnp.einsum("nhs,nshk->nhk", p, lv)
+            with jax.named_scope("attn.out"):
+                x = x + jnp.einsum("nhk,hkd->nd", a, bp["wo"])
             x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
         h = _rmsnorm(x, params["final_norm"])
-        logits = h @ params["head"]
-        return ({"k": ck, "v": cv},
-                jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        with jax.named_scope("head"):
+            logits = h @ params["head"]
+            return ({"k": ck, "v": cv},
+                    jnp.argmax(logits, -1).astype(jnp.int32), logits)
 
     kw = {}
     out_sh = _decode_out_shardings(cache_sharding)
@@ -2302,7 +2408,8 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
         raise ValueError(f"unknown verify ce_impl {ce_impl!r}")
 
     def verify(params, cache, tokens, pos, page_tables):
-        x = params["embed"][tokens]                    # [N, W, D]
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]                # [N, W, D]
         ck, cv = cache["k"], cache["v"]
         qpos = pos[:, None] + offs[None, :]            # [N, W]
         # causal over the virtual lane: query j reads index <= pos + j
@@ -2320,25 +2427,38 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
         row = qpos % page_size
         for l, bp in enumerate(_decode_block_params(params, cfg)):
             h = _rmsnorm(x, bp["ln1"])
-            q = _rope_at(jnp.einsum("nwd,dhk->nwhk", h, bp["wq"]), qpos)
-            k = _rope_at(jnp.einsum("nwd,dhk->nwhk", h, bp["wk"]), qpos)
-            v = jnp.einsum("nwd,dhk->nwhk", h, bp["wv"])
-            ck = ck.at[l, pg, row].set(k)
-            cv = cv.at[l, pg, row].set(v)
-            lk = _gather_lane(ck[l], page_tables, n_slots, V, cfg)
-            lv = _gather_lane(cv[l], page_tables, n_slots, V, cfg)
-            s = jnp.einsum("nwhk,nshk->nwhs", q, lk) * scale
-            s = jnp.where(mask, s, -1e30)              # [N, W, 1, V] bcast
-            p = jax.nn.softmax(s, axis=-1)
-            a = jnp.einsum("nwhs,nshk->nwhk", p, lv)
-            x = x + jnp.einsum("nwhk,hkd->nwd", a, bp["wo"])
+            with jax.named_scope("attn.qkv"):
+                q = _rope_at(jnp.einsum("nwd,dhk->nwhk", h, bp["wq"]),
+                             qpos)
+                k = _rope_at(jnp.einsum("nwd,dhk->nwhk", h, bp["wk"]),
+                             qpos)
+                v = jnp.einsum("nwd,dhk->nwhk", h, bp["wv"])
+            with jax.named_scope("kv.write"):
+                ck = ck.at[l, pg, row].set(k)
+                cv = cv.at[l, pg, row].set(v)
+            with jax.named_scope("kv.gather"):
+                lk = _gather_lane(ck[l], page_tables, n_slots, V, cfg)
+                lv = _gather_lane(cv[l], page_tables, n_slots, V, cfg)
+            with jax.named_scope("attn.core"):
+                s = jnp.einsum("nwhk,nshk->nwhs", q, lk) * scale
+                s = jnp.where(mask, s, -1e30)          # [N, W, 1, V] bcast
+                p = jax.nn.softmax(s, axis=-1)
+                a = jnp.einsum("nwhs,nshk->nwhk", p, lv)
+            with jax.named_scope("attn.out"):
+                x = x + jnp.einsum("nwhk,hkd->nwd", a, bp["wo"])
             x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
         h = _rmsnorm(x, params["final_norm"])          # [N, W, D]
-        logits = jnp.einsum("nwd,dv->nwv", h, params["head"])
-        out = ({"k": ck, "v": cv},
-               jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        with jax.named_scope("head"):
+            logits = jnp.einsum("nwd,dv->nwv", h, params["head"])
+            out = ({"k": ck, "v": cv},
+                   jnp.argmax(logits, -1).astype(jnp.int32), logits)
         if not with_scores:
             return out
+        with jax.named_scope("ce"):
+            return out + (score_proposals(tokens, h, logits,
+                                          params["head"]),)
+
+    def score_proposals(tokens, h, logits, head):
         labels = tokens[:, 1:].reshape(-1)             # proposals
         if ce_impl in ("fused", "fused_interpret"):
             # score straight off the hidden states: the streaming CE
@@ -2346,7 +2466,7 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
             # VMEM — log p(proposal) = -ce, f32-accumulated
             from mmlspark_tpu.ops.fused_ce import fused_softmax_xent
             ce = fused_softmax_xent(
-                h[:, :-1].reshape(-1, cfg.d_model), params["head"],
+                h[:, :-1].reshape(-1, cfg.d_model), head,
                 labels, interpret=ce_impl == "fused_interpret")
             scores = -ce.reshape(n_slots, width - 1)
         else:
@@ -2355,7 +2475,7 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
             gold = jnp.take_along_axis(
                 lg, tokens[:, 1:, None], axis=-1)[..., 0]
             scores = gold - lse
-        return out + (scores,)
+        return scores
 
     kw = {}
     out_sh = _decode_out_shardings(cache_sharding)

@@ -361,16 +361,23 @@ def _layout(q, k, v):
 def _flash_fwd(q, k, v, causal, scale, interpret, bwd_impl):
     (b, sq, sk, h, d, sq_p, sk_p, d_p, to_bh, qpos, kpos) = _layout(q, k, v)
     scale_f = float(scale) if scale is not None else d ** -0.5
-    o, m, l = _flash_call(to_bh(q, sq, sq_p), to_bh(k, sk, sk_p),
-                          to_bh(v, sk, sk_p), qpos, kpos,
+    # the relayout around the kernel, the kernel (the jitted
+    # ``_flash_call``, named by itself in a trace) and the normalisation
+    # under names of their own, inside the caller's ``attn.core``
+    with jax.named_scope("flash.layout"):
+        qb, kb, vb = (to_bh(q, sq, sq_p), to_bh(k, sk, sk_p),
+                      to_bh(v, sk, sk_p))
+    o, m, l = _flash_call(qb, kb, vb, qpos, kpos,
                           scale_f, causal, interpret)   # all f32 (BH,Sq_p,.)
-    l_safe = jnp.maximum(l, 1e-30)
-    out_bh = o / l_safe                                  # normalized
-    # lse = m + log l reconstructs p = exp(s - lse) tile-locally in the
-    # backward; fully-masked rows get +BIG so their p (and grads) are 0
-    lse_bh = jnp.where(l > 0, m + jnp.log(l_safe), 1e30)  # (BH, Sq_p, 1)
-    out = out_bh[:, :sq, :d].reshape(b, h, sq, d).swapaxes(1, 2)
-    return out.astype(q.dtype), (q, k, v, out_bh, lse_bh)
+    with jax.named_scope("flash.norm"):
+        l_safe = jnp.maximum(l, 1e-30)
+        out_bh = o / l_safe                              # normalized
+        # lse = m + log l reconstructs p = exp(s - lse) tile-locally in
+        # the backward; fully-masked rows get +BIG so their p (and
+        # grads) are 0
+        lse_bh = jnp.where(l > 0, m + jnp.log(l_safe), 1e30)  # (BH,Sq_p,1)
+        out = out_bh[:, :sq, :d].reshape(b, h, sq, d).swapaxes(1, 2)
+        return out.astype(q.dtype), (q, k, v, out_bh, lse_bh)
 
 
 def _flash_bwd(causal, scale, interpret, bwd_impl, res, dout):
@@ -379,31 +386,9 @@ def _flash_bwd(causal, scale, interpret, bwd_impl, res, dout):
     scale_f = float(scale) if scale is not None else d ** -0.5
 
     if bwd_impl == "xla":
-        # dense recompute: p from the saved lse, then the FA-2 gradient
-        # algebra as einsums (bf16 matmuls, f32 accumulation)
-        lse = lse_bh[:, :sq, 0].reshape(b, h, sq)        # (B, H, Sq)
-        out = out_bh[:, :sq, :d].reshape(b, h, sq, d).swapaxes(1, 2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                       preferred_element_type=jnp.float32) * scale_f
-        p = jnp.exp(s - lse[..., None])                  # (B, H, Sq, Sk)
-        if causal:
-            mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
-            p = jnp.where(mask[None, None], p, 0.0)
-        do = dout.astype(jnp.float32)
-        delta = jnp.sum(do * out, axis=-1)               # (B, Sq, H)
-        pc = p.astype(q.dtype)
-        dv = jnp.einsum("bhqk,bqhd->bkhd", pc, dout,
-                        preferred_element_type=jnp.float32)
-        dp = jnp.einsum("bqhd,bkhd->bhqk", dout, v,
-                        preferred_element_type=jnp.float32)
-        ds = (p * (dp - jnp.swapaxes(delta, 1, 2)[..., None])) \
-            .astype(q.dtype)
-        dq = jnp.einsum("bhqk,bkhd->bqhd", ds, k,
-                        preferred_element_type=jnp.float32) * scale_f
-        dk = jnp.einsum("bhqk,bqhd->bkhd", ds, q,
-                        preferred_element_type=jnp.float32) * scale_f
-        return (dq.astype(q.dtype), dk.astype(k.dtype),
-                dv.astype(v.dtype))
+        with jax.named_scope("flash.bwd_xla"):
+            return _flash_bwd_xla(q, k, v, out_bh, lse_bh, dout, scale_f,
+                                  causal)
 
     do_bh = to_bh(dout, sq, sq_p)
     delta = jnp.sum(do_bh.astype(jnp.float32) * out_bh, axis=-1,
@@ -418,6 +403,38 @@ def _flash_bwd(causal, scale, interpret, bwd_impl, res, dout):
     return (from_bh(dq, sq).astype(q.dtype),
             from_bh(dk, sk).astype(k.dtype),
             from_bh(dv, sk).astype(v.dtype))
+
+
+def _flash_bwd_xla(q, k, v, out_bh, lse_bh, dout, scale_f: float,
+                   causal: bool):
+    """The XLA backward of :func:`flash_attention`: dense recompute of
+    p from the saved lse, then the FA-2 gradient algebra as einsums
+    (bf16 matmuls, f32 accumulation)."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    lse = lse_bh[:, :sq, 0].reshape(b, h, sq)        # (B, H, Sq)
+    out = out_bh[:, :sq, :d].reshape(b, h, sq, d).swapaxes(1, 2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale_f
+    p = jnp.exp(s - lse[..., None])                  # (B, H, Sq, Sk)
+    if causal:
+        mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
+        p = jnp.where(mask[None, None], p, 0.0)
+    do = dout.astype(jnp.float32)
+    delta = jnp.sum(do * out, axis=-1)               # (B, Sq, H)
+    pc = p.astype(q.dtype)
+    dv = jnp.einsum("bhqk,bqhd->bkhd", pc, dout,
+                    preferred_element_type=jnp.float32)
+    dp = jnp.einsum("bqhd,bkhd->bhqk", dout, v,
+                    preferred_element_type=jnp.float32)
+    ds = (p * (dp - jnp.swapaxes(delta, 1, 2)[..., None])) \
+        .astype(q.dtype)
+    dq = jnp.einsum("bhqk,bkhd->bqhd", ds, k,
+                    preferred_element_type=jnp.float32) * scale_f
+    dk = jnp.einsum("bhqk,bqhd->bkhd", ds, q,
+                    preferred_element_type=jnp.float32) * scale_f
+    return (dq.astype(q.dtype), dk.astype(k.dtype),
+            dv.astype(v.dtype))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

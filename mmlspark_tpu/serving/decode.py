@@ -59,6 +59,12 @@ prefill/step latency histograms, page-pool occupancy, speculative
 acceptance, and queue-wait all land in the server's registry
 (``docs/observability.md`` "Decode metrics"); every request's trace
 shows ``queue_wait``/``prefill``/``decode`` children under its root.
+The loop records itself too: every pass is a chain of ``decode.*``
+phases (``core/profiling.span``: host events of any profiler trace,
+on its clock) recorded once as a ``decode.pass`` span, counted under
+``loop`` in ``GET /decode/stats``, and retained under route
+``decode.loop`` when it stalls (``docs/observability.md`` "The decode
+loop").
 Chaos: a ``fault_plan`` drives the ``decode_prefill`` and
 ``decode_step`` sites — an injected step/verify fault 500s the
 affected requests but **never strands a slot or a page**
@@ -69,12 +75,14 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
 from mmlspark_tpu.core.logs import get_logger
+from mmlspark_tpu.core.profiling import collect, span
 from mmlspark_tpu.core.resilience import SYSTEM_CLOCK, Clock
 from mmlspark_tpu.parallel.sharding import bucket_ladder, bucket_target
 from mmlspark_tpu.serving.tenancy import (
@@ -449,21 +457,27 @@ class TransformerDecoder:
         Returns greedy next tokens plus the full per-slot logits
         (device array; fetched only when a sampler needs it)."""
         import jax.numpy as jnp
-        if self.paged:
-            if page_tables is None:
-                if self._identity_tables is None:
-                    raise ValueError("undersized paged pool needs "
-                                     "scheduler page tables")
-                page_tables = self._identity_tables
-            self.cache, nxt, logits = self._step(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos),
-                jnp.asarray(np.asarray(page_tables, np.int32)))
-        else:
-            self.cache, nxt, logits = self._step(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos))
-        return np.asarray(nxt), logits
+        if self.paged and page_tables is None:
+            if self._identity_tables is None:
+                raise ValueError("undersized paged pool needs "
+                                 "scheduler page tables")
+            page_tables = self._identity_tables
+        # the host-to-device copies and the call until it returns, then
+        # the wait for the device and the copy back: two spans that land
+        # in the pass the scheduler has open on this thread
+        with span("decode.dispatch"):
+            if self.paged:
+                self.cache, nxt, logits = self._step(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(pos),
+                    jnp.asarray(np.asarray(page_tables, np.int32)))
+            else:
+                self.cache, nxt, logits = self._step(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(pos))
+        with span("decode.fetch"):
+            out = np.asarray(nxt)
+        return out, logits
 
     def step(self, tokens: np.ndarray, pos: np.ndarray,
              page_tables=None) -> np.ndarray:
@@ -478,10 +492,12 @@ class TransformerDecoder:
         -> proposals ``[n_slots, spec_k]`` (the draft cache advances
         in place)."""
         import jax.numpy as jnp
-        self.draft_cache, props = self._propose(
-            self.draft_params, self.draft_cache, jnp.asarray(tokens),
-            jnp.asarray(pos))
-        return np.asarray(props)
+        with span("decode.dispatch"):
+            self.draft_cache, props = self._propose(
+                self.draft_params, self.draft_cache,
+                jnp.asarray(tokens), jnp.asarray(pos))
+        with span("decode.fetch"):
+            return np.asarray(props)
 
     def draft_step_logits(self, tokens: np.ndarray, pos: np.ndarray
                           ) -> "tuple[np.ndarray, Any]":
@@ -489,10 +505,12 @@ class TransformerDecoder:
         sampled speculative slot needs (per-step draft distributions
         on host for rejection sampling)."""
         import jax.numpy as jnp
-        self.draft_cache, nxt, logits = self._draft_step(
-            self.draft_params, self.draft_cache, jnp.asarray(tokens),
-            jnp.asarray(pos))
-        return np.asarray(nxt), logits
+        with span("decode.dispatch"):
+            self.draft_cache, nxt, logits = self._draft_step(
+                self.draft_params, self.draft_cache,
+                jnp.asarray(tokens), jnp.asarray(pos))
+        with span("decode.fetch"):
+            return np.asarray(nxt), logits
 
     def verify_logits(self, tokens: np.ndarray, pos: np.ndarray,
                       page_tables
@@ -505,11 +523,13 @@ class TransformerDecoder:
         per-proposal target log-probs ``[n_slots, spec_k - 1]``
         (fused-CE or XLA per ``verify_ce_impl``)."""
         import jax.numpy as jnp
-        self.cache, toks, logits, scores = self._verify(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(pos),
-            jnp.asarray(np.asarray(page_tables, np.int32)))
-        return np.asarray(toks), logits, np.asarray(scores)
+        with span("decode.dispatch"):
+            self.cache, toks, logits, scores = self._verify(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(pos),
+                jnp.asarray(np.asarray(page_tables, np.int32)))
+        with span("decode.fetch"):
+            return np.asarray(toks), logits, np.asarray(scores)
 
     def n_compiles(self) -> int:
         """Compiled-executable count across every jitted entry point
@@ -1090,6 +1110,41 @@ TOKENS_PER_REQUEST_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0,
 #: so a 100k-token decode cannot flood the flight recorder ring
 _MAX_TIMELINE_SPANS = 128
 
+#: the phases of one pass of the scheduler's loop, each a
+#: ``decode.<phase>`` span (docs/observability.md "The decode loop"):
+#: the keys of ``/decode/stats`` -> ``loop`` and of a ``decode.pass``
+#: span's ``phases_ms``
+LOOP_PHASES = ("admit", "prefill", "prepare", "dispatch", "fetch",
+               "emit", "idle")
+#: the route ``decode.pass`` spans are captured under, and how many
+#: times the running median of the passes that ran a step a pass must
+#: last to be retained as slow (``GET /traces``)
+LOOP_ROUTE = "decode.loop"
+SLOW_PASS_MULTIPLE = 5.0
+
+
+def pass_view(phases) -> Dict[str, Any]:
+    """What a ``decode.pass`` span's ``phases`` say, spelled out: the
+    spans of one pass as they closed, ``(name, t0_ns, t1_ns, attrs)``,
+    as ``{"phases_ms": {phase: milliseconds}, "prefills": [one entry a
+    ``decode.prefill``: its attributes, ``start_ms`` into the pass and
+    ``ms``], **the other phases' attributes}`` (``admitted``,
+    ``active``, ``pages_in_use``, ``n_pages``, ``emitted``).
+    Read-side: the loop stores the spans as they are."""
+    t0 = min(p[1] for p in phases)
+    view: Dict[str, Any] = {"phases_ms": {}, "prefills": []}
+    for name, a, b, attrs in phases:
+        key = name[7:]                           # "decode.<phase>"
+        view["phases_ms"][key] = \
+            view["phases_ms"].get(key, 0.0) + (b - a) * 1e-6
+        if key == "prefill":
+            view["prefills"].append(dict(
+                attrs, start_ms=(a - t0) * 1e-6, ms=(b - a) * 1e-6))
+        elif attrs:
+            view.update(attrs)
+    view.pop("traces", None)       # the pass carries them itself
+    return view
+
 
 class _DecodeRequest:
     """Per-request decode state, riding alongside the server's
@@ -1242,6 +1297,15 @@ class DecodeScheduler:
         # goodput: tokens delivered by CLEAN finishes (eos/length) —
         # the numerator; n_tokens stays the all-reasons denominator
         self.n_goodput_tokens = 0
+        # the loop's own record: the phases the open pass has closed so
+        # far (the loop thread's; the decoder's dispatch and fetch land
+        # here too), each phase's [count, nanoseconds] since start, and
+        # the lengths of the last passes that ran a step, whose median
+        # sets the slow-pass threshold
+        self._pass: list = []
+        self.loop = {f"decode.{name}": [0, 0] for name in LOOP_PHASES}
+        self._pass_ns: deque = deque(maxlen=64)
+        self._slow_ns: Optional[float] = None
         # tenancy hooks (wired by bind() against the server's
         # registry): slot-release EWMA feeds honest decode-429
         # Retry-After; the fair cycle orders slot claims per tenant
@@ -1328,9 +1392,7 @@ class DecodeScheduler:
                     "KV-cache pages currently held by live slots "
                     "(prefix-cache residents are NOT in use — see "
                     "serving_decode_pages_cached).").set_function(
-                lambda: (self.pages.n_pages - 1) - self.pages.n_free
-                - (self.prefix.n_cached
-                   if self.prefix is not None else 0))
+                self._pages_in_use)
             m.gauge("serving_decode_page_high_water",
                     "Most pages ever simultaneously claimed."
                     ).set_function(lambda: self.pages.high_water)
@@ -1411,6 +1473,13 @@ class DecodeScheduler:
                     ).set_function(
                 lambda: self._cache_bytes()
                 * self.prefix.n_cached // max(self.pages.n_pages, 1))
+
+    def _pages_in_use(self) -> int:
+        """Pages live requests hold: claimable less free less the
+        prefix cache's residents (shared prefix pages count once)."""
+        return (self.pages.n_pages - 1 - self.pages.n_free
+                - (self.prefix.n_cached if self.prefix is not None
+                   else 0))
 
     def _cache_bytes(self) -> int:
         """Exposition-time view: bytes of the decoder's KV tree."""
@@ -1803,24 +1872,83 @@ class DecodeScheduler:
                          error="decode scheduler stopping")
 
     def _loop(self) -> None:
-        while not self._stop.is_set():
-            # dead waiters resolve EVERY pass, slots full or not: with
-            # every slot pinned by long decodes, a cancelled/expired
-            # waiter must still get its prompt reply (and stop counting
-            # toward overloaded()) instead of rotting until the
-            # frontend's request_timeout
-            self._reap_waiting()
-            self._admit_waiting()
-            if not self._active:
-                # fully idle (nothing waiting either) -> block until
-                # submit()/cancel()/stop() wakes us, no 50 Hz poll;
-                # with waiters held back by deadline-less slots the
-                # short timeout keeps their deadlines honest
-                self._work.wait(self.idle_wait_s
-                                if self._waiting else None)
-                self._work.clear()
-                continue
-            self._run_step()
+        """Every pass is a chain of ``decode.*`` spans (admit [with a
+        prefill child per request admitted], then prepare, dispatch,
+        fetch, emit, or idle), owned here and recorded once, as one
+        ``decode.pass`` span, when the pass ends."""
+        with collect() as self._pass:
+            while not self._stop.is_set():
+                with span("decode.admit") as sp:
+                    # dead waiters resolve EVERY pass, slots full or
+                    # not: with every slot pinned by long decodes, a
+                    # cancelled/expired waiter must still get its
+                    # prompt reply (and stop counting toward
+                    # overloaded()) instead of rotting until the
+                    # frontend's request_timeout
+                    self._reap_waiting()
+                    sp.attrs = {"admitted": self._admit_waiting()}
+                if self._active:
+                    self._run_step()
+                else:
+                    # fully idle (nothing waiting either) -> block
+                    # until submit()/cancel()/stop() wakes us, no 50 Hz
+                    # poll; with waiters held back by deadline-less
+                    # slots the short timeout keeps their deadlines
+                    # honest
+                    with span("decode.idle"):
+                        self._work.wait(self.idle_wait_s
+                                        if self._waiting else None)
+                    self._work.clear()
+                self._record_pass(self._pass[:])
+                self._pass.clear()
+
+    def _record_pass(self, phases: list) -> None:
+        """One finished pass: its phases into the cumulative ``loop``
+        counters, and the pass itself ONCE into the tracer's ring as a
+        root ``decode.pass`` span under a trace id of its own: start,
+        end, the step's sequence number and ``phases``, the spans as
+        they closed (:func:`pass_view` reads them). This runs between
+        two steps, while the device waits: it does the least it can. A
+        pass that ran a step or a prefill and lasted over
+        ``SLOW_PASS_MULTIPLE`` times the running median of the passes
+        that ran a step is retained under route ``decode.loop`` like
+        any slow request, with its view spelled out beside the
+        phases; a pass that only waited for work is no stall."""
+        loop = self.loop
+        t0 = t1 = 0
+        worked = stepped = False
+        riders = ()
+        for name, a, b, attrs in phases:
+            acc = loop[name]
+            acc[0] += 1
+            acc[1] += b - a
+            if name == "decode.admit":
+                t0 = a           # the first span opened, whatever
+            elif name == "decode.prepare":        # closed before it
+                riders = attrs["traces"]
+            elif name == "decode.dispatch":
+                stepped = True
+            elif name == "decode.prefill":
+                worked = True
+            t1 = b
+        if self.tracer is None:
+            return
+        ns = t1 - t0
+        if stepped:
+            self._pass_ns.append(ns)
+            if loop["decode.prepare"][0] % 32 == 0:
+                self._slow_ns = SLOW_PASS_MULTIPLE * sorted(
+                    self._pass_ns)[len(self._pass_ns) // 2]
+                self.tracer.set_threshold(LOOP_ROUTE,
+                                          self._slow_ns * 1e-6)
+        # until the median is known the tracer's own default decides
+        slow = (stepped or worked) and (self._slow_ns is None
+                                        or ns >= self._slow_ns)
+        self.tracer.add(
+            "decode.pass", t0 * 1e-9, t1 * 1e-9, None, capture=slow,
+            route=LOOP_ROUTE, step=self.n_steps if stepped else None,
+            traces=riders, phases=phases,
+            **(pass_view(phases) if slow else {}))
 
     def _reap_waiting(self) -> None:
         with self._lock:
@@ -1881,17 +2009,20 @@ class DecodeScheduler:
                     return r
             return self._waiting.popleft()
 
-    def _admit_waiting(self) -> None:
+    def _admit_waiting(self) -> int:
         """Between steps: claim free slots (and, paged, the prompt's
-        pages) for waiting requests — one prefill each. Cancelled/
-        expired/disconnected waiters resolve WITHOUT ever claiming
-        anything; a head-of-queue request the page pool cannot hold
-        yet WAITS (admission order preserved — pages free as running
-        requests finish)."""
+        pages) for waiting requests — one prefill each, under a
+        ``decode.prefill`` span whose two ends are the request's
+        ``prefill`` span, ``prefill_s`` and the prefill histogram.
+        Cancelled/expired/disconnected waiters resolve WITHOUT ever
+        claiming anything; a head-of-queue request the page pool
+        cannot hold yet WAITS (admission order preserved — pages free
+        as running requests finish). Returns the requests admitted."""
+        admitted = 0
         while self.pool.n_free > 0:
             req = self._pop_waiting()
             if req is None:
-                return
+                return admitted
             p = req.pending
             if req.cancelled:
                 self._finish(req, "cancelled")
@@ -1925,7 +2056,7 @@ class DecodeScheduler:
                         self.pages.release(shared)
                     with self._lock:
                         self._waiting.appendleft(req)
-                    return
+                    return admitted
                 pages = shared + own
             slot = self.pool.claim()
             if slot is None:      # raced a concurrent release? retry
@@ -1933,51 +2064,65 @@ class DecodeScheduler:
                     self.pages.release(pages)
                 with self._lock:
                     self._waiting.appendleft(req)
-                return
+                return admitted
             if self.prefix is not None:
                 # one monotonic hit-ledger bump per ADMITTED request
                 self.prefix.count(hit_len)
-            t0 = self._now()
-            self._add_span(req, "queue_wait", req.t_submit, t0)
-            if self._m_queue_wait is not None:
-                self._m_queue_wait.labels().observe(
-                    (t0 - req.t_submit) * 1000.0)
             table = None
             if self._tables is not None:
                 self._tables[slot, :] = 0
                 self._tables[slot, :len(pages)] = pages
                 table = self._tables[slot]
+            bucket = bucket_target(len(req.prompt) - hit_len,
+                                   self.decoder.max_len)
+            sp = span("decode.prefill", bucket=bucket,
+                      prompt_len=len(req.prompt), prefix_hit=hit_len,
+                      slot=slot, others_active=len(self._active),
+                      trace=getattr(p, "trace", None))
             try:
-                if self.fault_plan is not None:
-                    self.fault_plan.raise_at("decode_prefill",
-                                             clock=self.clock)
-                if hit_len > 0:
-                    first, last_logits = \
-                        self.decoder.prefill_prefix_logits(
-                            slot, req.prompt, hit_len, table,
-                            draft=self._spec_capable(req))
-                else:
-                    first, last_logits = self.decoder.prefill_logits(
-                        slot, req.prompt, table,
-                        draft=self._spec_capable(req))
-                if req.sampler is not None:
-                    # the request's own seeded PRNG picks the first
-                    # generated token from the prompt's last logits
-                    first = req.sampler.sample(np.asarray(last_logits))
+                with sp:
+                    sp.attrs["queue_wait_ms"] = (
+                        sp.t0 * 1e-9 - req.t_submit) * 1e3
+                    if self.fault_plan is not None:
+                        self.fault_plan.raise_at("decode_prefill",
+                                                 clock=self.clock)
+                    if hit_len > 0:
+                        first, last_logits = \
+                            self.decoder.prefill_prefix_logits(
+                                slot, req.prompt, hit_len, table,
+                                draft=self._spec_capable(req))
+                    else:
+                        first, last_logits = \
+                            self.decoder.prefill_logits(
+                                slot, req.prompt, table,
+                                draft=self._spec_capable(req))
+                    if req.sampler is not None:
+                        # the request's own seeded PRNG picks the first
+                        # generated token from the prompt's last logits
+                        first = req.sampler.sample(
+                            np.asarray(last_logits))
             except Exception as e:  # noqa: BLE001 — injected or real
                 self.pool.release(slot)
                 if pages:
                     self.pages.release(pages)
                 if self._tables is not None:
                     self._tables[slot, :] = 0
-                self._add_span(req, "prefill", t0, self._now(),
-                               status="error")
+                self._add_span(req, "queue_wait", req.t_submit,
+                               sp.t0 * 1e-9)
+                self._add_span(req, "prefill", sp.t0 * 1e-9,
+                               sp.t1 * 1e-9, status="error")
                 self._finish(req, "error", status=500,
                              error=f"prefill failed: {e}")
                 continue
-            t1 = self._now()
+            # the span's two ends are the only clock reads of a prefill
+            t0, t1 = sp.t0 * 1e-9, sp.t1 * 1e-9
+            self._add_span(req, "queue_wait", req.t_submit, t0)
+            if self._m_queue_wait is not None:
+                self._m_queue_wait.labels().observe(
+                    (t0 - req.t_submit) * 1000.0)
             req.t_prefill = t1
             req.t_decode = t1
+            admitted += 1
             self.n_prefills += 1
             self.n_prompt_tokens += len(req.prompt)
             self.prefill_s += t1 - t0
@@ -2015,6 +2160,7 @@ class DecodeScheduler:
                     self.slots_high_water = len(self._active)
             self._emit_stream(req, [first])
             self._retire_if_done(req, first)
+        return admitted
 
     def _retire_if_done(self, req: _DecodeRequest, tok: int) -> bool:
         """Post-token finish checks, cheapest terminal first."""
@@ -2119,14 +2265,33 @@ class DecodeScheduler:
                 spec[slot] = req
         return spec
 
+    def _device_interval(self, i0: int) -> "tuple[float, float]":
+        """Seconds (the tracer's clock) from the start of the first to
+        the end of the last ``decode.dispatch``/``decode.fetch`` span
+        the decoder closed since the pass held ``i0`` phases: a step's
+        or a round's wall time, from the spans' own clock reads."""
+        done = self._pass[i0:]
+        if not done:               # a decoder that opens no span
+            now = time.perf_counter()
+            return now, now
+        return done[0][1] * 1e-9, done[-1][2] * 1e-9
+
     def _run_step(self) -> None:
-        spec = self._prepare_round()
+        with span("decode.prepare") as sp:
+            spec = self._prepare_round()
+            paged = self.pages is not None
+            sp.attrs = {
+                "active": len(self._active),
+                "pages_in_use": self._pages_in_use() if paged else None,
+                "n_pages": self.pages.n_pages - 1 if paged else None,
+                "traces": [getattr(r.pending, "trace", None)
+                           for r in self._active.values()]}
         if not self._active:
             return
         if spec:
             self._run_spec_round(spec)
             return
-        t0 = self._now()
+        i0 = len(self._pass)
         try:
             if self.fault_plan is not None:
                 self.fault_plan.raise_at("decode_step",
@@ -2143,7 +2308,7 @@ class DecodeScheduler:
                 self._finish(req, "error", status=500,
                              error=f"decode step failed: {e}")
             return
-        t1 = self._now()
+        t0, t1 = self._device_interval(i0)
         self.n_steps += 1
         if self._m_step is not None:
             self._m_step.labels().observe((t1 - t0) * 1000.0)
@@ -2164,22 +2329,26 @@ class DecodeScheduler:
             except Exception:  # noqa: BLE001 — the draft is advisory:
                 logger.warning(  # a broken draft must not fail decode
                     "draft catch-up step failed", exc_info=True)
-        # one host fetch of the full [n_slots, vocab] logits per step,
-        # paid ONLY while a sampling request is in a slot — pure-greedy
-        # batches keep the token-only transfer
-        logits_np = None
-        if any(r.sampler is not None for r in self._active.values()):
-            logits_np = np.asarray(step_logits)
-        for slot, req in list(self._active.items()):
-            tok = (int(out[slot]) if req.sampler is None
-                   else req.sampler.sample(logits_np[slot]))
-            req.produced.append(tok)
-            self.n_tokens += 1
-            req.t_last = t1          # one store/token: the TPOT stamp
-            self._pos[slot] += 1
-            self._tokens[slot] = tok
-            self._emit_stream(req, [tok])
-            self._retire_if_done(req, tok)
+        with span("decode.emit") as sp:
+            # one host fetch of the full [n_slots, vocab] logits per
+            # step, paid ONLY while a sampling request is in a slot —
+            # pure-greedy batches keep the token-only transfer
+            logits_np = None
+            if any(r.sampler is not None
+                   for r in self._active.values()):
+                logits_np = np.asarray(step_logits)
+            live = list(self._active.items())
+            for slot, req in live:
+                tok = (int(out[slot]) if req.sampler is None
+                       else req.sampler.sample(logits_np[slot]))
+                req.produced.append(tok)
+                self.n_tokens += 1
+                req.t_last = t1      # one store/token: the TPOT stamp
+                self._pos[slot] += 1
+                self._tokens[slot] = tok
+                self._emit_stream(req, [tok])
+                self._retire_if_done(req, tok)
+            sp.attrs = {"emitted": len(live)}
 
     def _run_spec_round(self, spec: Dict[int, _DecodeRequest]) -> None:
         """One speculative round: draft proposes ``spec_k`` tokens per
@@ -2193,7 +2362,7 @@ class DecodeScheduler:
         k = self.decoder.spec_k
         sampled_spec = [s for s, r in spec.items()
                         if r.sampler is not None]
-        t0 = self._now()
+        i0 = len(self._pass)
         try:
             if self.fault_plan is not None:
                 self.fault_plan.raise_at("decode_step",
@@ -2239,78 +2408,82 @@ class DecodeScheduler:
                 self._finish(req, "error", status=500,
                              error=f"decode step failed: {e}")
             return
-        t1 = self._now()
+        t0, t1 = self._device_interval(i0)
         self.n_spec_rounds += 1
         if self._m_spec_round is not None:
             self._m_spec_round.labels().observe((t1 - t0) * 1000.0)
         self._charge_device_ms((t1 - t0) * 1000.0,
                                self._active.values())
-        logits_np = None
-        if any(r.sampler is not None
-               for r in self._active.values()):
-            logits_np = np.asarray(ver_logits)
-        if spec:
-            # per-proposal target log-probs from the verify's fused-CE
-            # (or XLA) score head: the acceptance-QUALITY signal —
-            # acceptance counts say how often the draft agreed,
-            # this says how close the misses were
-            sl = sorted(spec)
-            mean_logp = float(np.mean(ver_scores[sl]))
-            prev = self.spec_proposal_logp
-            self.spec_proposal_logp = (
-                mean_logp if prev is None
-                else 0.8 * prev + 0.2 * mean_logp)
-        round_proposed = round_accepted = 0
-        for slot, req in list(self._active.items()):
-            if slot not in spec:
-                # non-speculative rider: position 0 of the verify IS
-                # its single step
-                tok = (int(out_tok[slot, 0]) if req.sampler is None
-                       else req.sampler.sample(logits_np[slot, 0]))
-                self._accept_tokens(req, slot, [tok], t_emit=t1)
-                continue
-            self.n_spec_proposed += k
-            round_proposed += k
-            acc_before = round_accepted
-            emitted: List[int] = []
-            if req.sampler is None:
-                for j in range(k):
-                    tgt = int(out_tok[slot, j])
-                    emitted.append(tgt)
-                    if int(props[slot, j]) != tgt:
-                        break
-                    self.n_spec_accepted += 1
-                    round_accepted += 1
-            else:
-                smp = req.sampler
-                for j in range(k):
-                    d = int(props[slot, j])
-                    p_t = smp.probs(logits_np[slot, j])
-                    q_d = draft_probs[slot][j]
-                    accept = (q_d[d] > 0.0 and
-                              smp.uniform() <= min(
-                                  1.0, float(p_t[d] / q_d[d])))
-                    if accept:
-                        emitted.append(d)
+        with span("decode.emit") as sp:
+            n_before = self.n_tokens
+            logits_np = None
+            if any(r.sampler is not None
+                   for r in self._active.values()):
+                logits_np = np.asarray(ver_logits)
+            if spec:
+                # per-proposal target log-probs from the verify's
+                # fused-CE (or XLA) score head: the acceptance-QUALITY
+                # signal — acceptance counts say how often the draft
+                # agreed, this says how close the misses were
+                sl = sorted(spec)
+                mean_logp = float(np.mean(ver_scores[sl]))
+                prev = self.spec_proposal_logp
+                self.spec_proposal_logp = (
+                    mean_logp if prev is None
+                    else 0.8 * prev + 0.2 * mean_logp)
+            round_proposed = round_accepted = 0
+            for slot, req in list(self._active.items()):
+                if slot not in spec:
+                    # non-speculative rider: position 0 of the verify
+                    # IS its single step
+                    tok = (int(out_tok[slot, 0]) if req.sampler is None
+                           else req.sampler.sample(logits_np[slot, 0]))
+                    self._accept_tokens(req, slot, [tok], t_emit=t1)
+                    continue
+                self.n_spec_proposed += k
+                round_proposed += k
+                acc_before = round_accepted
+                emitted: List[int] = []
+                if req.sampler is None:
+                    for j in range(k):
+                        tgt = int(out_tok[slot, j])
+                        emitted.append(tgt)
+                        if int(props[slot, j]) != tgt:
+                            break
                         self.n_spec_accepted += 1
                         round_accepted += 1
-                        continue
-                    resid = np.maximum(p_t - q_d, 0.0)
-                    tot = resid.sum()
-                    emitted.append(smp.draw(resid / tot) if tot > 0
-                                   else smp.draw(p_t))
-                    break
-            # per-round timeline span: the token cadence a /trace/<id>
-            # tree shows (bounded per request — see _MAX_TIMELINE_SPANS)
-            if req.n_timeline < _MAX_TIMELINE_SPANS:
-                req.n_timeline += 1
-                self._add_span(req, "spec_round", t0, t1,
-                               proposed=k,
-                               accepted=round_accepted - acc_before,
-                               emitted=len(emitted))
-            self._accept_tokens(req, slot, emitted, t_emit=t1)
-        if self.spec_policy is not None:
-            self.spec_policy.note(round_proposed, round_accepted)
+                else:
+                    smp = req.sampler
+                    for j in range(k):
+                        d = int(props[slot, j])
+                        p_t = smp.probs(logits_np[slot, j])
+                        q_d = draft_probs[slot][j]
+                        accept = (q_d[d] > 0.0 and
+                                  smp.uniform() <= min(
+                                      1.0, float(p_t[d] / q_d[d])))
+                        if accept:
+                            emitted.append(d)
+                            self.n_spec_accepted += 1
+                            round_accepted += 1
+                            continue
+                        resid = np.maximum(p_t - q_d, 0.0)
+                        tot = resid.sum()
+                        emitted.append(smp.draw(resid / tot) if tot > 0
+                                       else smp.draw(p_t))
+                        break
+                # per-round timeline span: the token cadence a
+                # /trace/<id> tree shows (bounded per request — see
+                # _MAX_TIMELINE_SPANS)
+                if req.n_timeline < _MAX_TIMELINE_SPANS:
+                    req.n_timeline += 1
+                    self._add_span(req, "spec_round", t0, t1,
+                                   proposed=k,
+                                   accepted=round_accepted - acc_before,
+                                   emitted=len(emitted))
+                self._accept_tokens(req, slot, emitted, t_emit=t1)
+            if self.spec_policy is not None:
+                self.spec_policy.note(round_proposed, round_accepted)
+            sp.attrs = {"emitted": self.n_tokens - n_before}
 
     def _accept_tokens(self, req: _DecodeRequest, slot: int,
                        toks: List[int],
@@ -2443,6 +2616,11 @@ class DecodeScheduler:
                 "prefill_tokens_per_s": (
                     round(self.n_prompt_tokens / self.prefill_s, 1)
                     if self.prefill_s > 0 else None),
+                # the loop's passes by phase (LOOP_PHASES): how many
+                # decode.<phase> spans closed and their seconds, from
+                # the same clock reads as the spans and as prefill_s
+                "loop": {k[7:]: {"n": n, "s": round(ns * 1e-9, 6)}
+                         for k, (n, ns) in self.loop.items()},
                 "n_step_faults": self.n_step_faults,
                 "n_compiles": self.decoder.n_compiles(),
                 # the live honest-429 inputs: slot-release gap EWMA

@@ -99,7 +99,9 @@ from mmlspark_tpu.core.tracing import (
     ambient_tracer, capture_hint, extract_span_context, format_span_id,
     merge_traces, span_tree, to_perfetto,
 )
-from mmlspark_tpu.serving.decode import DecodeOverloaded, DecodeScheduler
+from mmlspark_tpu.serving.decode import (
+    DecodeOverloaded, DecodeScheduler, pass_view,
+)
 from mmlspark_tpu.serving.frontend import EventLoopFrontend, batched_replies
 from mmlspark_tpu.serving.incident import FanoutNotifier, IncidentManager
 from mmlspark_tpu.serving.policy import AdaptiveBatchPolicy
@@ -1387,6 +1389,25 @@ class ServingServer:
                        ("trace_id", "root", "route", "duration_ms",
                         "status", "reason", "captured_at", "n_spans")}
                 out["tree"] = span_tree(tr)
+                # the passes of the decode loop this request rode
+                # (still in the ring): how many, and the slowest with
+                # their phases — each one's own trace id is at
+                # /trace/<id> if it was slow enough to be kept
+                rode = [sp for sp in self.tracer.recorder.scan(
+                    "decode.pass")
+                    if tid in (sp.attrs.get("traces") or ())] \
+                    if self.decoder is not None else []
+                rode.sort(key=lambda sp: -sp.duration_ms)
+                if rode:
+                    out["decode_passes"] = {
+                        "n": len(rode),
+                        "slowest": [
+                            {"trace_id": sp.trace_id,
+                             "step": sp.attrs.get("step"),
+                             "duration_ms": round(sp.duration_ms, 3),
+                             "phases_ms": pass_view(
+                                 sp.attrs["phases"])["phases_ms"]}
+                            for sp in rode[:5]]}
                 body = json.dumps(out).encode()
             return 200, body, "application/json", ()
         if path == "/version":

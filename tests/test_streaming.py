@@ -93,11 +93,16 @@ class TestFileStreamSource:
 
 
 class TestProfiling:
-    def test_timed_span(self):
-        from mmlspark_tpu.core.profiling import timed_span
-        with timed_span("unit-test-span") as span:
-            time.sleep(0.01)
-        assert span["seconds"] >= 0.01
+    def test_span(self):
+        from mmlspark_tpu.core.profiling import collect, span
+        with collect() as spans:
+            with span("unit-test-span", k=1) as sp:
+                time.sleep(0.01)
+        assert sp.seconds >= 0.01
+        assert spans == [("unit-test-span", sp.t0, sp.t1, {"k": 1})]
+        with span("unowned") as sp:      # no owner: the tuple is dropped
+            pass
+        assert sp.t1 >= sp.t0 > 0 and spans[1:] == []
 
     @pytest.mark.slow
     def test_device_trace_writes(self, tmp_path):

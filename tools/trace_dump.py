@@ -87,16 +87,36 @@ def _get_json(url: str, timeout: float = 10.0):
         return json.loads(resp.read().decode())
 
 
+def _fmt_attr(v) -> str:
+    return f"{v:.3f}" if isinstance(v, float) else str(v)
+
+
 def _print_tree(node: dict, depth: int = 0) -> None:
     flag = "" if node["status"] == "ok" else f"  [{node['status']}]"
     worker = node.get("worker")
     wtag = f"  ({worker})" if worker else ""
     attrs = node.get("attrs") or {}
+    # a decode.pass carries its phases and prefills as attributes: the
+    # scalars go on the span's line, each dict or list on lines below
+    nested = {k: v for k, v in attrs.items()
+              if isinstance(v, (dict, list))
+              and not (k == "phases" and "phases_ms" in attrs)}
     extra = "".join(f" {k}={v}" for k, v in sorted(attrs.items())
-                    if k != "route")
+                    if k not in ("route", "phases") and k not in nested)
     print(f"{'  ' * depth}{node['name']:<{max(24 - 2 * depth, 1)}} "
           f"@{node['start_ms']:>9.3f}ms  {node['duration_ms']:>9.3f}ms"
           f"{extra}{wtag}{flag}")
+    for k, v in sorted(nested.items()):
+        pad = "  " * (depth + 1)
+        if isinstance(v, dict):
+            print(f"{pad}{k}: " + " ".join(
+                f"{a}={_fmt_attr(b)}" for a, b in v.items()))
+        else:
+            print(f"{pad}{k}: {len(v)}")
+            for item in v:
+                print(f"{pad}  " + (" ".join(
+                    f"{a}={_fmt_attr(b)}" for a, b in item.items())
+                    if isinstance(item, dict) else str(item)))
     for child in sorted(node.get("children", []),
                         key=lambda c: c["start_ms"]):
         _print_tree(child, depth + 1)
@@ -552,6 +572,15 @@ def main() -> None:
                 print(f"(worker {wk} unreachable: {err})",
                       file=sys.stderr)
             _print_tree(tr["tree"])
+            rode = tr.get("decode_passes")
+            if rode:          # a request's trace on a decode worker
+                print(f"rode {rode['n']} passes of the decode loop; "
+                      f"the slowest:")
+                for ps in rode["slowest"]:
+                    print(f"  {ps['trace_id']}  step {ps['step']}  "
+                          f"{ps['duration_ms']:.3f}ms  " + " ".join(
+                              f"{k}={_fmt_attr(v)}" for k, v in
+                              (ps.get("phases_ms") or {}).items()))
     except HTTPError as e:
         if e.code == 404:
             raise SystemExit(
