@@ -6,7 +6,11 @@ materializes the (Sq × Sk) score matrix in HBM: the KV dimension is the
 innermost grid axis, with the running max / normalizer / unnormalized
 accumulator carried in VMEM scratch across KV tiles (the canonical TPU
 flash pattern — see the pallas guide's grid/scratch sections). QK^T and
-P·V run on the MXU per (128 × 128) tile.
+P·V run on the MXU per (tq × tk) tile; :func:`flash_tiles` picks the
+tile from the lengths, the head_dim and the operand dtype (1024 × 1024
+for 2048 bf16 tokens at head_dim 64, 128 × 128 for a 128-token prefill
+bucket), because a grid step has a fixed cost that a 128 × 128 tile's
+work does not cover.
 
 Masking uses *global position* operands rather than block indices so the
 one kernel serves every ring step: each device's local Q block carries
@@ -28,15 +32,66 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-Q_TILE = 128
-KV_TILE = 128
-LANE = 128           # pad head_dim to the lane width
+Q_TILE = 128         # the floor of :func:`flash_tiles`, and the tile of
+KV_TILE = 128        # the Pallas backward kernels (not tuned)
+LANE = 128           # lanes of a vector register: a block's VMEM width
 _NEG_INF = -1e30
 _PAD_POS = np.iinfo(np.int32).max  # sentinel: padded key, always masked
+
+# what one grid step of the forward kernel may hold in VMEM by
+# :func:`_flash_vmem_bytes`' count, and the kernel's scoped-VMEM limit
+# (the v5e's default is 16 MiB of its 128). Mosaic's own count for
+# 1024 x 1024 tiles, compiled ahead of time for the v5e: 9-12 MiB at
+# head_dim 64 in bf16 and at 128 in f32 (the count here says 11 and
+# 14.5), 17-20 at 256 in f32 (19)
+_FLASH_VMEM_BUDGET = 20 * 2**20
+_FLASH_VMEM_LIMIT = 32 * 2**20
+_MAX_TILE = 1024     # the longest tile measured on the chip
 
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def _flash_vmem_bytes(tq: int, tk: int, d: int, itemsize: int) -> int:
+    """VMEM one forward grid step holds: the q/k/v blocks and the f32
+    output block double-buffered, the accumulator and statistics, and
+    the (tq x tk) scores in f32 and the probabilities in the operands'
+    dtype. A block's last dimension takes whole 128-lane registers
+    whatever ``d`` is."""
+    d_l = _round_up(d, LANE)
+    blocks = 2 * (tq + 2 * tk) * d_l * itemsize + 2 * tq * d_l * 4
+    scratch = tq * d_l * 4 + 4 * tq * LANE * 4
+    scores = tq * tk * (4 + itemsize)
+    return blocks + scratch + scores
+
+
+def _tile_choices(s: int):
+    """Multiples of 128 that divide ``s`` padded to 128, largest first:
+    the sequence is never padded further than to 128."""
+    n = _round_up(s, Q_TILE) // Q_TILE
+    return [Q_TILE * m for m in range(n, 0, -1)
+            if n % m == 0 and Q_TILE * m <= _MAX_TILE]
+
+
+def flash_tiles(sq: int, sk: int, d: int, dtype) -> tuple:
+    """``(tq, tk)`` of the forward kernel for these lengths, head_dim
+    and operand dtype: of the pairs that divide the lengths padded to
+    128 and fit :data:`_FLASH_VMEM_BUDGET`, the one with the most score
+    elements a grid step. A grid step has a fixed cost (about a third
+    of a microsecond) and every step rescales the accumulator, so on
+    the v5e 64 heads of 2048 causal bf16 tokens at head_dim 64 take
+    9.8 ms in 128 x 128 tiles, 1.56 in 512 x 512, 1.00 in 512 x 1024
+    and 0.90 in 1024 x 1024 (PERF.md, PR 27). Ties go to the longer KV
+    tile (1024 x 512 took 1.33). The floor is ``(Q_TILE, KV_TILE)``."""
+    itemsize = jnp.dtype(dtype).itemsize
+    fits = [(tq * tk, tk, tq) for tq in _tile_choices(sq)
+            for tk in _tile_choices(sk)
+            if _flash_vmem_bytes(tq, tk, d, itemsize) <= _FLASH_VMEM_BUDGET]
+    if not fits:
+        return Q_TILE, KV_TILE
+    _, tk, tq = max(fits)
+    return tq, tk
 
 
 def _tile_live(qpos, kpos, causal: bool):
@@ -49,15 +104,27 @@ def _tile_live(qpos, kpos, causal: bool):
     return live
 
 
+def _tile_full(qpos, kpos, causal: bool):
+    """True when no (query, key) pair of the tile is masked: no padded
+    key, and (causal) every key at or before every query."""
+    kmax = jnp.max(kpos)
+    full = kmax != _PAD_POS
+    if causal:
+        full = full & (jnp.min(qpos) >= kmax)
+    return full
+
+
 def _vma(x):
     """Varying-manual-axes of ``x`` (empty outside shard_map)."""
     return getattr(jax.typeof(x), "vma", frozenset()) or frozenset()
 
 
-def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
-                  o_ref, m_ref, l_ref,
-                  acc, m_scr, l_scr, *, scale: float, causal: bool):
-    """One (batch*head, q-tile, kv-tile) step of streaming attention."""
+def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, *refs,
+                  scale: float, causal: bool, normalize: bool):
+    """One (batch*head, q-tile, kv-tile) step of streaming attention.
+    Outputs ``(o, lse)`` normalised when ``normalize``, else the ring's
+    partials ``(o, m, l)``."""
+    out_refs, (acc, m_scr, l_scr) = refs[:-3], refs[-3:]
     kv_idx = pl.program_id(2)
 
     @pl.when(kv_idx == 0)
@@ -69,28 +136,25 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
     qpos = qpos_ref[0]                                 # (TQ,)
     kpos = kpos_ref[0]                                 # (TK,)
 
-    # tile skipping: a tile whose every key is padding, or (causal)
-    # whose every key is in the future of every query, contributes
-    # nothing — skip its two matmuls (half of all tiles under causal)
-    live = _tile_live(qpos, kpos, causal)
-
-    @pl.when(live)
-    def _():
-        q = q_ref[0]                                   # (TQ, D)
-        s = jax.lax.dot_general(q, k_ref[0],
+    def step(masked: bool):
+        s = jax.lax.dot_general(q_ref[0], k_ref[0],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        mask = (kpos != _PAD_POS)[None, :]
-        if causal:
-            mask = mask & (qpos[:, None] >= kpos[None, :])
-        s = jnp.where(mask, s, _NEG_INF)
-
+        if masked:
+            if causal:
+                # a padded key's sentinel lies past every query
+                mask = qpos[:, None] >= kpos[None, :]
+            else:
+                mask = (kpos != _PAD_POS)[None, :]
+            s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_scr[:]                              # (TQ, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        # fully-masked rows: m_new == -1e30 makes exp(s - m_new) = exp(0);
-        # kill those ones so l stays 0 and the ring merge sees "no data"
-        p = jnp.where(mask, p, 0.0)
+        # a row with no live key yet keeps m == -1e30, and exp(s - m)
+        # would be exp(0) for its masked entries: subtract 0 there, so
+        # they underflow to 0, l stays 0 and the ring merge sees
+        # "no data"
+        m_sub = jnp.where(m_new <= _NEG_INF, 0.0, m_new) if masked else m_new
+        p = jnp.exp(s - m_sub)
         alpha = jnp.exp(m_prev - m_new)                # (TQ, 1)
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         # P·V in the inputs' dtype (bf16 inputs keep the MXU fast path),
@@ -100,54 +164,114 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
             preferred_element_type=jnp.float32)
         m_scr[:] = m_new
 
+    # a tile whose every key is padding, or (causal) in the future of
+    # every query, contributes nothing and is skipped (6 of 16 tile
+    # pairs at 512 x 512 over 2048 causal tokens); a tile with nothing
+    # to mask (another 6, off the diagonal) runs without the mask
+    live = _tile_live(qpos, kpos, causal)
+    full = _tile_full(qpos, kpos, causal)
+
+    @pl.when(live & full)
+    def _():
+        step(masked=False)
+
+    @pl.when(live & jnp.logical_not(full))
+    def _():
+        step(masked=True)
+
     @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _():
-        o_ref[0] = acc[:]                              # unnormalized
-        m_ref[0] = m_scr[:]                            # (TQ, 1)
-        l_ref[0] = l_scr[:]
+        l = l_scr[:]
+        if normalize:
+            o_ref, lse_ref = out_refs
+            l_safe = jnp.maximum(l, 1e-30)
+            o_ref[0] = acc[:] / l_safe
+            # lse = m + log l reconstructs p = exp(s - lse) tile-locally
+            # in the backward; fully-masked rows get +BIG so their p
+            # (and grads) are 0
+            lse_ref[0] = jnp.where(l > 0, m_scr[:] + jnp.log(l_safe), 1e30)
+        else:
+            o_ref, m_ref, l_ref = out_refs
+            o_ref[0] = acc[:]                          # unnormalized
+            m_ref[0] = m_scr[:]                        # (TQ, 1)
+            l_ref[0] = l
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "causal", "interpret"))
+                   static_argnames=("scale", "causal", "interpret", "tiles",
+                                    "normalize", "arange_pos"))
 def _flash_call(q, k, v, q_pos, k_pos, scale: float, causal: bool,
-                interpret: bool):
-    """q (BH, Sq, D), k/v (BH, Sk, D), positions (1, S*) int32 (padded)."""
+                interpret: bool, tiles: tuple = (Q_TILE, KV_TILE),
+                normalize: bool = False, arange_pos: bool = False):
+    """q (BH, Sq, D), k/v (BH, Sk, D), positions (1, S*) int32, all
+    padded to ``tiles`` = (tq, tk); D is the head_dim as it is (a block
+    whose last dimension is the array's is legal: nothing pads the head
+    to 128 lanes, and the matmuls contract and produce D columns).
+    Returns f32 ``(o, m, l)`` partials, or ``(o, lse)`` normalised.
+    ``arange_pos`` says the positions are ``arange`` (padding apart), so
+    which causal tiles are dead is known here: their K/V index repeats
+    the row's last live block and the pipeline fetches nothing."""
     bh, sq, d = q.shape
     sk = k.shape[1]
-    grid = (bh, sq // Q_TILE, sk // KV_TILE)
-    kernel = functools.partial(_flash_kernel, scale=scale, causal=causal)
+    tq, tk = tiles
+    grid = (bh, sq // tq, sk // tk)
+    kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
+                               normalize=normalize)
+    if causal and arange_pos:
+        def kv_map(b, i, j):
+            return (b, jnp.minimum(j, ((i + 1) * tq - 1) // tk), 0)
+    else:
+        def kv_map(b, i, j):
+            return (b, j, 0)
+    # stats as (.., TQ, 1) blocks: a trailing dim equal to the full array
+    # dim satisfies the TPU (8, 128) tiling rule
+    stat_spec = pl.BlockSpec((1, tq, 1), lambda b, i, j: (b, i, 0))
+    # propagate the varying-manual-axes type so the kernel also composes
+    # inside VMA-checked shard_map (the ring body)
+    stat_shape = jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32, vma=_vma(q))
+    n_stats = 1 if normalize else 2
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, Q_TILE), lambda b, i, j: (0, i)),
-            pl.BlockSpec((1, KV_TILE), lambda b, i, j: (0, j)),
-            pl.BlockSpec((1, Q_TILE, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, KV_TILE, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, KV_TILE, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, tq), lambda b, i, j: (0, i)),
+            pl.BlockSpec((1, tk), lambda b, i, j: (0, j)),
+            pl.BlockSpec((1, tq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, tk, d), kv_map),
+            pl.BlockSpec((1, tk, d), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((1, Q_TILE, d), lambda b, i, j: (b, i, 0)),
-            # stats as (.., TQ, 1) blocks: a trailing dim equal to the
-            # full array dim satisfies the TPU (8, 128) tiling rule
-            pl.BlockSpec((1, Q_TILE, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, Q_TILE, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            # propagate the varying-manual-axes type so the kernel also
-            # composes inside VMA-checked shard_map (the ring body)
-            jax.ShapeDtypeStruct((bh, sq, d), jnp.float32, vma=_vma(q)),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32, vma=_vma(q)),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32, vma=_vma(q)),
-        ],
+        out_specs=[pl.BlockSpec((1, tq, d), lambda b, i, j: (b, i, 0))]
+        + [stat_spec] * n_stats,
+        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), jnp.float32,
+                                        vma=_vma(q))]
+        + [stat_shape] * n_stats,
         scratch_shapes=[
             # acc / running-max / normalizer live across KV tiles
-            pltpu.VMEM((Q_TILE, d), jnp.float32),
-            pltpu.VMEM((Q_TILE, 1), jnp.float32),
-            pltpu.VMEM((Q_TILE, 1), jnp.float32),
+            pltpu.VMEM((tq, d), jnp.float32),
+            pltpu.VMEM((tq, 1), jnp.float32),
+            pltpu.VMEM((tq, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM_LIMIT),
         interpret=interpret,
     )(q_pos, k_pos, q, k, v)
+
+
+def _to_bh(x, s_pad: int):
+    """(B, S, H, D) -> (B*H, S_pad, D); keeps dtype and head_dim."""
+    b, s, h, d = x.shape
+    x = jnp.swapaxes(x, 1, 2).reshape(b * h, s, d)
+    return jnp.pad(x, ((0, 0), (0, s_pad - s), (0, 0)))
+
+
+def _padded_positions(q_pos, k_pos, sq_p: int, sk_p: int):
+    """(1, S_pad) int32 position rows: padded queries sit at 0, padded
+    keys at the sentinel no query sees."""
+    q_pos, k_pos = (jnp.asarray(x, jnp.int32) for x in (q_pos, k_pos))
+    return (jnp.pad(q_pos, (0, sq_p - q_pos.shape[0]))[None],
+            jnp.pad(k_pos, (0, sk_p - k_pos.shape[0]),
+                    constant_values=_PAD_POS)[None])
 
 
 def flash_block_attn(q, k, v, scale, q_pos, k_pos, causal: bool,
@@ -156,26 +280,20 @@ def flash_block_attn(q, k, v, scale, q_pos, k_pos, causal: bool,
     o (B,Sq,H,Dh) unnormalized) for the online-softmax ring merge.
 
     q (B, Sq, H, Dh); k, v (B, Sk, H, Dh); *_pos (S*,) int32 global
-    positions. Handles arbitrary (unaligned) Sq/Sk/Dh by padding to the
-    (128, 128) flash tiles; padded keys carry a sentinel position and
-    can never contribute.
+    positions. Handles arbitrary (unaligned) Sq/Sk by padding to 128,
+    a multiple of the tiles :func:`flash_tiles` picks; padded keys
+    carry a sentinel position and can never contribute. The positions
+    are operands (a ring step's are traced), so every tile is a grid
+    step and the kernel decides from them which it skips.
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    sq_p, sk_p, d_p = (_round_up(sq, Q_TILE), _round_up(sk, KV_TILE),
-                       _round_up(d, LANE))
-
-    def to_bh(x, s, s_pad):                    # (B,S,H,D) -> (B*H, S_p, D_p)
-        x = jnp.swapaxes(x, 1, 2).reshape(b * h, s, d)
-        return jnp.pad(x, ((0, 0), (0, s_pad - s), (0, d_p - d)))
-
-    qpos_p = jnp.pad(jnp.asarray(q_pos, jnp.int32), (0, sq_p - sq))[None]
-    kpos_p = jnp.pad(jnp.asarray(k_pos, jnp.int32), (0, sk_p - sk),
-                     constant_values=_PAD_POS)[None]
-    o, m, l = _flash_call(to_bh(q, sq, sq_p), to_bh(k, sk, sk_p),
-                          to_bh(v, sk, sk_p), qpos_p, kpos_p,
-                          float(scale), causal, interpret)
-    o = o[:, :sq, :d].reshape(b, h, sq, d).swapaxes(1, 2)  # (B,Sq,H,Dh)
+    sq_p, sk_p = _round_up(sq, Q_TILE), _round_up(sk, KV_TILE)
+    qpos_p, kpos_p = _padded_positions(q_pos, k_pos, sq_p, sk_p)
+    o, m, l = _flash_call(_to_bh(q, sq_p), _to_bh(k, sk_p), _to_bh(v, sk_p),
+                          qpos_p, kpos_p, float(scale), causal, interpret,
+                          tiles=flash_tiles(sq, sk, d, q.dtype))
+    o = o[:, :sq].reshape(b, h, sq, d).swapaxes(1, 2)      # (B,Sq,H,Dh)
     m = m[:, :sq, 0].reshape(b, h, sq)
     l = l[:, :sq, 0].reshape(b, h, sq)
     return m.astype(q.dtype), l.astype(q.dtype), o.astype(q.dtype)
@@ -270,7 +388,8 @@ def _flash_dkv_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, do_ref,
                    static_argnames=("scale", "causal", "interpret"))
 def _flash_bwd_call(q, k, v, do, lse, delta, q_pos, k_pos,
                     scale: float, causal: bool, interpret: bool):
-    """All (BH, S_pad, D_pad) f32; lse/delta (BH, S_pad, 1)."""
+    """q/k/v/do (BH, S_pad, D) in their own dtype; lse/delta
+    (BH, S_pad, 1) f32; S_pad a multiple of the 128 tiles."""
     bh, sq, d = q.shape
     sk = k.shape[1]
     nq, nk = sq // Q_TILE, sk // KV_TILE
@@ -326,9 +445,10 @@ def flash_attention(q, k, v, causal: bool = True, scale=None,
     the FlashAttention-2 gradient algebra, via one of two engines:
 
     - ``bwd_impl="xla"`` (default): the recompute as XLA einsums. The
-      (S x S) probabilities exist transiently but XLA fuses the chain;
-      at head_dim 64 this is FASTER than the Pallas backward below,
-      whose (128-lane) head padding doubles every matmul's work.
+      (S x S) probabilities exist transiently but XLA fuses the chain.
+      It became the default when the Pallas backward below padded
+      head_dim 64 to 128 lanes (it no longer does); no chip record
+      compares the two (ROADMAP A6, C3).
     - ``bwd_impl="pallas"``: dq and dk/dv Pallas kernels accumulating in
       VMEM scratch — nothing (S x S) ever reaches HBM, the right regime
       for long sequences where the dense recompute stops fitting.
@@ -342,47 +462,39 @@ def flash_attention(q, k, v, causal: bool = True, scale=None,
 
 
 def _layout(q, k, v):
-    """Shared fwd/bwd padded (B*H, S_pad, D_pad) layout + positions."""
+    """Shared fwd/bwd (B*H, S_pad, D) layout: the lengths padded to 128
+    (a multiple of every tile) and the ``arange`` positions."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    sq_p, sk_p, d_p = (_round_up(sq, Q_TILE), _round_up(sk, KV_TILE),
-                       _round_up(d, LANE))
-
-    def to_bh(x, s, s_pad):                 # keeps dtype (bf16 stays bf16)
-        x = jnp.swapaxes(x, 1, 2).reshape(b * h, s, d)
-        return jnp.pad(x, ((0, 0), (0, s_pad - s), (0, d_p - d)))
-
-    qpos = jnp.pad(jnp.arange(sq, dtype=jnp.int32), (0, sq_p - sq))[None]
-    kpos = jnp.pad(jnp.arange(sk, dtype=jnp.int32), (0, sk_p - sk),
-                   constant_values=_PAD_POS)[None]
-    return (b, sq, sk, h, d, sq_p, sk_p, d_p, to_bh, qpos, kpos)
+    sq_p, sk_p = _round_up(sq, Q_TILE), _round_up(sk, KV_TILE)
+    qpos, kpos = _padded_positions(jnp.arange(sq), jnp.arange(sk),
+                                   sq_p, sk_p)
+    return (b, sq, sk, h, d, sq_p, sk_p, qpos, kpos)
 
 
 def _flash_fwd(q, k, v, causal, scale, interpret, bwd_impl):
-    (b, sq, sk, h, d, sq_p, sk_p, d_p, to_bh, qpos, kpos) = _layout(q, k, v)
+    (b, sq, sk, h, d, sq_p, sk_p, qpos, kpos) = _layout(q, k, v)
     scale_f = float(scale) if scale is not None else d ** -0.5
-    # the relayout around the kernel, the kernel (the jitted
-    # ``_flash_call``, named by itself in a trace) and the normalisation
-    # under names of their own, inside the caller's ``attn.core``
+    tq, tk = flash_tiles(sq, sk, d, q.dtype)
+    # the relayout around the kernel and the kernel (the jitted
+    # ``_flash_call``, named by itself in a trace, under a scope that
+    # says which tiles ran) under names of their own, inside the
+    # caller's ``attn.core``
     with jax.named_scope("flash.layout"):
-        qb, kb, vb = (to_bh(q, sq, sq_p), to_bh(k, sk, sk_p),
-                      to_bh(v, sk, sk_p))
-    o, m, l = _flash_call(qb, kb, vb, qpos, kpos,
-                          scale_f, causal, interpret)   # all f32 (BH,Sq_p,.)
-    with jax.named_scope("flash.norm"):
-        l_safe = jnp.maximum(l, 1e-30)
-        out_bh = o / l_safe                              # normalized
-        # lse = m + log l reconstructs p = exp(s - lse) tile-locally in
-        # the backward; fully-masked rows get +BIG so their p (and
-        # grads) are 0
-        lse_bh = jnp.where(l > 0, m + jnp.log(l_safe), 1e30)  # (BH,Sq_p,1)
-        out = out_bh[:, :sq, :d].reshape(b, h, sq, d).swapaxes(1, 2)
+        qb, kb, vb = _to_bh(q, sq_p), _to_bh(k, sk_p), _to_bh(v, sk_p)
+    with jax.named_scope(f"flash.t{tq}x{tk}"):
+        # normalized f32 (BH, Sq_p, D) and lse (BH, Sq_p, 1)
+        out_bh, lse_bh = _flash_call(
+            qb, kb, vb, qpos, kpos, scale_f, causal, interpret,
+            tiles=(tq, tk), normalize=True, arange_pos=True)
+    with jax.named_scope("flash.layout"):
+        out = out_bh[:, :sq].reshape(b, h, sq, d).swapaxes(1, 2)
         return out.astype(q.dtype), (q, k, v, out_bh, lse_bh)
 
 
 def _flash_bwd(causal, scale, interpret, bwd_impl, res, dout):
     q, k, v, out_bh, lse_bh = res
-    (b, sq, sk, h, d, sq_p, sk_p, d_p, to_bh, qpos, kpos) = _layout(q, k, v)
+    (b, sq, sk, h, d, sq_p, sk_p, qpos, kpos) = _layout(q, k, v)
     scale_f = float(scale) if scale is not None else d ** -0.5
 
     if bwd_impl == "xla":
@@ -390,15 +502,15 @@ def _flash_bwd(causal, scale, interpret, bwd_impl, res, dout):
             return _flash_bwd_xla(q, k, v, out_bh, lse_bh, dout, scale_f,
                                   causal)
 
-    do_bh = to_bh(dout, sq, sq_p)
+    do_bh = _to_bh(dout, sq_p)
     delta = jnp.sum(do_bh.astype(jnp.float32) * out_bh, axis=-1,
                     keepdims=True)                       # (BH, Sq_p, 1)
     dq, dk, dv = _flash_bwd_call(
-        to_bh(q, sq, sq_p), to_bh(k, sk, sk_p), to_bh(v, sk, sk_p),
+        _to_bh(q, sq_p), _to_bh(k, sk_p), _to_bh(v, sk_p),
         do_bh, lse_bh, delta, qpos, kpos, scale_f, causal, interpret)
 
     def from_bh(x, s):
-        return x[:, :s, :d].reshape(b, h, s, d).swapaxes(1, 2)
+        return x[:, :s].reshape(b, h, s, d).swapaxes(1, 2)
 
     return (from_bh(dq, sq).astype(q.dtype),
             from_bh(dk, sk).astype(k.dtype),
@@ -413,7 +525,7 @@ def _flash_bwd_xla(q, k, v, out_bh, lse_bh, dout, scale_f: float,
     b, sq, h, d = q.shape
     sk = k.shape[1]
     lse = lse_bh[:, :sq, 0].reshape(b, h, sq)        # (B, H, Sq)
-    out = out_bh[:, :sq, :d].reshape(b, h, sq, d).swapaxes(1, 2)
+    out = out_bh[:, :sq].reshape(b, h, sq, d).swapaxes(1, 2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale_f
     p = jnp.exp(s - lse[..., None])                  # (B, H, Sq, Sk)
@@ -444,11 +556,9 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 # Folded (feature-major) flash kernels — the short-head-dim regime
 # ---------------------------------------------------------------------------
 #
-# The kernels above put head_dim on the LANE axis, so head_dim 64 pads to
-# the 128-lane width: every DMA moves 2x the bytes and every d-output
-# matmul does 2x the work. That is exactly the regime of the train bench
-# (8 heads x 64), where the padded backward measures slower than XLA's
-# dense attention. The folded layout dodges the padding entirely:
+# The kernels above put head_dim on the LANE axis: at head_dim 64 a block
+# fills half of each 128-lane register and every d-output matmul uses
+# half the MXU's columns. The folded layout has no short lane axis:
 #
 #   q, k, v, o:  (B, H*Dh, S)   — heads*features on the SUBLANE axis
 #                                  (8-multiple, no 128 constraint),

@@ -20,6 +20,7 @@ compile on the host); run it alone::
 import dataclasses
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -72,6 +73,14 @@ def _abstract(tree, sharding):
 
 def _n_mosaic(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def _flash_call_names(compiled):
+    """Names of the Mosaic calls that are the flash forward kernel: XLA
+    names the instruction after the jitted ``_flash_call``, and the
+    benchmark finds the kernel in a trace by that name."""
+    return re.findall(r"%(\S*_flash_call\S*) = .* custom-call\(",
+                      compiled.as_text())
 
 
 # ---------------------------------------------------------------------------
@@ -135,6 +144,38 @@ class TestKernels:
         qkv = ((1, s, self.H, self.DH), jnp.float32)
         c = _compile_on_one(topo, flash_prefill_attention, qkv, qkv, qkv)
         assert _n_mosaic(c) == 1
+
+    # the benchmark's cells at their real shapes: Mosaic takes the
+    # 64-lane blocks, the tiles ``flash_tiles`` picks fit VMEM, and the
+    # instruction keeps the name the benchmark's readers look for
+
+    @pytest.mark.parametrize("bwd_impl,n_kernels", [("xla", 1),
+                                                    ("pallas", 3)])
+    def test_flash_attention_pretrain_2k(self, topo, bwd_impl, n_kernels):
+        """``pythia-410m.pretrain-2k``: (4, 2048, 16, 64) bf16, forward
+        and gradient."""
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        qkv = ((4, 2048, 16, 64), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return jnp.sum(PA.flash_attention(
+                q, k, v, True, None, False, bwd_impl).astype(jnp.float32))
+
+        c = _compile_on_one(topo, jax.grad(loss, argnums=(0, 1, 2)),
+                            qkv, qkv, qkv)
+        assert _n_mosaic(c) == n_kernels
+        assert _flash_call_names(c)
+
+    @pytest.mark.parametrize("s", [16, 128, 512, 1024])
+    def test_flash_prefill_chat_closed(self, topo, s):
+        """``pythia-1.4b.chat-closed``'s prefill buckets: (1, S, 16,
+        128) f32."""
+        from mmlspark_tpu.parallel.pallas_attention import (
+            flash_prefill_attention)
+        qkv = ((1, s, 16, 128), jnp.float32)
+        c = _compile_on_one(topo, flash_prefill_attention, qkv, qkv, qkv)
+        assert _n_mosaic(c) == 1
+        assert _flash_call_names(c)
 
     @pytest.mark.parametrize("s", [1, 16, 128, FULL.max_len])
     def test_paged_prefix_prefill_attention(self, topo, s):

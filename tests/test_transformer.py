@@ -126,20 +126,87 @@ class TestRingAttention:
             assert float(jnp.max(fl2)) == 0.0
             assert float(jnp.max(jnp.abs(fo2))) == 0.0
 
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("relation", ["earlier", "equal", "later"])
+    @pytest.mark.parametrize("sk", [128, 200, 1100])
+    def test_flash_block_partials_ring_contract(self, rng, causal,
+                                                relation, sk):
+        """``flash_block_attn``'s (m, l, o-unnormalized) partials
+        against the dense block, as a ring step calls it: the queries'
+        global positions against a KV block that started earlier in the
+        sequence (all visible: no tile needs the mask), at the same
+        place (the diagonal) or later (nothing visible: ``l`` and ``o``
+        stay 0 for every row). Key lengths that pad (200 -> 256 in one
+        tile; 1100 -> 1152 in three tiles of 384) keep the padding out
+        of every row."""
+        from mmlspark_tpu.parallel.ring_attention import _block_attn
+        from mmlspark_tpu.parallel.pallas_attention import (
+            flash_block_attn)
+        B, S, H, D = 1, 200, 2, 16
+        q = jnp.asarray(rng.normal(size=(B, S, H, D)).astype(np.float32))
+        k, v = (jnp.asarray(
+            rng.normal(size=(B, sk, H, D)).astype(np.float32))
+            for _ in range(2))
+        base = 4096
+        q_pos = base + jnp.arange(S)
+        k_pos = {"earlier": base - sk, "equal": base,
+                 "later": base + S}[relation] + jnp.arange(sk)
+        scale = D ** -0.5
+        rm, rl, ro = _block_attn(q, k, v, scale, q_pos, k_pos, causal)
+        fm, fl, fo = flash_block_attn(q, k, v, scale, q_pos, k_pos,
+                                      causal, interpret=True)
+        assert fo.shape == (B, S, H, D) and fl.shape == (B, H, S)
+        np.testing.assert_allclose(np.asarray(fl), np.asarray(rl),
+                                   rtol=1e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(fo), np.asarray(ro),
+                                   rtol=1e-5, atol=2e-5)
+        seen = np.asarray(rl) > 0
+        np.testing.assert_allclose(np.asarray(fm)[seen],
+                                   np.asarray(rm)[seen], atol=2e-5)
+        if causal and relation == "later":
+            assert float(jnp.max(fl)) == 0.0
+            assert float(jnp.max(jnp.abs(fo))) == 0.0
+        if causal and relation == "equal":
+            # row 0 sees key 0 only; with sk > S every row still has a
+            # live key, with padded keys never among them
+            assert np.all(seen)
+
 
 class TestFlashAttentionVJP:
     """The differentiable Pallas flash kernel (interpret mode) must match
     dense attention in value AND gradients — it is the kernel the
     single-chip train path runs on TPU (`transformer._attention`)."""
 
+    # (B, S, H, Dh), dtype -> the tiles ``flash_tiles`` picks for it:
+    # one padded 128 tile; one 384 and one 640 tile (not powers of two);
+    # a 3 x 3 grid of 384 tiles with a padded remainder (52 keys of the
+    # last tile are padding, three tile pairs dead under causal); a
+    # 2 x 2 grid of 640 tiles; one 1024 tile; f32 at head_dim 128 (the
+    # prefill's operands) in one 1024 tile
+    SHAPES = {
+        "s48-d16-f32": ((2, 48, 2, 16), "float32", (128, 128)),
+        "s384-d64-bf16": ((1, 384, 2, 64), "bfloat16", (384, 384)),
+        "s640-d16-bf16": ((1, 640, 1, 16), "bfloat16", (640, 640)),
+        "s1100-d16-bf16": ((1, 1100, 1, 16), "bfloat16", (384, 384)),
+        "s1280-d64-bf16": ((1, 1280, 1, 64), "bfloat16", (640, 640)),
+        "s1024-d64-bf16": ((1, 1024, 1, 64), "bfloat16", (1024, 1024)),
+        "s1024-d128-f32": ((1, 1024, 1, 128), "float32", (1024, 1024)),
+    }
+
     @pytest.mark.parametrize("causal", [True, False])
     @pytest.mark.parametrize("bwd_impl", ["xla", "pallas"])
-    def test_value_and_grads_match_dense(self, rng, causal, bwd_impl):
-        from mmlspark_tpu.parallel.pallas_attention import flash_attention
-        q, k, v = (jnp.asarray(
-            rng.normal(size=(2, 48, 2, 16)).astype(np.float32))
-            for _ in range(3))   # unaligned S/Dh exercise tile padding
-        w = jnp.asarray(rng.normal(size=(2, 48, 2, 16)).astype(np.float32))
+    @pytest.mark.parametrize("case", list(SHAPES))
+    def test_value_and_grads_match_dense(self, rng, causal, bwd_impl, case):
+        from mmlspark_tpu.parallel.pallas_attention import (
+            flash_attention, flash_tiles)
+        shape, dtype, tiles = self.SHAPES[case]
+        assert flash_tiles(shape[1], shape[1], shape[3], dtype) == tiles
+        q, k, v = (jnp.asarray(rng.normal(size=shape), dtype)
+                   for _ in range(3))
+        w = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+        # bf16 operands: p is rounded to bf16 for P.V (and ds for the
+        # gradients), 2^-9 relative an element
+        atol_v, atol_g = (2e-5, 5e-5) if dtype == "float32" else (3e-2, 6e-2)
 
         def loss_flash(q, k, v):
             return jnp.sum(
@@ -148,15 +215,48 @@ class TestFlashAttentionVJP:
         def loss_dense(q, k, v):
             return jnp.sum(dense_attention(q, k, v, causal=causal) * w)
 
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
         out_f = flash_attention(q, k, v, causal, None, True, bwd_impl)
-        out_d = dense_attention(q, k, v, causal=causal)
-        np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d),
-                                   atol=2e-5)
+        out_d = dense_attention(*f32, causal=causal)
+        assert out_f.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(out_f, np.float32),
+                                   np.asarray(out_d), atol=atol_v)
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+        gd = jax.grad(loss_dense, argnums=(0, 1, 2))(*f32)
         for a, b, name in zip(gf, gd, "qkv"):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-5, err_msg=f"d{name}")
+            a, b = np.asarray(a, np.float32), np.asarray(b)
+            np.testing.assert_allclose(a, b, atol=atol_g,
+                                       err_msg=f"d{name}")
+            # no systematic error hides under the elementwise tolerance
+            assert np.abs(a - b).mean() <= atol_g / 10, f"d{name}"
+
+    # the two cells' calls, every prefill bucket of the serve cell
+    # (B = 1, 16 heads x 128, f32), and shapes that pad
+    @pytest.mark.parametrize("sq,sk,d,dtype,want", [
+        (2048, 2048, 64, "bfloat16", (1024, 1024)),     # pretrain-2k
+        (16, 16, 128, "float32", (128, 128)),
+        (32, 32, 128, "float32", (128, 128)),
+        (64, 64, 128, "float32", (128, 128)),
+        (128, 128, 128, "float32", (128, 128)),
+        (256, 256, 128, "float32", (256, 256)),
+        (512, 512, 128, "float32", (512, 512)),
+        (1024, 1024, 128, "float32", (1024, 1024)),
+        (48, 96, 16, "float32", (128, 128)),
+        (130, 300, 64, "bfloat16", (256, 384)),
+        (4096, 4096, 128, "bfloat16", (1024, 1024)),
+        (1024, 2048, 512, "float32", (512, 1024)),      # VMEM binds
+    ])
+    def test_flash_tiles(self, sq, sk, d, dtype, want):
+        """The pure tile choice: tiles divide the lengths padded to 128
+        (never further), fit the VMEM budget, and are as large as both
+        allow; 128 x 128 is the floor."""
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        tq, tk = PA.flash_tiles(sq, sk, d, dtype)
+        assert (tq, tk) == want
+        assert PA._round_up(sq, 128) % tq == 0
+        assert PA._round_up(sk, 128) % tk == 0
+        assert (tq, tk) == (128, 128) or PA._flash_vmem_bytes(
+            tq, tk, d, jnp.dtype(dtype).itemsize) <= PA._FLASH_VMEM_BUDGET
 
     @pytest.mark.parametrize("causal", [True, False])
     @pytest.mark.slow
