@@ -1416,6 +1416,34 @@ def paged_prefix_prefill_attention(q, k_pages, v_pages, page_table,
     return out.transpose(1, 0, 2)[:s]
 
 
+def eva_prefill_attention(q, k, v, ks, vs, n_summary, scale: float,
+                          interpret: bool = False):
+    """One window's EVA attention (``models/evabyte.py``): queries
+    ``q`` [S, H, Dh] over the tile's own ``k``/``v`` [S, H, Dh] causally
+    and over the first ``n_summary`` (traced) of the summary rows
+    ``ks``/``vs`` [M, H, Dh], all in ONE softmax: the forward flash
+    kernel over the concatenated keys, the visibility carried by the
+    position operands (a live summary sits at position -1, before every
+    query; a dead one at the padding sentinel). Returns float32
+    [S, H, Dh], normalised."""
+    s, h, d = q.shape
+    m = ks.shape[0]
+    k_pos = jnp.concatenate([
+        jnp.where(jnp.arange(m) < n_summary, -1, _PAD_POS).astype(jnp.int32),
+        jnp.arange(s, dtype=jnp.int32)])
+    sq_p, sk_p = _round_up(s, Q_TILE), _round_up(m + s, KV_TILE)
+    qpos_p, kpos_p = _padded_positions(jnp.arange(s), k_pos, sq_p, sk_p)
+    tiles = flash_tiles(s, m + s, d, q.dtype)
+    with jax.named_scope(f"flash.t{tiles[0]}x{tiles[1]}"):
+        o, _ = _flash_call(
+            _to_bh(q[None], sq_p),
+            _to_bh(jnp.concatenate([ks, k])[None], sk_p),
+            _to_bh(jnp.concatenate([vs, v])[None], sk_p),
+            qpos_p, kpos_p, float(scale), True, interpret, tiles=tiles,
+            normalize=True)
+    return o[:, :s].swapaxes(0, 1)
+
+
 def flash_prefill_available() -> bool:
     """Whether the flash prefill kernels can run compiled on this
     backend (TPU); everywhere else the dense-softmax paths are the

@@ -24,7 +24,7 @@ from mmlspark_tpu.serving.capture import TrafficCapture
 from mmlspark_tpu.serving.consolidator import PartitionConsolidator
 from mmlspark_tpu.serving.decode import (
     DecodeOverloaded, DecodeScheduler, PagePool, PrefixCache, Sampler,
-    SlotPool, TransformerDecoder,
+    SlotPool, TransformerDecoder, decoder_for,
 )
 from mmlspark_tpu.serving.frontend import EventLoopFrontend
 from mmlspark_tpu.serving.incident import FanoutNotifier, IncidentManager
@@ -44,7 +44,7 @@ __all__ = ["ServingServer", "ServingCoordinator", "ServingClient",
            "ModelVersionManager", "RolloutError", "RolloutOrchestrator",
            "DecodeScheduler", "DecodeOverloaded", "SlotPool", "PagePool",
            "PrefixCache",
-           "TransformerDecoder", "AdaptiveBatchPolicy",
+           "TransformerDecoder", "decoder_for", "AdaptiveBatchPolicy",
            "QuantizationConfig",
            "SpeculationPolicy", "Sampler", "TrafficCapture",
            "Tenant", "TenantRegistry", "TokenBucket", "FairCycle",

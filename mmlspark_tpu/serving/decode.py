@@ -334,6 +334,42 @@ class TransformerDecoder:
                 cache_sharding=cache_sharding,
                 with_scores=True, ce_impl=self.verify_ce_impl)
 
+    #: the softmax block has ONE kind of cache row (a K/V row a
+    #: position, kept for ever); a decoder with a second kind states a
+    #: window here (``serving/eva_decode.py``)
+    window: Optional[int] = None
+
+    def rows_at(self, pos) -> "tuple[Any, Any]":
+        """``(summary_rows, window_rows)`` a slot holds once the row of
+        position ``pos`` is written: what the scheduler's admission,
+        growth and release count (an int or an array of positions)."""
+        return pos * 0, pos + 1
+
+    def pages_for(self, pos: int) -> "tuple[int, int]":
+        """Pages of each kind covering :meth:`rows_at`."""
+        return 0, max(-(-(int(pos) + 1) // self.page_size), 1)
+
+    def prefill_pages(self, prompt_len: int) -> "tuple[int, int]":
+        """The most pages a prefill of ``prompt_len`` holds at once,
+        the first generated row included."""
+        return self.pages_for(prompt_len)
+
+    def prefill_facts(self, prompt_len: int) -> Dict[str, int]:
+        """What a ``decode.prefill`` span carries beyond the
+        scheduler's own attributes: nothing, for this kind."""
+        return {}
+
+    #: windows turned into summary rows: none, for this kind
+    n_compactions = 0
+
+    def lane(self, sum_pages, win_pages) -> np.ndarray:
+        """A slot's page-table row from the pages it holds: the one
+        place that says where each kind of page stands in the row (one
+        kind here, in order of position)."""
+        row = np.zeros(self.pages_per_slot, np.int32)
+        row[:len(win_pages)] = win_pages
+        return row
+
     @property
     def has_draft(self) -> bool:
         return self._verify is not None
@@ -575,6 +611,20 @@ class TransformerDecoder:
                 np.zeros((self.n_slots, self.spec_k), np.int32),
                 zeros_t.copy(), zero_tables)
         return self.n_compiles()
+
+def decoder_for(params, cfg, **kwargs):
+    """The decoder of ``cfg``'s block kind: the configuration object
+    says which block it describes (``block_kind``; a config without
+    one is the softmax block), and the serving plane builds the decoder
+    that holds that kind's cache."""
+    kind = getattr(cfg, "block_kind", "softmax")
+    if kind == "eva":
+        from mmlspark_tpu.serving.eva_decode import EvaByteDecoder
+        return EvaByteDecoder(params, cfg, **kwargs)
+    if kind != "softmax":
+        raise ValueError(f"no decoder for block kind {kind!r}")
+    return TransformerDecoder(params, cfg, **kwargs)
+
 
 class Sampler:
     """Per-request seeded token sampling over the step's full logits.
@@ -1115,7 +1165,7 @@ _MAX_TIMELINE_SPANS = 128
 #: the keys of ``/decode/stats`` -> ``loop`` and of a ``decode.pass``
 #: span's ``phases_ms``
 LOOP_PHASES = ("admit", "prefill", "prepare", "dispatch", "fetch",
-               "emit", "idle")
+               "emit", "compact", "idle")
 #: the route ``decode.pass`` spans are captured under, and how many
 #: times the running median of the passes that ran a step a pass must
 #: last to be retained as slow (``GET /traces``)
@@ -1154,7 +1204,7 @@ class _DecodeRequest:
     __slots__ = ("pending", "prompt", "max_new", "produced", "slot",
                  "cancelled", "t_submit", "t_prefill", "t_decode",
                  "t_first", "t_last", "n_timeline",
-                 "sampler", "spec", "pages", "hit_len")
+                 "sampler", "spec", "pages", "sum_pages", "hit_len")
 
     def __init__(self, pending, prompt: np.ndarray, max_new: int,
                  sampler: Optional[Sampler] = None,
@@ -1173,6 +1223,10 @@ class _DecodeRequest:
         self.pages: List[int] = []          # held KV pages (paged):
         # the first hit_len // page_size are SHARED prefix pages
         # (ref'd, read-only), the rest privately claimed
+        # pages of the second kind of row (a finished window's
+        # summaries; decoders with a ``window``): ahead of ``pages`` in
+        # the slot's table, kept until the request leaves
+        self.sum_pages: List[int] = []
         self.hit_len = 0                    # cached-prefix depth
         self.cancelled = False
         self.t_submit: float = 0.0
@@ -1624,11 +1678,6 @@ class DecodeScheduler:
             return None
         return Sampler(float(temp), int(top_k), float(top_p), seed)
 
-    def _pages_for(self, rows: int) -> int:
-        """Pages covering virtual rows ``[0, rows)``."""
-        ps = self.decoder.page_size
-        return max((int(rows) + ps - 1) // ps, 1)
-
     def _claim_pages(self, n: int) -> Optional[List[int]]:
         """Claim ``n`` fresh pages, evicting LRU unreferenced cached
         pages first when the free list alone cannot cover it."""
@@ -1650,6 +1699,9 @@ class DecodeScheduler:
         suspect, and poisoning the index would wrong every future
         match."""
         pages, req.pages = req.pages, []
+        if req.sum_pages:
+            self.pages.release(req.sum_pages)
+            req.sum_pages = []
         absorbed = set()
         if self.prefix is not None and publish:
             absorbed = self.prefix.publish(
@@ -1693,7 +1745,7 @@ class DecodeScheduler:
             # slot, and _admit_waiting re-checks — but it turns a
             # full pool into an honest 429 instead of a queued
             # request that can never start.
-            need = self._pages_for(len(prompt) + 1)
+            need = sum(self.decoder.prefill_pages(len(prompt)))
             # cache-full admission sheds BEFORE touching shared state:
             # cached pages count as reclaimable headroom (eviction
             # frees them at claim time), but no lookup, ref, or
@@ -1772,7 +1824,7 @@ class DecodeScheduler:
                            slot=req.slot, n_tokens=len(req.produced),
                            finish_reason=reason)
             req.slot = None
-        if req.pages:
+        if req.pages or req.sum_pages:
             self._release_pages(req, publish=reason != "error")
         with self._lock:
             self._by_rid.pop(req.pending.rid, None)
@@ -2038,6 +2090,7 @@ class DecodeScheduler:
                 continue
             pages: List[int] = []
             hit_len = 0
+            n_sum = 0
             if self.pages is not None:
                 shared: List[int] = []
                 if self.prefix is not None:
@@ -2045,8 +2098,11 @@ class DecodeScheduler:
                     # ref'd — on any bail-out below they are released
                     # (the cache keeps its own reference)
                     hit_len, shared = self.prefix.lookup(req.prompt)
-                own = self._claim_pages(
-                    self._pages_for(len(req.prompt) + 1) - len(shared))
+                # rows as the decoder counts them: the summary pages
+                # the prompt leaves, then the most window pages the
+                # prefill holds at once
+                n_sum, n_win = self.decoder.prefill_pages(len(req.prompt))
+                own = self._claim_pages(n_sum + n_win - len(shared))
                 if own is None:
                     # not enough pages YET: head-of-line waits for
                     # running requests to release theirs (it looks up
@@ -2070,8 +2126,8 @@ class DecodeScheduler:
                 self.prefix.count(hit_len)
             table = None
             if self._tables is not None:
-                self._tables[slot, :] = 0
-                self._tables[slot, :len(pages)] = pages
+                self._tables[slot] = self.decoder.lane(pages[:n_sum],
+                                                       pages[n_sum:])
                 table = self._tables[slot]
             bucket = bucket_target(len(req.prompt) - hit_len,
                                    self.decoder.max_len)
@@ -2079,6 +2135,7 @@ class DecodeScheduler:
                       prompt_len=len(req.prompt), prefix_hit=hit_len,
                       slot=slot, others_active=len(self._active),
                       trace=getattr(p, "trace", None))
+            sp.attrs.update(self.decoder.prefill_facts(len(req.prompt)))
             try:
                 with sp:
                     sp.attrs["queue_wait_ms"] = (
@@ -2138,6 +2195,15 @@ class DecodeScheduler:
                            prompt_len=len(req.prompt),
                            prefix_hit=hit_len)
             req.slot = slot
+            if pages:
+                # windows a prefill walked are compacted by now: what
+                # stays claimed is what the first step needs (all of
+                # it, where rows are one a position)
+                keep = n_sum + self.decoder.pages_for(len(req.prompt))[1]
+                if len(pages) > keep:
+                    self.pages.release(pages[keep:])
+                req.sum_pages, pages = pages[:n_sum], pages[n_sum:keep]
+                self._tables[slot] = self.decoder.lane(req.sum_pages, pages)
             req.pages = pages
             req.hit_len = hit_len
             req.produced.append(first)
@@ -2207,7 +2273,7 @@ class DecodeScheduler:
         ``upto_pos``; False when the pool cannot (caller decides:
         preempt for the step's own row, degrade to non-speculative
         for lookahead rows)."""
-        need = self._pages_for(upto_pos + 1)
+        need = self.decoder.pages_for(upto_pos)[1]
         have = len(req.pages)
         if need <= have:
             return True
@@ -2216,9 +2282,44 @@ class DecodeScheduler:
         got = self._claim_pages(need - have)
         if got is None:
             return False
-        self._tables[req.slot, have:need] = got
         req.pages.extend(got)
+        self._tables[req.slot] = self.decoder.lane(req.sum_pages, req.pages)
         return True
+
+    def _compact_filled_windows(self) -> None:
+        """After a step: a slot whose step wrote its window's last row
+        turns that window into summary rows (``decoder.compact``: the
+        second kind of row, in pages claimed here and kept until the
+        request leaves) and gives the window's pages back. A pool that
+        cannot hold the summaries ends the request like any other
+        growth (``pages_exhausted``); a compaction that raises ends it
+        like a failed step."""
+        window = self.decoder.window
+        for slot, req in list(self._active.items()):
+            if int(self._pos[slot]) % window:
+                continue
+            with span("decode.compact", slot=slot,
+                      pos=int(self._pos[slot])) as sp:
+                new = self._claim_pages(
+                    self.decoder.summary_pages_per_window)
+                if new is None:
+                    self.n_page_preempts += 1
+                    self._finish(req, "pages_exhausted")
+                    continue
+                try:
+                    self.decoder.compact(req.pages, new)
+                except Exception as e:  # noqa: BLE001 — as a failed step
+                    self.pages.release(new)
+                    self.n_step_faults += 1
+                    logger.warning("compaction failed", exc_info=True)
+                    self._finish(req, "error", status=500,
+                                 error=f"compaction failed: {e}")
+                    continue
+                self.pages.release(req.pages)
+                req.pages = []
+                req.sum_pages.extend(new)
+                self._tables[slot] = self.decoder.lane(req.sum_pages, ())
+                sp.attrs["pages_returned"] = self.decoder.window_pages
 
     def _prepare_round(self):
         """Pre-step upkeep: reap dead slots, grow pages for every
@@ -2265,6 +2366,15 @@ class DecodeScheduler:
                 spec[slot] = req
         return spec
 
+    def _live_rows(self) -> "tuple[int, int]":
+        """``(summary_rows, window_rows)`` over the live slots, as the
+        decoder counts them at each slot's position."""
+        live = list(self._active)
+        if not live:
+            return 0, 0
+        n_sum, n_win = self.decoder.rows_at(self._pos[live])
+        return int(np.sum(n_sum)), int(np.sum(n_win))
+
     def _device_interval(self, i0: int) -> "tuple[float, float]":
         """Seconds (the tracer's clock) from the start of the first to
         the end of the last ``decode.dispatch``/``decode.fetch`` span
@@ -2280,10 +2390,13 @@ class DecodeScheduler:
         with span("decode.prepare") as sp:
             spec = self._prepare_round()
             paged = self.pages is not None
+            sum_rows, win_rows = self._live_rows()
             sp.attrs = {
                 "active": len(self._active),
                 "pages_in_use": self._pages_in_use() if paged else None,
                 "n_pages": self.pages.n_pages - 1 if paged else None,
+                # the rows this step reads, by kind
+                "window_rows": win_rows, "summary_rows": sum_rows,
                 "traces": [getattr(r.pending, "trace", None)
                            for r in self._active.values()]}
         if not self._active:
@@ -2349,6 +2462,8 @@ class DecodeScheduler:
                 self._emit_stream(req, [tok])
                 self._retire_if_done(req, tok)
             sp.attrs = {"emitted": len(live)}
+        if self.decoder.window:
+            self._compact_filled_windows()
 
     def _run_spec_round(self, spec: Dict[int, _DecodeRequest]) -> None:
         """One speculative round: draft proposes ``spec_k`` tokens per
@@ -2514,12 +2629,13 @@ class DecodeScheduler:
             waiting = len(self._waiting)
             active = sorted(self._active.items())
             releases = dict(self.releases)
+            rows = self._live_rows()
         slots = [{"slot": s,
                   "rid": r.pending.rid,
                   "prompt_len": int(len(r.prompt)),
                   "n_tokens": len(r.produced),   # incremental progress
                   "max_new_tokens": r.max_new,
-                  "n_pages": len(r.pages),
+                  "n_pages": len(r.pages) + len(r.sum_pages),
                   "prefix_hit_tokens": r.hit_len,
                   "streaming": r.stream is not None,
                   "sampling": (r.sampler.describe()
@@ -2542,7 +2658,7 @@ class DecodeScheduler:
                      "high_water": self.pages.high_water,
                      "n_preempts": self.n_page_preempts,
                      "pool_bytes": tree_bytes(self.decoder.cache),
-                     "per_slot": {str(s): len(r.pages)
+                     "per_slot": {str(s): len(r.pages) + len(r.sum_pages)
                                   for s, r in active}}
         spec = None
         if self.decoder.has_draft:
@@ -2622,6 +2738,12 @@ class DecodeScheduler:
                 "loop": {k[7:]: {"n": n, "s": round(ns * 1e-9, 6)}
                          for k, (n, ns) in self.loop.items()},
                 "n_step_faults": self.n_step_faults,
+                # the two kinds of cache row (docs/serving.md "Two
+                # kinds of row"): windows turned into summaries so far
+                # (after a step or inside a prefill: the decoder counts
+                # both), and the rows the live slots hold now
+                "n_compactions": self.decoder.n_compactions,
+                "window_rows": rows[1], "summary_rows": rows[0],
                 "n_compiles": self.decoder.n_compiles(),
                 # the live honest-429 inputs: slot-release gap EWMA
                 # and the Retry-After a shed client would be told now

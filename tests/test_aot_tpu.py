@@ -309,3 +309,66 @@ class TestServePrograms:
         for name, c in progs.items():
             # one attention kernel per layer in every program
             assert _n_mosaic(c) == self.SZ.n_layers, name
+
+
+class TestEvaBytePrograms:
+    """The EVA block kind's programs at the shapes of the cell
+    ``evabyte-6.5b.doc-closed`` (``benchmark/configs/evabyte-6.5b.json``:
+    every width, 8 slots, 1,537 pages of 16 rows, 64 summary + 128
+    window pages a slot), depth cut to 2: the layers repeat one program
+    text, and each layer's pool is an array of its own. What the chip's
+    compiler has to take: ``paged_decode_attention`` over bfloat16 pages,
+    the flash forward kernel over [1,024 summary rows | the tile] with
+    the visibility in its positions, and no temporary of a pool's size
+    (the softmax block's programs copy the pool: PERF.md, fault (b))."""
+
+    N_SLOTS, PAGE, N_PAGES = 8, 16, 1537
+    SUM_PAGES, WIN_PAGES = 64, 128
+
+    @pytest.fixture(scope="class")
+    def eva(self, topo):
+        from mmlspark_tpu.models import evabyte as E
+        one = NamedSharding(_mesh(topo, {"x": 1}), P())
+        cfg = E.EvaByteConfig(n_layers=2)
+        params = _abstract(jax.eval_shape(
+            lambda: E.init_params(cfg, 0)), one)
+        cache = _abstract(jax.eval_shape(
+            lambda: E.init_cache(cfg, self.N_PAGES, self.PAGE)), one)
+
+        def ints(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+        return E, cfg, params, cache, ints
+
+    @staticmethod
+    def _pool_bytes(cfg, n_pages, page):
+        return n_pages * page * cfg.n_heads * cfg.d_head * 2
+
+    def test_step(self, eva):
+        E, cfg, params, cache, ints = eva
+        c = E.build_eva_step(cfg, self.PAGE, attn_impl="pallas").lower(
+            params, cache, ints(self.N_SLOTS), ints(self.N_SLOTS),
+            ints(self.N_SLOTS, self.SUM_PAGES + self.WIN_PAGES)).compile()
+        assert _n_mosaic(c) == cfg.n_layers
+        assert "paged_decode_attention" in c.as_text()
+        assert c.memory_analysis().temp_size_in_bytes \
+            < self._pool_bytes(cfg, self.N_PAGES, self.PAGE) // 4
+
+    @pytest.mark.parametrize("tile", [128, 2048])
+    def test_window_prefill(self, eva, tile):
+        E, cfg, params, cache, ints = eva
+        c = E.build_eva_prefill(cfg, self.PAGE, attn_impl="pallas").lower(
+            params, cache, ints(tile), ints(self.SUM_PAGES),
+            ints(self.WIN_PAGES), ints(), ints()).compile()
+        assert _n_mosaic(c) == cfg.n_layers
+        assert "_flash_call" in c.as_text()
+        assert c.memory_analysis().temp_size_in_bytes \
+            < self._pool_bytes(cfg, self.N_PAGES, self.PAGE)
+
+    def test_compaction(self, eva):
+        E, cfg, params, cache, ints = eva
+        summ = [{"phi": b["phi"], "mu": b["mu"]} for b in params["blocks"]]
+        c = E.build_eva_compact(cfg, self.PAGE).lower(
+            summ, cache, ints(self.WIN_PAGES),
+            ints(cfg.summaries_per_window // self.PAGE)).compile()
+        assert c.memory_analysis().temp_size_in_bytes \
+            < self._pool_bytes(cfg, self.N_PAGES, self.PAGE) // 4

@@ -95,7 +95,10 @@ def test_scheduler_run_leaves_whole_passes(run, what):
                            and sp.parent_id is None for sp in passes)
     if what == "phases_present":
         seen = set().union(*(sp.view["phases_ms"] for sp in passes))
-        assert seen == set(LOOP_PHASES)
+        # ``compact`` is the phase of decoders with a second kind of
+        # cache row (tests/test_serving_decode.py TestTwoRowKinds): the
+        # softmax block never opens it
+        assert seen == set(LOOP_PHASES) - {"compact"}
         for sp in stepped:
             assert set(STEP_PHASES) <= set(sp.view["phases_ms"])
         # every pass under a trace id of its own, steps in sequence

@@ -963,8 +963,11 @@ class TestPagedScheduler:
         rng = np.random.default_rng(22)
         eos_prompt = _prompt(rng, 3)
         eos = _greedy_reference(eos_prompt, 3)[1]
+        # a lane long enough that the requests this test cancels and
+        # lets expire are still decoding when it does (2,044 steps to
+        # the lane's end)
         sched = DecodeScheduler(
-            _decoder(n_slots=3, max_len=256, paged=True, page_size=8,
+            _decoder(n_slots=3, max_len=2048, paged=True, page_size=8,
                      eos_id=eos),
             clock=clock).start()
         try:
@@ -985,6 +988,11 @@ class TestPagedScheduler:
                 sched.submit(p)
             for p in waves[0]:
                 assert p.event.wait(30)
+            # the EOS wave is done: no later request may end on the
+            # stop token by accident (of this seed's prompts, the one
+            # meant to expire emits it as its FIRST token and the one
+            # meant to be cancelled ran to its lane's end unobserved)
+            sched.decoder.eos_id = None
             for p in waves[1]:
                 sched.submit(p)
             t_end = time.monotonic() + 10
@@ -1004,10 +1012,12 @@ class TestPagedScheduler:
             for p in waves[2]:
                 assert p.event.wait(30)
             sched.fault_plan = None
-            reasons = {json.loads(p.reply)["finish_reason"]
+            reasons = {p.rid: json.loads(p.reply)["finish_reason"]
                        for wave in waves for p in wave}
-            assert {"eos", "length", "cancelled", "deadline",
-                    "error"} <= reasons
+            assert reasons == {"w-eos": "eos", "w-len": "length",
+                               "w-cancel": "cancelled",
+                               "w-deadline": "deadline",
+                               "w-fault": "error"}
         finally:
             sched.stop()
         assert sched.pool.n_free == 3
@@ -1090,6 +1100,176 @@ class TestPagedScheduler:
         dec = _decoder(max_len=32)
         assert dec.prompt_buckets() == bucket_ladder(32) == sorted(
             {bucket_target(n, 32) for n in range(1, 33)})
+
+
+class TestTwoRowKinds:
+    """The cache manager with a decoder that holds TWO kinds of row in
+    the one page pool (the EVA block kind, ``serving/eva_decode.py``:
+    window 16, chunk 4, pages of 4 rows, so a window is 4 pages and
+    leaves 1 summary page): rows of both kinds claimed, compacted and
+    released, counted as the decoder says they are, on the release
+    path every request shares."""
+
+    E_CFG = None
+
+    @classmethod
+    def _decoder(cls, **kw):
+        from mmlspark_tpu.models import evabyte as E
+        from mmlspark_tpu.serving.decode import decoder_for
+        if cls.E_CFG is None:
+            cls.E_CFG = E.EvaByteConfig(
+                vocab=64, d_model=16, n_heads=2, d_head=8, d_ff=32,
+                n_layers=2, window=16, chunk=4, n_pred_heads=2,
+                init_std=0.2, dtype="float32")
+            cls.E_PARAMS = E.init_params(cls.E_CFG, seed=3)
+        kw = dict(dict(n_slots=2, max_len=64, page_size=4,
+                       attn_impl="dense"), **kw)
+        return decoder_for(cls.E_PARAMS, cls.E_CFG, **kw)
+
+    def test_pages_of_both_kinds_by_position(self):
+        """A request at position ``pos`` holds ``pos // 16`` summary
+        pages and the pages of ``pos % 16 + 1`` window rows: 40 rows of
+        one kind would be 10 pages, here the most is 2 + 4."""
+        sched = DecodeScheduler(self._decoder()).start()
+        rng = np.random.default_rng(41)
+        seen = {}
+        inner = sched.decoder.step_logits
+
+        def step_logits(tokens, pos, tables=None):
+            req = sched._active.get(0)
+            if req is not None:
+                seen[int(pos[0])] = (len(req.sum_pages), len(req.pages),
+                                     sched.pages.n_claimed)
+            return inner(tokens, pos, tables)
+
+        sched.decoder.step_logits = step_logits
+        try:
+            p = _Pending({"prompt": _prompt(rng, 21),
+                          "max_new_tokens": 20}, "two-kinds")
+            sched.submit(p)
+            assert p.event.wait(30)
+            stats = sched.stats()
+        finally:
+            sched.stop()
+        assert json.loads(p.reply)["finish_reason"] == "length"
+        for pos, (n_sum, n_win, claimed) in seen.items():
+            assert (n_sum, n_win) == (pos // 16, (pos % 16) // 4 + 1), pos
+            assert claimed == n_sum + n_win
+        assert sorted(seen) == list(range(21, 40))
+        # the prompt's first window inside the prefill, the second by
+        # the loop after the step at position 31
+        assert stats["n_compactions"] == 2
+        assert stats["loop"]["compact"]["n"] == 1
+        assert stats["window_rows"] == 0 and stats["summary_rows"] == 0
+        # the compaction at 32 holds the full window, the first
+        # window's summary page and the new one for a moment
+        assert sched.pages.high_water == 1 + 4 + 1
+        assert _pages_idle(sched)
+
+    @pytest.mark.parametrize("reason", ["length", "eos", "cancelled",
+                                        "deadline", "error",
+                                        "pages_exhausted"])
+    def test_pool_back_to_its_start_after_every_release_reason(
+            self, reason):
+        """Both kinds of page come back whatever ends the request, and
+        a request that ends past a compaction held summary pages."""
+        clock = ManualClock()
+        rng = np.random.default_rng(42)
+        prompt = _prompt(rng, 20)
+        kw = {}
+        if reason == "pages_exhausted":
+            # one window's pages and one summary page, and not the
+            # second summary page the compaction at position 32 needs
+            kw["n_pages"] = 1 + 4 + 1
+        dec = self._decoder(**kw)
+        if reason == "eos":
+            probe = DecodeScheduler(dec).start()
+            p = _Pending({"prompt": prompt, "max_new_tokens": 16}, "probe")
+            probe.submit(p)
+            assert p.event.wait(30)
+            probe.stop()
+            dec.eos_id = json.loads(p.reply)["tokens"][14]
+        sched = DecodeScheduler(dec, clock=clock).start()
+        held = {}
+        inner = dec.step_logits
+
+        def step_logits(tokens, pos, tables=None):
+            req = sched._active.get(0)
+            if req is not None and int(pos[0]) == 34:
+                # past the compaction at 32: hold here until released
+                held["sum_pages"] = len(req.sum_pages)
+                if reason == "cancelled":
+                    sched.cancel("r")
+                elif reason == "deadline":
+                    clock.advance(5.0)
+                elif reason == "error":
+                    raise RuntimeError("scripted step fault")
+            return inner(tokens, pos, tables)
+
+        dec.step_logits = step_logits
+        try:
+            p = _Pending({"prompt": prompt,
+                          "max_new_tokens": 16 if reason == "length"
+                          else 40}, "r",
+                         deadline=Deadline(1.0, clock=clock)
+                         if reason == "deadline" else None)
+            sched.submit(p)
+            assert p.event.wait(30)
+        finally:
+            sched.stop()
+        out = json.loads(p.reply)
+        assert out["finish_reason"] == reason, out
+        if reason == "pages_exhausted":
+            # it ended AT the compaction it could not hold: the
+            # prefill's byte and those of the steps at 20..31, partial
+            # output, no fault
+            assert out["n_tokens"] == 13 and sched.n_page_preempts == 1
+        elif reason != "eos":
+            assert held["sum_pages"] == 2
+        assert sched.pool.n_free == 2
+        assert _pages_idle(sched)
+        assert sched.releases == {reason: 1}
+
+    def test_admission_counts_the_prefills_peak(self):
+        """A prompt past its first window needs a whole window's pages
+        while it is walked: a pool one page short sheds at submit; the
+        same pool admits a prompt that fits."""
+        # 1 summary page + 4 window pages are the 18-byte prompt's peak
+        sched = DecodeScheduler(self._decoder(n_pages=1 + 4)).start()
+        rng = np.random.default_rng(43)
+        try:
+            with pytest.raises(DecodeOverloaded, match="5 pages"):
+                sched.submit(_Pending({"prompt": _prompt(rng, 18),
+                                       "max_new_tokens": 2}, "big"))
+            ok = _Pending({"prompt": _prompt(rng, 12),
+                           "max_new_tokens": 3}, "fits")
+            sched.submit(ok)
+            assert ok.event.wait(30) and ok.status == 200
+        finally:
+            sched.stop()
+        assert _pages_idle(sched)
+
+    def test_prepare_stamps_the_rows_by_kind(self):
+        from mmlspark_tpu.core.tracing import Tracer
+        from mmlspark_tpu.serving.decode import pass_view
+        tracer = Tracer()
+        sched = DecodeScheduler(self._decoder(), tracer=tracer).start()
+        rng = np.random.default_rng(44)
+        try:
+            p = _Pending({"prompt": _prompt(rng, 37),
+                          "max_new_tokens": 3}, "rows")
+            sched.submit(p)
+            assert p.event.wait(30)
+        finally:
+            sched.stop()
+        views = [pass_view(sp.attrs["phases"])
+                 for sp in tracer.recorder.scan("decode.pass")]
+        pre, = [q for v in views for q in v["prefills"]]
+        assert pre["windows"] == 3 and pre["summary_rows_written"] == 8
+        steps = [v for v in views if "dispatch" in v["phases_ms"]]
+        # positions 37 and 38: 8 summary rows, 6 then 7 window rows
+        assert [(v["summary_rows"], v["window_rows"]) for v in steps] \
+            == [(8, 6), (8, 7)]
 
 
 class TestPrefixScheduler:
