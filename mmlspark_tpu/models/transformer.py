@@ -242,7 +242,9 @@ def _psum_if(x, axis):
 # Every part of the block and of the programs built from it runs under
 # a ``jax.named_scope`` of its own: embed, norm, attn.qkv, attn.core,
 # attn.out, ffn, moe, head, ce, optimizer, and in the decode programs
-# kv.write and kv.gather. A scope is metadata (each op's ``op_name``,
+# kv.write and kv.gather (the dense engines' gather of a slot's lane
+# through its page table; a Pallas engine is handed a layer's pool
+# whole and gathers nothing). A scope is metadata (each op's ``op_name``,
 # read from the compiled text or a profiler trace); the backward of a
 # scoped part carries it as ``transpose(jvp(<scope>))``. The names are
 # public: PERF.md's split of a step's device time reads them.
@@ -1704,14 +1706,17 @@ def decode_param_specs(cfg: TransformerConfig, mesh,
     return specs
 
 
-def decode_cache_spec(mesh):
+def decode_cache_spec(mesh, paged: bool = True):
     """The KV pool's sharding under tensor parallelism: the head dim
-    (axis 3 of BOTH layouts — dense ``[n_layers, n_slots, max_len, H,
-    Dh]`` and paged ``[n_layers, n_pages, page_size, H, Dh]``) over
-    the ``model`` axis — each device's cache holds exactly its heads'
-    lanes, so the pool's HBM footprint splits across the mesh."""
+    over the ``model`` axis — axis 2 of every leaf of the paged pool
+    (one ``[n_pages, page_size, H, Dh]`` array a layer), axis 3 of the
+    dense ``[n_layers, n_slots, max_len, H, Dh]`` stack — so each
+    device's cache holds exactly its heads' lanes and the pool's HBM
+    footprint splits across the mesh."""
     from jax.sharding import PartitionSpec as P
     model = AXIS_MODEL if AXIS_MODEL in mesh.axis_names else None
+    if paged:
+        return P(None, None, model, None)
     return P(None, None, None, model, None)
 
 
@@ -1914,34 +1919,69 @@ def _dense_step_body(params, cfg: TransformerConfig, ck, cv, tokens,
 # The dense pool above reserves ``max_len`` rows per slot, so a short
 # sequence wastes most of its lane — concurrency per device is capped
 # by WORST-CASE length. The paged layout breaks the lane into fixed
-# ``page_size``-row pages drawn from one shared pool
-# ``[n_layers, n_pages, page_size, H, Dh]``; a per-slot **page table**
+# ``page_size``-row pages drawn from one shared pool: ONE array a
+# layer, ``[n_pages, page_size, H, Dh]``, in a list (the layout
+# ``models/evabyte.init_cache`` has). A per-slot **page table**
 # (int32 page indices, virtual row r lives at
 # ``pages[table[r // page_size], r % page_size]``) maps each slot's
 # virtual lane onto whatever pages it has claimed, so HBM is spent on
 # rows sequences actually occupy and the same pool holds
 # ``~max_len / mean_len`` times more concurrent sessions. All shapes
 # stay fixed (tables are ``[pages_per_slot]`` dense int arrays), the
-# pool is donated through every call, and the compile-once contract is
-# unchanged. Page index 0 is the SCRATCH page by convention: unclaimed
+# pool is donated leaf by leaf through every call, and the
+# compile-once contract is unchanged. Why a list and not one stacked
+# array: a ``pallas_call`` operand must be a buffer of its own, so a
+# layer sliced out of a stack is a copy of that layer's pool (48 a
+# step), while a layer's own array goes to the kernel whole and takes
+# the rows or pages a program names in place.
+# Page index 0 is the SCRATCH page by convention: unclaimed
 # table entries point at it, so writes past a slot's claimed region
 # (bucket-padding tails, speculative overshoot, free slots riding the
 # step) land harmlessly there and the position mask never reads them.
 
 
 def init_paged_kv_cache(cfg: TransformerConfig, n_pages: int,
-                        page_size: int) -> Dict[str, jax.Array]:
-    """The shared page pool: ``{"k", "v"}`` arrays of shape
-    ``[n_layers, n_pages, page_size, n_heads, d_head]`` (f32, like the
-    dense pool — decode mirrors the reference numerics). Allocated
+                        page_size: int) -> Dict[str, List[jax.Array]]:
+    """The shared page pool: ``{"k", "v"}``, each a LIST of one
+    ``[n_pages, page_size, n_heads, d_head]`` array a layer (f32, like
+    the dense pool — decode mirrors the reference numerics). Allocated
     once and donated through every prefill/step/verify call. Page 0
     is the scratch page (see module section comment); a pool of
     ``n_pages`` therefore holds ``n_pages - 1`` claimable pages."""
     _check_decode_config(cfg)
-    shape = (cfg.n_layers, int(n_pages), int(page_size),
-             cfg.n_heads, cfg.d_head)
-    return {"k": jnp.zeros(shape, jnp.float32),
-            "v": jnp.zeros(shape, jnp.float32)}
+    shape = (int(n_pages), int(page_size), cfg.n_heads, cfg.d_head)
+    return {name: [jnp.zeros(shape, jnp.float32)
+                   for _ in range(cfg.n_layers)] for name in ("k", "v")}
+
+
+def _write_pages(c_l, x, page_table, start_page):
+    """A prefill's rows ``x [S, H, Dh]`` into one layer's pool ``c_l
+    [n_pages, page_size, H, Dh]`` through the slot's ``page_table``,
+    from table entry ``start_page`` on (0 in a cold prefill, the first
+    private page behind a prefix hit: the hit is page-aligned, so
+    chunk c fills page ``table[start_page + c]`` exactly)."""
+    page_size = c_l.shape[1]
+    pages_per_slot = page_table.shape[0]
+    S = x.shape[0]
+    if S <= page_size:
+        # one page or a part of one: rows [0, S) of the first page, as
+        # ONE dynamic_update_slice (a one-chunk scatter is answered
+        # with a relayout of the whole operand). ``hit_len < length
+        # <= max_len``, so ``start_page`` is inside the table.
+        pg = jax.lax.dynamic_index_in_dim(page_table, start_page,
+                                          keepdims=False)
+        return jax.lax.dynamic_update_slice(c_l, x[None], (pg, 0, 0, 0))
+    # The bucket can overshoot the lane end (start_page + n_chunks >
+    # pages_per_slot when hit_len + S_pad > max_len) — a clamped
+    # dynamic_slice would silently re-aim those chunks at EARLIER
+    # table entries, i.e. write padding over the SHARED prefix pages,
+    # so overflow chunks route to the scratch page instead (the verify
+    # step's overshoot convention).
+    n_chunks = S // page_size
+    cpos = start_page + jnp.arange(n_chunks)
+    pgs = jnp.where(cpos < pages_per_slot,
+                    page_table[jnp.minimum(cpos, pages_per_slot - 1)], 0)
+    return c_l.at[pgs].set(x.reshape(n_chunks, page_size, *x.shape[1:]))
 
 
 def build_paged_prefill(cfg: TransformerConfig, page_size: int,
@@ -1954,42 +1994,23 @@ def build_paged_prefill(cfg: TransformerConfig, page_size: int,
     ``tokens`` is one bucket-padded prompt ``[S_pad]`` (one compile
     per bucket), ``page_table`` the slot's ``[pages_per_slot]`` table.
     Every layer's K/V rows land in the slot's claimed pages through
-    the table: buckets >= ``page_size`` scatter whole page-shaped
-    chunks, smaller buckets write one partial page. Chunks past the
-    claimed page count ride the scratch-page convention (table entry
-    0), so bucket padding never corrupts another slot's pages.
+    the table: buckets over ``page_size`` scatter whole page-shaped
+    chunks, a bucket of one page or less is one
+    ``dynamic_update_slice`` into the first claimed page. Chunks past
+    the claimed page count ride the scratch-page convention (table
+    entry 0), so bucket padding never corrupts another slot's pages.
     ``attn_impl`` picks the in-flight attention engine (the cold
     prefill attends over the q/k/v it just computed, not the pool —
     see :func:`_make_inflight_attn`)."""
     _check_decode_config(cfg)
-    page_size, pages_per_slot = int(page_size), int(pages_per_slot)
     attn = _make_inflight_attn(cfg, attn_impl, cache_sharding)
-
-    def write_kv(ck, cv, k, v, l, page_table):
-        S = k.shape[1]
-        if S >= page_size:
-            n_chunks = S // page_size
-            kc = k[0].reshape(n_chunks, page_size,
-                              cfg.n_heads, cfg.d_head)
-            vc = v[0].reshape(n_chunks, page_size,
-                              cfg.n_heads, cfg.d_head)
-            ck = ck.at[l, page_table[:n_chunks]].set(kc)
-            cv = cv.at[l, page_table[:n_chunks]].set(vc)
-        else:
-            # a sub-page bucket: one partial write into the first
-            # claimed page, rows [0, S)
-            ck = jax.lax.dynamic_update_slice(
-                ck, k[0][None, None], (l, page_table[0], 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v[0][None, None], (l, page_table[0], 0, 0, 0))
-        return ck, cv
 
     def prefill(params, cache, tokens, page_table, length):
         S = tokens.shape[0]
         with jax.named_scope("embed"):
             x = params["embed"][tokens][None]          # [1, S, D]
         pos = jnp.arange(S)
-        ck, cv = cache["k"], cache["v"]
+        ck, cv = list(cache["k"]), list(cache["v"])
         for l, bp in enumerate(_decode_block_params(params, cfg)):
             h = _rmsnorm(x, bp["ln1"])
             with jax.named_scope("attn.qkv"):
@@ -1997,7 +2018,8 @@ def build_paged_prefill(cfg: TransformerConfig, page_size: int,
                 k = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wk"]), pos)
                 v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"])
             with jax.named_scope("kv.write"):
-                ck, cv = write_kv(ck, cv, k, v, l, page_table)
+                ck[l] = _write_pages(ck[l], k[0], page_table, 0)
+                cv[l] = _write_pages(cv[l], v[0], page_table, 0)
             with jax.named_scope("attn.core"):
                 a = attn(q, k, v)
             with jax.named_scope("attn.out"):
@@ -2098,48 +2120,13 @@ def build_paged_prefix_prefill(cfg: TransformerConfig, page_size: int,
             check_vma=False)
         return f(q, k_pool, v_pool, page_table, hit_len)
 
-    def write_kv(ck, cv, k, v, l, page_table, start_page):
-        S = k.shape[0]
-        if S >= page_size:
-            # hit_len is page-aligned: suffix chunk c fills page
-            # table[start_page + c] exactly. The bucket can
-            # overshoot the lane end (start_page + n_chunks >
-            # pages_per_slot when hit_len + S_pad > max_len) — a
-            # clamped dynamic_slice would silently re-aim those
-            # chunks at EARLIER table entries, i.e. write padding
-            # over the SHARED prefix pages, so overflow chunks
-            # route to the scratch page instead (the verify step's
-            # overshoot convention).
-            n_chunks = S // page_size
-            cpos = start_page + jnp.arange(n_chunks)
-            pgs = jnp.where(
-                cpos < pages_per_slot,
-                page_table[jnp.minimum(cpos, pages_per_slot - 1)],
-                0)
-            ck = ck.at[l, pgs].set(
-                k.reshape(n_chunks, page_size,
-                          cfg.n_heads, cfg.d_head))
-            cv = cv.at[l, pgs].set(
-                v.reshape(n_chunks, page_size,
-                          cfg.n_heads, cfg.d_head))
-        else:
-            # a sub-page suffix bucket: one partial write into the
-            # first private page, rows [0, S)
-            pg = jax.lax.dynamic_index_in_dim(
-                page_table, start_page, keepdims=False)
-            ck = jax.lax.dynamic_update_slice(
-                ck, k[None, None], (l, pg, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v[None, None], (l, pg, 0, 0, 0))
-        return ck, cv
-
     def prefill(params, cache, tokens, page_table, length, hit_len):
         S = tokens.shape[0]
         with jax.named_scope("embed"):
             x = params["embed"][tokens]                # [S, D]
         pos = hit_len + jnp.arange(S)                  # virtual rows
         start_page = hit_len // page_size
-        ck, cv = cache["k"], cache["v"]
+        ck, cv = list(cache["k"]), list(cache["v"])
         # query j at virtual row hit_len + j reads index <= hit_len + j
         # (the flash kernel masks inside its (q-tile, page) steps — on
         # that path no [S, V]-shaped value enters the jaxpr at all)
@@ -2152,20 +2139,19 @@ def build_paged_prefix_prefill(cfg: TransformerConfig, page_size: int,
                 k = _rope_at(jnp.einsum("sd,dhk->shk", h, bp["wk"]), pos)
                 v = jnp.einsum("sd,dhk->shk", h, bp["wv"])
             with jax.named_scope("kv.write"):
-                ck, cv = write_kv(ck, cv, k, v, l, page_table,
-                                  start_page)
+                ck[l] = _write_pages(ck[l], k, page_table, start_page)
+                cv[l] = _write_pages(cv[l], v, page_table, start_page)
             # attend over the whole virtual lane: shared prefix rows
             # are read from their pages, suffix rows were just written
             if use_flash:
-                with jax.named_scope("kv.gather"):
-                    lk, lv = ck[l], cv[l]
                 with jax.named_scope("attn.core"):
-                    a = _flash_lane_attn(q, lk, lv, page_table, hit_len)
+                    a = _flash_lane_attn(q, ck[l], cv[l], page_table,
+                                         hit_len)
             else:
                 with jax.named_scope("kv.gather"):
-                    lk = ck[l, page_table].reshape(V, cfg.n_heads,
+                    lk = ck[l][page_table].reshape(V, cfg.n_heads,
                                                    cfg.d_head)
-                    lv = cv[l, page_table].reshape(V, cfg.n_heads,
+                    lv = cv[l][page_table].reshape(V, cfg.n_heads,
                                                    cfg.d_head)
                 with jax.named_scope("attn.core"):
                     s = jnp.einsum("shk,vhk->shv", q, lk) * scale
@@ -2275,7 +2261,7 @@ def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
     def step(params, cache, tokens, pos, page_tables):
         with jax.named_scope("embed"):
             x = params["embed"][tokens]                # [N, D]
-        ck, cv = cache["k"], cache["v"]
+        ck, cv = list(cache["k"]), list(cache["v"])
         mask = idx[None, None, :] <= pos[:, None, None]  # [N, 1, V]
         pg = page_tables[rows, pos // page_size]       # [N]
         row = pos % page_size
@@ -2286,14 +2272,13 @@ def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
                 k = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wk"]), pos)
                 v = jnp.einsum("nd,dhk->nhk", h, bp["wv"])
             with jax.named_scope("kv.write"):
-                ck = ck.at[l, pg, row].set(k)
-                cv = cv.at[l, pg, row].set(v)
+                ck[l] = ck[l].at[pg, row].set(k)
+                cv[l] = cv[l].at[pg, row].set(v)
             if use_pallas:
-                # the layer's slice of each pool, handed to the kernel
-                with jax.named_scope("kv.gather"):
-                    lk, lv = ck[l], cv[l]
+                # the layer's pool goes to the kernel whole: its page
+                # table names the pages that are read
                 with jax.named_scope("attn.core"):
-                    a = _paged_attn(q, lk, lv, page_tables, pos)
+                    a = _paged_attn(q, ck[l], cv[l], page_tables, pos)
             else:
                 with jax.named_scope("kv.gather"):
                     lk = _gather_lane(ck[l], page_tables, n_slots, V,
@@ -2410,7 +2395,7 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
     def verify(params, cache, tokens, pos, page_tables):
         with jax.named_scope("embed"):
             x = params["embed"][tokens]                # [N, W, D]
-        ck, cv = cache["k"], cache["v"]
+        ck, cv = list(cache["k"]), list(cache["v"])
         qpos = pos[:, None] + offs[None, :]            # [N, W]
         # causal over the virtual lane: query j reads index <= pos + j
         mask = idx[None, None, None, :] <= qpos[:, :, None, None]
@@ -2434,8 +2419,8 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
                              qpos)
                 v = jnp.einsum("nwd,dhk->nwhk", h, bp["wv"])
             with jax.named_scope("kv.write"):
-                ck = ck.at[l, pg, row].set(k)
-                cv = cv.at[l, pg, row].set(v)
+                ck[l] = ck[l].at[pg, row].set(k)
+                cv[l] = cv[l].at[pg, row].set(v)
             with jax.named_scope("kv.gather"):
                 lk = _gather_lane(ck[l], page_tables, n_slots, V, cfg)
                 lv = _gather_lane(cv[l], page_tables, n_slots, V, cfg)

@@ -164,11 +164,11 @@ class TransformerDecoder:
             # tensor-parallel decode: ONE model + ONE KV pool span the
             # mesh — heads/MLP-hidden shard over the model axis
             # (decode_param_specs), each device's cache holds exactly
-            # its heads' lanes (decode_cache_spec — the head dim is
-            # axis 3 of the dense AND the paged layout, so one spec
-            # serves both). The jitted machinery below compiles the
-            # SAME programs as sharded computations; shapes, donation,
-            # and compile-once are unchanged.
+            # its heads' lanes (decode_cache_spec — the head dim of
+            # every leaf: the paged pool is one array a layer, the
+            # dense pool one stack). The jitted machinery below
+            # compiles the SAME programs as sharded computations;
+            # shapes, donation, and compile-once are unchanged.
             import jax
             from jax.sharding import NamedSharding, PartitionSpec
             is_spec = lambda x: isinstance(x, PartitionSpec)  # noqa: E731
@@ -178,8 +178,8 @@ class TransformerDecoder:
                                      quantized_ffn=self.quantized_ffn),
                 is_leaf=is_spec)
             params = jax.device_put(params, p_sh)
-            cache_sharding = NamedSharding(mesh,
-                                           T.decode_cache_spec(mesh))
+            cache_sharding = NamedSharding(
+                mesh, T.decode_cache_spec(mesh, paged=self.paged))
         self.params = params
         if self.paged:
             page_size = int(page_size)

@@ -94,6 +94,15 @@ def make_workload(vocab: int, n_requests: int, seed: int = 0,
     return jobs
 
 
+def cache_buffer_pointers(cache) -> List[int]:
+    """The device buffer address of every leaf of a KV pool (the paged
+    pool is one array a layer): a donated call that writes in place
+    leaves every one where it was."""
+    import jax
+    return [leaf.unsafe_buffer_pointer()
+            for leaf in jax.tree.leaves(cache)]
+
+
 def _reset_jobs(jobs: List[DecodeJob]) -> None:
     for j in jobs:
         j.t_done = 0.0
@@ -114,7 +123,7 @@ def run_continuous(decoder, jobs: List[DecodeJob]) -> Dict[str, Any]:
     active: Dict[int, DecodeJob] = {}
     queue = sorted(jobs, key=lambda j: j.arrival_s)
     total_tokens = 0
-    ptr0 = decoder.cache["k"].unsafe_buffer_pointer()
+    ptr0 = cache_buffer_pointers(decoder.cache)
     live_counts: List[int] = []
     t0 = time.perf_counter()
     while queue or active:
@@ -165,7 +174,7 @@ def run_continuous(decoder, jobs: List[DecodeJob]) -> Dict[str, Any]:
             decoder.n_compiles() - compiles_before,
         # the donation proof: the pool's device buffer never moved
         "cache_buffer_stable":
-            decoder.cache["k"].unsafe_buffer_pointer() == ptr0,
+            cache_buffer_pointers(decoder.cache) == ptr0,
         # steady-state device allocation growth (second half vs first
         # sample): 0 = the warm loop allocates nothing that lives
         "live_array_growth":
@@ -301,7 +310,7 @@ def run_scheduler_sessions(scheduler, jobs: List[DecodeJob],
     prefill_s0 = scheduler.prefill_s
     prompt_tokens0 = scheduler.n_prompt_tokens
     prefills0 = scheduler.n_prefills
-    ptr0 = scheduler.decoder.cache["k"].unsafe_buffer_pointer()
+    ptr0 = cache_buffer_pointers(scheduler.decoder.cache)
     pendings = [_BenchPending(
         dict({"prompt": [int(t) for t in j.prompt],
               "max_new_tokens": int(j.max_new)},
@@ -333,8 +342,7 @@ def run_scheduler_sessions(scheduler, jobs: List[DecodeJob],
         "post_warmup_recompiles":
             scheduler.decoder.n_compiles() - compiles_before,
         "cache_buffer_stable":
-            scheduler.decoder.cache["k"].unsafe_buffer_pointer()
-            == ptr0,
+            cache_buffer_pointers(scheduler.decoder.cache) == ptr0,
         "slots_all_freed":
             scheduler.pool.n_free == scheduler.decoder.n_slots,
     }

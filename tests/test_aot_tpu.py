@@ -249,12 +249,16 @@ class TestTrainPrograms:
         assert _n_mosaic(c) == 2 * 3 + 3    # per layer fwd+2 bwd, CE 3
 
 
-def _decode_programs(topo, mesh_shape, sz):
+def _decode_programs(topo, mesh_shape, sz, cfg=None):
     """AOT-compile the decoder's program set — the step and every
     bucket of both prefills, what ``warmup()`` compiles — the way
-    ``TransformerDecoder`` builds them."""
+    ``TransformerDecoder`` builds them. ``sz`` gives slots, lane and
+    engine; ``cfg`` the model, where it is not the smoke's."""
     from mmlspark_tpu.parallel.sharding import bucket_ladder
-    cfg = chip_smoke.transformer_config(sz, "float32")
+    if cfg is None:
+        cfg = chip_smoke.transformer_config(sz, "float32")
+    pages_per_slot = sz.max_len // PAGE
+    n_pages = 1 + sz.n_slots * pages_per_slot
     if mesh_shape is None:
         mesh = _mesh(topo, {"x": 1})
         repl = cache_sh = NamedSharding(mesh, P())
@@ -272,25 +276,25 @@ def _decode_programs(topo, mesh_shape, sz):
     params = _abstract(jax.eval_shape(lambda: T.init_params(cfg, 0)),
                        param_sh)
     cache = _abstract(jax.eval_shape(
-        lambda: T.init_paged_kv_cache(cfg, N_PAGES, PAGE)), cache_sh)
+        lambda: T.init_paged_kv_cache(cfg, n_pages, PAGE)), cache_sh)
 
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
 
     kw = dict(cache_sharding=cache_sharding, attn_impl=sz.attn_impl)
-    prefill = T.build_paged_prefill(cfg, PAGE, PAGES_PER_SLOT, **kw)
-    prefix = T.build_paged_prefix_prefill(cfg, PAGE, PAGES_PER_SLOT, **kw)
+    prefill = T.build_paged_prefill(cfg, PAGE, pages_per_slot, **kw)
+    prefix = T.build_paged_prefix_prefill(cfg, PAGE, pages_per_slot, **kw)
     step = T.build_paged_decode_step(cfg, sz.n_slots, PAGE,
-                                     PAGES_PER_SLOT, **kw)
+                                     pages_per_slot, **kw)
     out = {"step": step.lower(
         params, cache, arg((sz.n_slots,)), arg((sz.n_slots,)),
-        arg((sz.n_slots, PAGES_PER_SLOT))).compile()}
+        arg((sz.n_slots, pages_per_slot))).compile()}
     for s in bucket_ladder(sz.max_len):
         out[f"prefill_{s}"] = prefill.lower(
-            params, cache, arg((s,)), arg((PAGES_PER_SLOT,)),
+            params, cache, arg((s,)), arg((pages_per_slot,)),
             arg(())).compile()
         out[f"prefix_{s}"] = prefix.lower(
-            params, cache, arg((s,)), arg((PAGES_PER_SLOT,)), arg(()),
+            params, cache, arg((s,)), arg((pages_per_slot,)), arg(()),
             arg(())).compile()
     return out
 
@@ -309,6 +313,68 @@ class TestServePrograms:
         for name, c in progs.items():
             # one attention kernel per layer in every program
             assert _n_mosaic(c) == self.SZ.n_layers, name
+
+
+_POOL_MOVE = re.compile(
+    r"= \(?\w+\[([\d,]+)\]\S* "
+    r"(copy|copy-start|slice|dynamic-slice|transpose)\(")
+
+
+def _pool_sized_moves(text: str, n_elems: int):
+    """The instructions of a compiled program that copy, slice or
+    transpose ``n_elems`` elements or more (one layer's page pool):
+    what a program that touches only the pages it names does not hold
+    (a layer sliced out of a stacked pool is such a ``slice``, under
+    ``slice_bitcast_fusion``)."""
+    return [line.strip()[:200] for line in text.splitlines()
+            for m in [_POOL_MOVE.search(line)]
+            if m and np.prod([int(d) for d in m.group(1).split(",")])
+            >= n_elems]
+
+
+class TestPythiaServeCell:
+    """The softmax block's decode programs at the size of the cell
+    ``pythia-1.4b.chat-closed`` (``benchmark/configs/pythia-1.4b.json``:
+    every width, all 24 layers, page 16, lanes of 1,024): the pool is
+    one array a layer, so no program's temporaries are of a pool's
+    size (the stacked pool's 16-token bucket held 4.52 GiB of them at
+    8 slots), nothing copies or slices a layer's pool, and the 23
+    programs of ``warmup()`` compile at 16 slots too."""
+
+    TEMP_LIMIT = 2 ** 28                    # 0.25 GiB
+
+    @staticmethod
+    def _programs(topo, n_slots):
+        import json
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               "pythia-1.4b.json")) as f:
+            m = json.load(f)
+        sv = m["serve"]
+        assert (sv["n_slots"], sv["page_size"]) == (8, PAGE)
+        cfg = T.TransformerConfig(
+            vocab=m["vocab_size"], d_model=m["hidden_size"],
+            n_heads=m["num_attention_heads"], d_head=m["head_dim"],
+            d_ff=m["intermediate_size"], n_stages=1,
+            layers_per_stage=m["num_hidden_layers"], dtype=sv["dtype"])
+        sz = dataclasses.replace(NAMED, n_slots=n_slots,
+                                 max_len=sv["max_len"])
+        layer_pool = ((1 + n_slots * sv["max_len"] // PAGE) * PAGE
+                      * cfg.n_heads * cfg.d_head)
+        return cfg, layer_pool, _decode_programs(topo, None, sz, cfg)
+
+    @pytest.mark.parametrize("n_slots", [8, 16])
+    def test_programs_touch_only_the_pages_they_name(self, topo, n_slots):
+        cfg, layer_pool, progs = self._programs(topo, n_slots)
+        assert len(progs) == 23             # what warmup() reports
+        for name, c in progs.items():
+            assert _n_mosaic(c) == cfg.n_layers, name
+            assert c.memory_analysis().temp_size_in_bytes \
+                < self.TEMP_LIMIT, name
+            assert not _pool_sized_moves(c.as_text(), layer_pool), name
+        # the benchmark finds the step's kernel by this name
+        assert progs["step"].as_text().count(
+            "paged_decode_attention") >= cfg.n_layers
 
 
 class TestEvaBytePrograms:

@@ -1346,7 +1346,8 @@ class TestPrefixScheduler:
                 cached = [ch.page for ch in
                           pc._root.children.values()]
                 assert cached
-            before = {p: np.asarray(
+            # the pool is one array a layer: stacked, [L, pages, ...]
+            before = {p: np.stack(
                 sched.decoder.cache["k"])[:, p].copy()
                 for p in cached}
             done = self._run(sched, [
@@ -1354,7 +1355,7 @@ class TestPrefixScheduler:
                 for pr in prompts[1:]])
             assert all(p.status == 200 for p in done)
             assert sched.stats()["prefix_cache"]["hits"] >= 1
-            after = np.asarray(sched.decoder.cache["k"])
+            after = np.stack(sched.decoder.cache["k"])
             for p, snap in before.items():
                 assert np.array_equal(snap, after[:, p]), \
                     f"shared page {p} was mutated"

@@ -1047,6 +1047,143 @@ class TestPagedDecode:
         assert step._cache_size() == 1
 
 
+def _big_outputs(jaxpr, n_elems, out=None):
+    """Primitive names of every equation, nested jaxprs included,
+    that produces ``n_elems`` elements or more."""
+    out = set() if out is None else out
+    for eqn in jaxpr.eqns:
+        subs = [v for v in eqn.params.values()
+                if hasattr(v, "eqns") or hasattr(v, "jaxpr")]
+        for sub in subs:
+            _big_outputs(getattr(sub, "jaxpr", sub), n_elems, out)
+        if not subs and any(int(np.prod(v.aval.shape)) >= n_elems
+                            for v in eqn.outvars):
+            out.add(eqn.primitive.name)
+    return out
+
+
+class TestPagedPoolLayout:
+    """The paged pool is ONE array a layer (ISSUE 30): a program hands
+    a layer's array whole to its kernel and writes the rows or pages
+    it names in place, so every leaf's buffer stays where it was
+    through every donated call, and no program holds an operation
+    that produces a pool-sized value other than the in-place write."""
+
+    CFG = T.TransformerConfig(**_DENSE, layers_per_stage=3)
+    PS, PPS, SLOTS, W = 8, 4, 3, 3
+    N_PAGES = 1 + SLOTS * PPS
+
+    def _cache(self):
+        return T.init_paged_kv_cache(self.CFG, self.N_PAGES, self.PS)
+
+    def _call(self, program, impl, donate=True):
+        """``(jitted program, the arguments after params and cache)``"""
+        cfg, ps, pps = self.CFG, self.PS, self.PPS
+        tables = np.zeros((self.SLOTS, pps), np.int32)
+        tables[1] = [7, 2, 11, 5]
+        ints = lambda *a: jnp.asarray(np.array(a, np.int32))  # noqa: E731
+        kw = dict(donate=donate, attn_impl=impl)
+        if program.startswith("prefill_"):
+            bucket = int(program.split("_")[1])
+            return (T.build_paged_prefill(cfg, ps, pps, **kw),
+                    (jnp.ones(bucket, jnp.int32), ints(*tables[1]),
+                     np.int32(bucket)))
+        if program.startswith("prefix_"):
+            bucket = int(program.split("_")[1])
+            return (T.build_paged_prefix_prefill(cfg, ps, pps, **kw),
+                    (jnp.ones(bucket, jnp.int32), ints(*tables[1]),
+                     np.int32(ps + bucket), np.int32(ps)))
+        if program == "step":
+            return (T.build_paged_decode_step(cfg, self.SLOTS, ps, pps,
+                                              **kw),
+                    (ints(0, 5, 0), ints(0, 9, 0), jnp.asarray(tables)))
+        assert program == "verify"
+        return (T.build_paged_verify_step(cfg, self.SLOTS, self.W, ps,
+                                          pps, donate=donate),
+                (jnp.ones((self.SLOTS, self.W), jnp.int32),
+                 ints(0, 9, 0), jnp.asarray(tables)))
+
+    def test_pool_is_one_array_a_layer(self):
+        cache = self._cache()
+        assert sorted(cache) == ["k", "v"]
+        for name in ("k", "v"):
+            assert isinstance(cache[name], list)
+            assert len(cache[name]) == self.CFG.n_layers == 3
+            for leaf in cache[name]:
+                assert leaf.shape == (self.N_PAGES, self.PS,
+                                      self.CFG.n_heads, self.CFG.d_head)
+                assert leaf.dtype == jnp.float32
+        ptrs = [x.unsafe_buffer_pointer() for x in jax.tree.leaves(cache)]
+        assert len(set(ptrs)) == 2 * self.CFG.n_layers
+
+    @pytest.mark.parametrize("program,impl", [
+        ("prefill_4", "dense"), ("prefill_8", "dense"),
+        ("prefill_16", "dense"), ("prefill_8", "pallas_interpret"),
+        ("prefix_4", "dense"), ("prefix_8", "dense"),
+        ("prefix_16", "dense"), ("prefix_8", "pallas_interpret"),
+        ("step", "dense"), ("step", "pallas_interpret"),
+        ("verify", "dense")])
+    def test_every_layers_buffer_stays_in_place(self, program, impl):
+        """Donation holds leaf by leaf: after a prefill (a part of a
+        page, one page, several), an offset prefill, a step and a
+        verify each layer's buffer is the one it was, and the tree
+        comes back in the layout it went in."""
+        from mmlspark_tpu.testing.decode_load import (
+            cache_buffer_pointers)
+        params = T.init_params(self.CFG, seed=0)
+        fn, args = self._call(program, impl)
+        cache = self._cache()
+        before = cache_buffer_pointers(cache)
+        struct = jax.tree.structure(cache)
+        for _ in range(2):
+            cache = fn(params, cache, *args)[0]
+            assert jax.tree.structure(cache) == struct
+            assert cache_buffer_pointers(cache) == before
+        assert fn._cache_size() == 1
+
+    @pytest.mark.parametrize("program", [
+        "prefill_4", "prefill_8", "prefill_16",
+        "prefix_4", "prefix_8", "prefix_16", "step"])
+    def test_kernel_programs_produce_no_pool_sized_value(self, program):
+        """With the kernels on the path (``attn_impl="pallas"``, traced
+        and not run) the only operations whose result is as large as a
+        layer's pool are the in-place writes and the kernel call that
+        takes the pool whole: no slice, gather, copy or transpose of a
+        pool. The stacked layout's ``ck[l]`` was such a slice."""
+        params = T.init_params(self.CFG, seed=0)
+        fn, args = self._call(program, "pallas", donate=False)
+        n_pages = 4097                  # a pool larger than any activation
+        cache = jax.eval_shape(
+            lambda: T.init_paged_kv_cache(self.CFG, n_pages, self.PS))
+        jaxpr = jax.make_jaxpr(fn)(params, cache, *args).jaxpr
+        layer_pool = n_pages * self.PS * self.CFG.n_heads * self.CFG.d_head
+        assert _big_outputs(jaxpr, layer_pool) == {
+            "step": {"scatter"}, "prefill_16": {"scatter"},
+            "prefix_16": {"scatter"}}.get(
+                program, {"dynamic_update_slice"})
+
+    @pytest.mark.parametrize("bucket", [4, 8, 16])
+    def test_cold_prefill_writes_only_its_pages(self, bucket):
+        """A bucket under, at and over the page size: the rows land in
+        the pages the table names, in order, and every other page of
+        every layer is as it was (the bucket equal to the page size is
+        one ``dynamic_update_slice``, not a one-chunk scatter)."""
+        params = T.init_params(self.CFG, seed=0)
+        fn, args = self._call(f"prefill_{bucket}", "dense",
+                              donate=False)
+        cache = jax.tree.map(lambda x: x + 7.0, self._cache())
+        out = fn(params, cache, *args)[0]
+        named = [7, 2, 11, 5][:max(bucket // self.PS, 1)]
+        for name in ("k", "v"):
+            for l in range(self.CFG.n_layers):
+                got = np.asarray(out[name][l])
+                rows = got[named].reshape(-1, *got.shape[2:])
+                assert not np.any(rows[:bucket] == 7.0)
+                np.testing.assert_array_equal(rows[bucket:], 7.0)
+                others = np.delete(got, named, axis=0)
+                np.testing.assert_array_equal(others, 7.0)
+
+
 class TestSpeculativeSteps:
     """The propose/verify machinery (ISSUE 11): with the target as
     its own draft, every proposal must verify (acceptance is exactly
@@ -1324,7 +1461,7 @@ class TestFlashPrefill:
                 jnp.arange(1, 1 + self.PPS, dtype=jnp.int32),
                 jnp.int32(plen))
             outs[impl] = (int(nxt), np.asarray(logits), int(pnxt),
-                          np.asarray(plogits), np.asarray(cache["k"]))
+                          np.asarray(plogits), np.stack(cache["k"]))
         d, fl = outs["dense"], outs["pallas_interpret"]
         assert d[0] == fl[0] and d[2] == fl[2]
         np.testing.assert_allclose(fl[1], d[1], atol=1e-4, rtol=1e-4)
@@ -1378,7 +1515,7 @@ class TestFlashPrefill:
                                    jnp.asarray(pad), table,
                                    jnp.int32(length), jnp.int32(hit))
             outs[impl] = (int(nxt), np.asarray(logits),
-                          np.asarray(cache["k"]))
+                          np.stack(cache["k"]))
         d, fl = outs["dense"], outs["pallas_interpret"]
         assert d[0] == fl[0]
         np.testing.assert_allclose(fl[1], d[1], atol=1e-4, rtol=1e-4)
@@ -1389,7 +1526,7 @@ class TestFlashPrefill:
         assert d[0] == int(cold_nxt)
         np.testing.assert_allclose(fl[1], np.asarray(cold_logits),
                                    atol=1e-4, rtol=1e-4)
-        shared = np.asarray(warm_cache["k"])[:, 1:1 + hit_pages]
+        shared = np.stack(warm_cache["k"])[:, 1:1 + hit_pages]
         np.testing.assert_array_equal(
             fl[2][:, 1:1 + hit_pages], shared)
 
