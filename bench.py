@@ -1749,94 +1749,6 @@ def bench_decode_continuous():
             "passed": ok, "chip": _chip()}
 
 
-def bench_decode_paged():
-    """Paged KV cache vs the dense slot-lane pool at FIXED cache HBM
-    (ISSUE 11 acceptance gate).
-
-    The dense pool reserves ``max_len`` rows per slot, so its
-    concurrency is ``HBM / max_len`` whatever sequences actually need;
-    the block-table layout spends the same rows page-by-page, so a
-    mixed-length workload holds ``~max_len / mean_len`` times more
-    live sessions. Both decoders here get the SAME claimable cache
-    rows (dense: 4 slots x 128 rows; paged: 32 x 16-row pages + the
-    scratch page) and the same backlogged mixed-length workload
-    through a live DecodeScheduler. Gates, in order:
-
-    * **>= 2x concurrent sessions** — peak live sessions (the
-      scheduler's slots high-water) at the fixed budget;
-    * **zero post-warmup recompiles** + the **donated page pool's
-      buffer pointer stable** (block tables are data, not shapes);
-    * **token-for-token parity** — every paged greedy sequence equals
-      its dense twin's;
-    * **no leaks** — slots and pages all freed after the run.
-    """
-    from mmlspark_tpu.models import transformer as T
-    from mmlspark_tpu.parallel.dist import tree_bytes
-    from mmlspark_tpu.serving.decode import (
-        DecodeScheduler, TransformerDecoder,
-    )
-    from mmlspark_tpu.testing.decode_load import (
-        make_workload, run_scheduler_sessions,
-    )
-
-    cfg = T.TransformerConfig(vocab=256, d_model=48, n_heads=4,
-                              d_head=12, d_ff=192, n_stages=1,
-                              layers_per_stage=3)
-    params = T.init_params(cfg, seed=0)
-    max_len, page = 128, 16
-    jobs = make_workload(cfg.vocab, n_requests=48, seed=0,
-                         mean_gap_ms=0.0, prompt_lens=(6, 10, 14),
-                         max_new=(6, 10, 14))
-
-    def run(decoder):
-        sched = DecodeScheduler(decoder,
-                                max_waiting=len(jobs) + 1).start()
-        try:
-            decoder.warmup()
-            return run_scheduler_sessions(sched, jobs)
-        finally:
-            sched.stop()
-
-    dense = TransformerDecoder(params, cfg, n_slots=4,
-                               max_len=max_len, paged=False)
-    dense_bytes = tree_bytes(dense.cache)
-    dense_out = run(dense)
-    # same claimable rows (4 * 128 = 32 pages of 16) + scratch page
-    paged = TransformerDecoder(params, cfg, n_slots=16,
-                               max_len=max_len, page_size=page,
-                               n_pages=(4 * max_len) // page + 1)
-    paged_bytes = tree_bytes(paged.cache)
-    paged_out = run(paged)
-    parity = dense_out["sequences"] == paged_out["sequences"]
-    sess_ratio = (paged_out["peak_concurrent_sessions"]
-                  / max(dense_out["peak_concurrent_sessions"], 1))
-    ok = (parity
-          and sess_ratio >= 2.0
-          and paged_out["post_warmup_recompiles"] == 0
-          and paged_out["cache_buffer_stable"]
-          and paged_out["slots_all_freed"]
-          and paged_out["pages_all_freed"]
-          and dense_out["errors"] == paged_out["errors"] == 0)
-    strip = lambda d: {k: v for k, v in d.items()  # noqa: E731
-                       if k != "sequences"}
-    return {"metric": "decode_paged_v1",
-            "value": paged_out["peak_concurrent_sessions"],
-            "unit": "concurrent sessions @ fixed cache HBM",
-            "baseline": dense_out["peak_concurrent_sessions"],
-            "vs_baseline": round(sess_ratio, 3),
-            "cache_bytes": {"dense": dense_bytes,
-                            "paged": paged_bytes},
-            "tokens_per_s": {"dense": dense_out["tokens_per_s"],
-                             "paged": paged_out["tokens_per_s"]},
-            "page_high_water": paged_out["page_high_water"],
-            "token_parity": parity,
-            "post_warmup_recompiles":
-                paged_out["post_warmup_recompiles"],
-            "cache_buffer_stable": paged_out["cache_buffer_stable"],
-            "dense": strip(dense_out), "paged": strip(paged_out),
-            "passed": ok, "chip": _chip()}
-
-
 def bench_decode_speculative():
     """Speculative decoding vs plain single-token decode (ISSUE 11
     acceptance gate).
@@ -1917,9 +1829,8 @@ def bench_decode_prefix_cache():
     prompt from token 0. The radix-indexed page cache attaches the
     longest cached prefix by REFERENCE (refcounted shared pages) and
     computes only the uncached suffix. Both arms serve the SAME seeded
-    70 %-shared-prefix workload (``make_workload(prefix_share=...)`` —
-    the one traffic generator ``tools/bench_decode.py --prefix-share``
-    drives too) through live schedulers. Gates, in order:
+    70 %-shared-prefix workload (``make_workload(prefix_share=...)``)
+    through live schedulers. Gates, in order:
 
     * **>= 1.5x prefill tokens/s** (prompt tokens per prefill
       wall-second; equivalently lower TTFT) for the cached arm;
@@ -2826,7 +2737,7 @@ BENCHES = [bench_gbdt_quantile, bench_adult_census, bench_cifar10_scoring,
            bench_tsdb_overhead,
            bench_profiler_overhead,
            bench_decode_continuous,
-           bench_decode_paged, bench_decode_speculative,
+           bench_decode_speculative,
            bench_decode_prefix_cache,
            bench_prefill_flash, bench_quantized_compute,
            bench_multihost_scaling, bench_retrain_loop,

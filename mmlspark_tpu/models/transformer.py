@@ -33,7 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1706,18 +1706,15 @@ def decode_param_specs(cfg: TransformerConfig, mesh,
     return specs
 
 
-def decode_cache_spec(mesh, paged: bool = True):
+def decode_cache_spec(mesh):
     """The KV pool's sharding under tensor parallelism: the head dim
     over the ``model`` axis — axis 2 of every leaf of the paged pool
-    (one ``[n_pages, page_size, H, Dh]`` array a layer), axis 3 of the
-    dense ``[n_layers, n_slots, max_len, H, Dh]`` stack — so each
+    (one ``[n_pages, page_size, H, Dh]`` array a layer) — so each
     device's cache holds exactly its heads' lanes and the pool's HBM
     footprint splits across the mesh."""
     from jax.sharding import PartitionSpec as P
     model = AXIS_MODEL if AXIS_MODEL in mesh.axis_names else None
-    if paged:
-        return P(None, None, model, None)
-    return P(None, None, None, model, None)
+    return P(None, None, model, None)
 
 
 def init_kv_cache(cfg: TransformerConfig, n_slots: int, max_len: int
@@ -1734,59 +1731,229 @@ def init_kv_cache(cfg: TransformerConfig, n_slots: int, max_len: int
             "v": jnp.zeros(shape, jnp.float32)}
 
 
-def _decode_out_shardings(cache_sharding):
-    """Pin the jitted decode pair's output layout under tensor
-    parallelism: the cache keeps its canonical head sharding through
-    every donated call (otherwise XLA may pick a different layout for
-    the prefill's output than the step expects — one silent retrace
-    per transition), tokens/logits come back replicated (they are
-    host-fetched anyway)."""
-    if cache_sharding is None:
-        return None
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    repl = NamedSharding(cache_sharding.mesh, P())
-    return ({"k": cache_sharding, "v": cache_sharding}, repl, repl)
+def _jit_decode(fn, donate: bool, cache_sharding=None,
+                n_replicated: int = 2):
+    """The decode builders' jit epilogue: ``fn(params, cache, ...) ->
+    (cache, *outs)`` under its own ``__name__`` (``jit_step``,
+    ``jit_prefill``: what traces and the benchmark find it by), the
+    cache donated. Under tensor parallelism the output layout is
+    pinned: the cache keeps its canonical head sharding through every
+    donated call (otherwise XLA may pick a different layout for the
+    prefill's output than the step expects — one silent retrace per
+    transition), the ``n_replicated`` outputs behind it (tokens,
+    logits, scores) come back replicated (they are host-fetched
+    anyway)."""
+    kw = {}
+    if cache_sharding is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        repl = NamedSharding(cache_sharding.mesh, P())
+        kw["out_shardings"] = (
+            {"k": cache_sharding, "v": cache_sharding},
+        ) + (repl,) * n_replicated
+    return jax.jit(fn, donate_argnums=(1,) if donate else (), **kw)
 
 
-def _make_inflight_attn(cfg: TransformerConfig, attn_impl: str,
-                        cache_sharding):
-    """Resolve the prefill builders' in-flight attention engine:
-    ``attn(q, k, v)`` over the [B, S, H, Dh] q/k/v a prefill just
-    computed. ``"dense"`` is the softmax path (the [S, S] score matrix
-    materializes), ``"pallas"`` the streaming flash kernel
-    (:func:`~mmlspark_tpu.parallel.pallas_attention.
-    flash_prefill_attention` — no [S, S] intermediate),
-    ``"pallas_interpret"`` the kernel interpreted for CPU parity.
-    Under a TP mesh the kernel runs per head-slice via ``shard_map``
-    (heads are independent — the decode kernel's dispatch, one shape
-    earlier in the request's life)."""
+def _attn_kernel(kernel, attn_impl: str, cache_sharding, ranks, **kw):
+    """A Pallas attention kernel of ``parallel/pallas_attention`` made
+    ready for a decode program, or None under ``attn_impl="dense"``
+    (XLA's softmax path); ``"pallas_interpret"`` interprets it, for
+    CPU parity. Under a TP mesh the dispatch is sharding-aware: heads
+    are independent in attention, so each model-axis shard runs the
+    SAME kernel on its own head slice (q ``[N, H/t, Dh]``, pool
+    ``[pages, page, H/t, Dh]``) with page tables and positions
+    replicated — per-shard head-slice grids, no collective in either
+    direction. ``ranks`` names each operand: its rank where it is
+    ``[..., H, Dh]``, 0 where replicated; the output is shaped like
+    the first. check_vma is irrelevant here (forward-only, nothing
+    replicated is produced); False lets the interpret-mode parity
+    tests run (see build_spmd_train_step on interpret + vma)."""
     if attn_impl not in ("dense", "pallas", "pallas_interpret"):
         raise ValueError(f"unknown attn_impl {attn_impl!r}")
-    scale = cfg.d_head ** -0.5
     if attn_impl == "dense":
-        return lambda q, k, v: dense_attention(q, k, v, causal=True)
+        return None
+    kernel = functools.partial(
+        kernel, interpret=attn_impl == "pallas_interpret", **kw)
+    if cache_sharding is None \
+            or cache_sharding.mesh.shape.get(AXIS_MODEL, 1) <= 1:
+        return kernel
+    from jax.sharding import PartitionSpec as P
+    specs = tuple(P(*[None] * (r - 2), AXIS_MODEL, None) if r else P()
+                  for r in ranks)
+    return jax.shard_map(kernel, mesh=cache_sharding.mesh, in_specs=specs,
+                         out_specs=specs[0], check_vma=False)
+
+
+def _inflight_attention(cfg: TransformerConfig, attn_impl: str,
+                        cache_sharding):
+    """The cold prefills' ``attend``: attention over the ``[S, H, Dh]``
+    q/k/v a prefill just computed (no cache is read). ``"dense"`` is
+    the softmax path (the [S, S] score matrix materializes),
+    ``"pallas"`` the streaming flash kernel
+    (:func:`~mmlspark_tpu.parallel.pallas_attention.
+    flash_prefill_attention` — no [S, S] intermediate),
+    ``"pallas_interpret"`` the kernel interpreted for CPU parity."""
     from mmlspark_tpu.parallel.pallas_attention import (
         flash_prefill_attention)
-    interp = attn_impl == "pallas_interpret"
-    tp_mesh = None
-    if cache_sharding is not None \
-            and cache_sharding.mesh.shape.get(AXIS_MODEL, 1) > 1:
-        tp_mesh = cache_sharding.mesh
+    core = _attn_kernel(flash_prefill_attention, attn_impl, cache_sharding,
+                        (4, 4, 4), scale=cfg.d_head ** -0.5) \
+        or functools.partial(dense_attention, causal=True)
 
-    def attn(q, k, v):
-        if tp_mesh is None:
-            return flash_prefill_attention(q, k, v, scale, interp)
-        from jax.sharding import PartitionSpec as P
-        f = jax.shard_map(
-            lambda q_, k_, v_: flash_prefill_attention(
-                q_, k_, v_, scale, interp),
-            mesh=tp_mesh,
-            in_specs=(P(None, None, AXIS_MODEL, None),) * 3,
-            out_specs=P(None, None, AXIS_MODEL, None),
-            check_vma=False)
-        return f(q, k, v)
+    def attend(l, q, k, v):
+        # both engines take a batch: a prefill is a batch of one prompt
+        with jax.named_scope("attn.core"):
+            return core(q[None], k[None], v[None])[0]
 
-    return attn
+    return attend
+
+
+def _lane_attention(q, lk, lv, qpos):
+    """Softmax attention over gathered lanes: queries ``q [N, H, Dh]``
+    (one a slot: the single-token step) or ``[N, W, H, Dh]`` (a
+    verify's window; a prefix prefill is N = 1) at virtual rows
+    ``qpos [N]`` / ``[N, W]`` over each slot's lane ``lk``/``lv [N, V,
+    H, Dh]``. A query reads ``index <= qpos``, so rows not yet
+    overwritten (padding tails, the last occupant's leftovers, the
+    scratch page) are dead by construction."""
+    w = "w" if q.ndim == 4 else ""
+    mask = jnp.arange(lk.shape[1]) <= qpos[..., None, None]
+    s = jnp.einsum(f"n{w}hk,nshk->n{w}hs", q, lk) * q.shape[-1] ** -0.5
+    s = jnp.where(mask, s, -1e30)                  # [N, (W,) 1, V] bcast
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum(f"n{w}hs,nshk->n{w}hk", p, lv)
+
+
+def _attend_pages(k_l, v_l, q, tables, qpos, kernel=None, kernel_pos=None):
+    """Attention over virtual lanes of one layer's pools ``k_l``/``v_l
+    [n_pages, page_size, H, Dh]``: by ``kernel`` (the pool goes to it
+    whole: the page table names the pages that are read, and no lane-
+    or score-shaped value enters the jaxpr) or by gathering each
+    slot's lane from its pages."""
+    if kernel is not None:
+        with jax.named_scope("attn.core"):
+            return kernel(q, k_l, v_l, tables, kernel_pos)
+    with jax.named_scope("kv.gather"):
+        # [N, P, page, H, Dh] -> [N, virtual_len, H, Dh]
+        lane = (tables.shape[0], -1) + q.shape[-2:]
+        lk, lv = k_l[tables].reshape(lane), v_l[tables].reshape(lane)
+    with jax.named_scope("attn.core"):
+        return _lane_attention(q, lk, lv, qpos)
+
+
+class _CacheView(NamedTuple):
+    """What one decode program states about its cache, and all it
+    states (the rest of a program is :func:`_decode_layers`): the
+    pool it returns, how a layer's new K/V rows reach it (``write(l,
+    k, v)``), and what attention reads (``attend(l, q, k, v)``, which
+    opens ``kv.gather`` / ``attn.core`` itself; a cold prefill's is
+    :func:`_inflight_attention`: the q/k/v in flight, never the
+    pool)."""
+    cache: Dict[str, Any]
+    write: Callable
+    attend: Callable
+
+
+def _lanes(cache, slot, pos=None, inflight=None) -> _CacheView:
+    """The unpaged pool (the speculation draft's): K and V one stacked
+    ``[L, n_slots, max_len, H, Dh]`` array each. Rows go to ``[l,
+    slot, pos]``: a prefill's ``[S, H, Dh]`` are rows ``[0, S)`` of ONE
+    slot's lane (a scalar ``slot``: one ``dynamic_update_slice``), a
+    step's ``[N, H, Dh]`` one row a slot, which then attends its own
+    lane masked to ``index <= pos``."""
+    pool = dict(cache)
+
+    def write(l, k, v):
+        if jnp.ndim(slot) == 0:
+            put = lambda c, x: jax.lax.dynamic_update_slice(  # noqa: E731
+                c, x[None, None], (l, slot, 0, 0, 0))
+        else:
+            put = lambda c, x: c.at[l, slot, pos].set(x)  # noqa: E731
+        pool["k"], pool["v"] = put(pool["k"], k), put(pool["v"], v)
+
+    def attend(l, q, k, v):
+        with jax.named_scope("kv.gather"):
+            lk, lv = pool["k"][l], pool["v"][l]
+        with jax.named_scope("attn.core"):
+            return _lane_attention(q, lk, lv, pos)
+
+    return _CacheView(pool, write, inflight or attend)
+
+
+def _table_pages(cache, page_table, hit_len, pos=None, inflight=None,
+                 kernel=None) -> _CacheView:
+    """Pages through ONE slot's table (the paged prefills): the rows
+    ``[S, H, Dh]`` of virtual positions ``pos = hit_len + [0, S)`` go
+    through ``page_table`` from entry ``hit_len // page_size`` on
+    (:func:`_write_pages`; a cold prefill is ``hit_len = 0``). Behind
+    a prefix hit attention reads the WHOLE virtual lane: shared prefix
+    rows straight from their pages, suffix rows just written."""
+    ck, cv = list(cache["k"]), list(cache["v"])
+
+    def write(l, k, v):
+        start = hit_len // ck[l].shape[1]
+        ck[l] = _write_pages(ck[l], k, page_table, start)
+        cv[l] = _write_pages(cv[l], v, page_table, start)
+
+    def attend(l, q, k, v):
+        if kernel is not None:
+            return _attend_pages(ck[l], cv[l], q, page_table, None,
+                                 kernel, hit_len)
+        return _attend_pages(ck[l], cv[l], q[None], page_table[None],
+                             pos[None])[0]
+
+    return _CacheView({"k": ck, "v": cv}, write, inflight or attend)
+
+
+def _slot_pages(cache, page_tables, qpos, pg, kernel=None) -> _CacheView:
+    """Pages through EVERY slot's table (step and verify): the row of
+    query position ``qpos`` (``[N]``, or ``[N, W]`` in a verify) goes
+    to page ``pg``, row ``qpos % page_size``, and attention reads each
+    slot's virtual lane masked to ``index <= qpos``."""
+    ck, cv = list(cache["k"]), list(cache["v"])
+    row = qpos % ck[0].shape[1]
+
+    def write(l, k, v):
+        ck[l], cv[l] = ck[l].at[pg, row].set(k), cv[l].at[pg, row].set(v)
+
+    def attend(l, q, k, v):
+        return _attend_pages(ck[l], cv[l], q, page_tables, qpos, kernel,
+                             qpos)
+
+    return _CacheView({"k": ck, "v": cv}, write, attend)
+
+
+def _decode_layers(params, cfg: TransformerConfig, tokens, pos,
+                   view: _CacheView):
+    """The softmax block as the decode programs run it, stated once:
+    ``tokens`` at positions ``pos`` (one shape: ``[S]`` in a prefill,
+    ``[N]`` in a step, ``[N, W]`` in a verify) through every layer and
+    the final norm -> ``[..., D]``. Programs differ in ``view``."""
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]                    # [..., D]
+    for l, bp in enumerate(_decode_block_params(params, cfg)):
+        h = _rmsnorm(x, bp["ln1"])
+        with jax.named_scope("attn.qkv"):
+            q = _rope_at(jnp.einsum("...d,dhk->...hk", h, bp["wq"]), pos)
+            k = _rope_at(jnp.einsum("...d,dhk->...hk", h, bp["wk"]), pos)
+            v = jnp.einsum("...d,dhk->...hk", h, bp["wv"])
+        with jax.named_scope("kv.write"):
+            view.write(l, k, v)
+        a = view.attend(l, q, k, v)
+        with jax.named_scope("attn.out"):
+            x = x + jnp.einsum("...hk,hkd->...d", a, bp["wo"])
+        x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
+    return _rmsnorm(x, params["final_norm"])
+
+
+def _greedy_head(params, h, last=None):
+    """The vocab head over final-normed ``h [..., D]`` -> ``(greedy
+    tokens int32, logits)``. A prefill names ``last``, the one row of
+    its ``[S, D]`` anybody reads (the prompt's last position)."""
+    with jax.named_scope("head"):
+        if last is not None:
+            h = jax.lax.dynamic_index_in_dim(h, last, axis=0,
+                                             keepdims=False)
+        logits = h @ params["head"]
+        return jnp.argmax(logits, -1).astype(jnp.int32), logits
 
 
 def build_prefill(cfg: TransformerConfig, donate: bool = True,
@@ -1805,45 +1972,17 @@ def build_prefill(cfg: TransformerConfig, donate: bool = True,
 
     ``next_token`` is the greedy argmax at position ``length - 1`` —
     the first generated token. ``attn_impl`` picks the in-flight
-    attention engine (see :func:`_make_inflight_attn`)."""
+    attention engine (see :func:`_inflight_attention`)."""
     _check_decode_config(cfg)
-    attn = _make_inflight_attn(cfg, attn_impl, cache_sharding)
+    inflight = _inflight_attention(cfg, attn_impl, cache_sharding)
 
     def prefill(params, cache, tokens, slot, length):
-        with jax.named_scope("embed"):
-            x = params["embed"][tokens][None]          # [1, S, D]
-        pos = jnp.arange(tokens.shape[0])
-        ck, cv = cache["k"], cache["v"]
-        for l, bp in enumerate(_decode_block_params(params, cfg)):
-            h = _rmsnorm(x, bp["ln1"])
-            with jax.named_scope("attn.qkv"):
-                q = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wq"]), pos)
-                k = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wk"]), pos)
-                v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"])
-            with jax.named_scope("kv.write"):
-                # [S, H, Dh] -> this layer's slot lane, rows [0, S)
-                ck = jax.lax.dynamic_update_slice(
-                    ck, k[0][None, None], (l, slot, 0, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cv, v[0][None, None], (l, slot, 0, 0, 0))
-            with jax.named_scope("attn.core"):
-                a = attn(q, k, v)
-            with jax.named_scope("attn.out"):
-                x = x + jnp.einsum("bshk,hkd->bsd", a, bp["wo"])
-            x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
-        h = _rmsnorm(x[0], params["final_norm"])       # [S, D]
-        with jax.named_scope("head"):
-            last = jax.lax.dynamic_index_in_dim(h, length - 1, axis=0,
-                                                keepdims=False)
-            logits = last @ params["head"]
-            return ({"k": ck, "v": cv},
-                    jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        view = _lanes(cache, slot, inflight=inflight)
+        h = _decode_layers(params, cfg, tokens,
+                           jnp.arange(tokens.shape[0]), view)
+        return (view.cache,) + _greedy_head(params, h, length - 1)
 
-    kw = {}
-    out_sh = _decode_out_shardings(cache_sharding)
-    if out_sh is not None:
-        kw["out_shardings"] = out_sh
-    return jax.jit(prefill, donate_argnums=(1,) if donate else (), **kw)
+    return _jit_decode(prefill, donate, cache_sharding)
 
 
 def build_decode_step(cfg: TransformerConfig, n_slots: int,
@@ -1863,54 +2002,14 @@ def build_decode_step(cfg: TransformerConfig, n_slots: int,
     0`` (their lane row 0 is rewritten by the next prefill); their
     outputs are garbage the host never reads."""
     _check_decode_config(cfg)
-    n_slots, max_len = int(n_slots), int(max_len)
-    rows = jnp.arange(n_slots)
-    idx = jnp.arange(max_len)
+    rows = jnp.arange(int(n_slots))
 
     def step(params, cache, tokens, pos):
-        ck, cv, nxt, logits = _dense_step_body(
-            params, cfg, cache["k"], cache["v"], tokens, pos, rows, idx)
-        return {"k": ck, "v": cv}, nxt, logits
+        view = _lanes(cache, rows, pos)
+        h = _decode_layers(params, cfg, tokens, pos, view)
+        return (view.cache,) + _greedy_head(params, h)
 
-    kw = {}
-    out_sh = _decode_out_shardings(cache_sharding)
-    if out_sh is not None:
-        kw["out_shardings"] = out_sh
-    return jax.jit(step, donate_argnums=(1,) if donate else (), **kw)
-
-
-def _dense_step_body(params, cfg: TransformerConfig, ck, cv, tokens,
-                     pos, rows, idx):
-    """One single-token step for every slot over the dense slot-lane
-    cache — the body :func:`build_decode_step` jits and
-    :func:`build_draft_propose` unrolls ``k`` times in one program."""
-    scale = cfg.d_head ** -0.5
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens]                    # [N, D]
-    mask = idx[None, None, :] <= pos[:, None, None]    # [N, 1, S]
-    for l, bp in enumerate(_decode_block_params(params, cfg)):
-        h = _rmsnorm(x, bp["ln1"])
-        with jax.named_scope("attn.qkv"):
-            q = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wq"]), pos)
-            k = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wk"]), pos)
-            v = jnp.einsum("nd,dhk->nhk", h, bp["wv"])
-        with jax.named_scope("kv.write"):
-            ck = ck.at[l, rows, pos].set(k)
-            cv = cv.at[l, rows, pos].set(v)
-        with jax.named_scope("kv.gather"):
-            lk, lv = ck[l], cv[l]
-        with jax.named_scope("attn.core"):
-            s = jnp.einsum("nhk,nshk->nhs", q, lk) * scale
-            s = jnp.where(mask, s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            a = jnp.einsum("nhs,nshk->nhk", p, lv)
-        with jax.named_scope("attn.out"):
-            x = x + jnp.einsum("nhk,hkd->nd", a, bp["wo"])
-        x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
-    h = _rmsnorm(x, params["final_norm"])
-    with jax.named_scope("head"):
-        logits = h @ params["head"]
-        return ck, cv, jnp.argmax(logits, -1).astype(jnp.int32), logits
+    return _jit_decode(step, donate, cache_sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -2001,43 +2100,17 @@ def build_paged_prefill(cfg: TransformerConfig, page_size: int,
     entry 0), so bucket padding never corrupts another slot's pages.
     ``attn_impl`` picks the in-flight attention engine (the cold
     prefill attends over the q/k/v it just computed, not the pool —
-    see :func:`_make_inflight_attn`)."""
+    see :func:`_inflight_attention`)."""
     _check_decode_config(cfg)
-    attn = _make_inflight_attn(cfg, attn_impl, cache_sharding)
+    inflight = _inflight_attention(cfg, attn_impl, cache_sharding)
 
     def prefill(params, cache, tokens, page_table, length):
-        S = tokens.shape[0]
-        with jax.named_scope("embed"):
-            x = params["embed"][tokens][None]          # [1, S, D]
-        pos = jnp.arange(S)
-        ck, cv = list(cache["k"]), list(cache["v"])
-        for l, bp in enumerate(_decode_block_params(params, cfg)):
-            h = _rmsnorm(x, bp["ln1"])
-            with jax.named_scope("attn.qkv"):
-                q = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wq"]), pos)
-                k = _rope(jnp.einsum("bsd,dhk->bshk", h, bp["wk"]), pos)
-                v = jnp.einsum("bsd,dhk->bshk", h, bp["wv"])
-            with jax.named_scope("kv.write"):
-                ck[l] = _write_pages(ck[l], k[0], page_table, 0)
-                cv[l] = _write_pages(cv[l], v[0], page_table, 0)
-            with jax.named_scope("attn.core"):
-                a = attn(q, k, v)
-            with jax.named_scope("attn.out"):
-                x = x + jnp.einsum("bshk,hkd->bsd", a, bp["wo"])
-            x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
-        h = _rmsnorm(x[0], params["final_norm"])       # [S, D]
-        with jax.named_scope("head"):
-            last = jax.lax.dynamic_index_in_dim(h, length - 1, axis=0,
-                                                keepdims=False)
-            logits = last @ params["head"]
-            return ({"k": ck, "v": cv},
-                    jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        view = _table_pages(cache, page_table, 0, inflight=inflight)
+        h = _decode_layers(params, cfg, tokens,
+                           jnp.arange(tokens.shape[0]), view)
+        return (view.cache,) + _greedy_head(params, h, length - 1)
 
-    kw = {}
-    out_sh = _decode_out_shardings(cache_sharding)
-    if out_sh is not None:
-        kw["out_shardings"] = out_sh
-    return jax.jit(prefill, donate_argnums=(1,) if donate else (), **kw)
+    return _jit_decode(prefill, donate, cache_sharding)
 
 
 def build_paged_prefix_prefill(cfg: TransformerConfig, page_size: int,
@@ -2085,104 +2158,21 @@ def build_paged_prefix_prefill(cfg: TransformerConfig, page_size: int,
     ``"pallas_interpret"`` is the CPU parity mode. Same scratch-page
     overshoot semantics on every engine."""
     _check_decode_config(cfg)
-    page_size, pages_per_slot = int(page_size), int(pages_per_slot)
-    V = page_size * pages_per_slot
-    scale = cfg.d_head ** -0.5
-    idx = jnp.arange(V)
-    if attn_impl not in ("dense", "pallas", "pallas_interpret"):
-        raise ValueError(f"unknown attn_impl {attn_impl!r}")
-    use_flash = attn_impl in ("pallas", "pallas_interpret")
-    tp_mesh = None
-    if use_flash:
-        from mmlspark_tpu.parallel.pallas_attention import (
-            paged_prefix_prefill_attention)
-        if cache_sharding is not None \
-                and cache_sharding.mesh.shape.get(AXIS_MODEL, 1) > 1:
-            tp_mesh = cache_sharding.mesh
-
-    def _flash_lane_attn(q, k_pool, v_pool, page_table, hit_len):
-        interp = attn_impl == "pallas_interpret"
-        if tp_mesh is None:
-            return paged_prefix_prefill_attention(
-                q, k_pool, v_pool, page_table, hit_len, scale=scale,
-                page_size=page_size, interpret=interp)
-        from jax.sharding import PartitionSpec as P
-        f = jax.shard_map(
-            lambda q_, k_, v_, t_, h_: paged_prefix_prefill_attention(
-                q_, k_, v_, t_, h_, scale=scale, page_size=page_size,
-                interpret=interp),
-            mesh=tp_mesh,
-            in_specs=(P(None, AXIS_MODEL, None),
-                      P(None, None, AXIS_MODEL, None),
-                      P(None, None, AXIS_MODEL, None),
-                      P(None), P()),
-            out_specs=P(None, AXIS_MODEL, None),
-            check_vma=False)
-        return f(q, k_pool, v_pool, page_table, hit_len)
+    from mmlspark_tpu.parallel.pallas_attention import (
+        paged_prefix_prefill_attention)
+    kernel = _attn_kernel(
+        paged_prefix_prefill_attention, attn_impl, cache_sharding,
+        (3, 4, 4, 0, 0), scale=cfg.d_head ** -0.5, page_size=int(page_size))
 
     def prefill(params, cache, tokens, page_table, length, hit_len):
-        S = tokens.shape[0]
-        with jax.named_scope("embed"):
-            x = params["embed"][tokens]                # [S, D]
-        pos = hit_len + jnp.arange(S)                  # virtual rows
-        start_page = hit_len // page_size
-        ck, cv = list(cache["k"]), list(cache["v"])
-        # query j at virtual row hit_len + j reads index <= hit_len + j
-        # (the flash kernel masks inside its (q-tile, page) steps — on
-        # that path no [S, V]-shaped value enters the jaxpr at all)
-        mask = None if use_flash \
-            else idx[None, None, :] <= pos[:, None, None]  # [S, 1, V]
-        for l, bp in enumerate(_decode_block_params(params, cfg)):
-            h = _rmsnorm(x, bp["ln1"])
-            with jax.named_scope("attn.qkv"):
-                q = _rope_at(jnp.einsum("sd,dhk->shk", h, bp["wq"]), pos)
-                k = _rope_at(jnp.einsum("sd,dhk->shk", h, bp["wk"]), pos)
-                v = jnp.einsum("sd,dhk->shk", h, bp["wv"])
-            with jax.named_scope("kv.write"):
-                ck[l] = _write_pages(ck[l], k, page_table, start_page)
-                cv[l] = _write_pages(cv[l], v, page_table, start_page)
-            # attend over the whole virtual lane: shared prefix rows
-            # are read from their pages, suffix rows were just written
-            if use_flash:
-                with jax.named_scope("attn.core"):
-                    a = _flash_lane_attn(q, ck[l], cv[l], page_table,
-                                         hit_len)
-            else:
-                with jax.named_scope("kv.gather"):
-                    lk = ck[l][page_table].reshape(V, cfg.n_heads,
-                                                   cfg.d_head)
-                    lv = cv[l][page_table].reshape(V, cfg.n_heads,
-                                                   cfg.d_head)
-                with jax.named_scope("attn.core"):
-                    s = jnp.einsum("shk,vhk->shv", q, lk) * scale
-                    s = jnp.where(mask, s, -1e30)
-                    p = jax.nn.softmax(s, axis=-1)
-                    a = jnp.einsum("shv,vhk->shk", p, lv)
-            with jax.named_scope("attn.out"):
-                x = x + jnp.einsum("shk,hkd->sd", a, bp["wo"])
-            x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
-        h = _rmsnorm(x, params["final_norm"])          # [S, D]
-        with jax.named_scope("head"):
-            last = jax.lax.dynamic_index_in_dim(
-                h, length - 1 - hit_len, axis=0, keepdims=False)
-            logits = last @ params["head"]
-            return ({"k": ck, "v": cv},
-                    jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        pos = hit_len + jnp.arange(tokens.shape[0])    # virtual rows
+        view = _table_pages(cache, page_table, hit_len, pos,
+                            kernel=kernel)
+        h = _decode_layers(params, cfg, tokens, pos, view)
+        return (view.cache,) + _greedy_head(params, h,
+                                            length - 1 - hit_len)
 
-    kw = {}
-    out_sh = _decode_out_shardings(cache_sharding)
-    if out_sh is not None:
-        kw["out_shardings"] = out_sh
-    return jax.jit(prefill, donate_argnums=(1,) if donate else (), **kw)
-
-
-def _gather_lane(c_l, page_tables, n_slots, virtual_len, cfg):
-    """Assemble each slot's virtual lane from its pages:
-    ``c_l [n_pages, page_size, H, Dh]`` gathered through
-    ``page_tables [N, pages_per_slot]`` -> ``[N, virtual_len, H, Dh]``
-    (virtual_len = pages_per_slot * page_size)."""
-    lane = c_l[page_tables]        # [N, P, page, H, Dh]
-    return lane.reshape(n_slots, virtual_len, cfg.n_heads, cfg.d_head)
+    return _jit_decode(prefill, donate, cache_sharding)
 
 
 def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
@@ -2212,98 +2202,21 @@ def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
     CPU parity tests). Token-for-token parity between the two is
     test-pinned."""
     _check_decode_config(cfg)
-    if attn_impl not in ("dense", "pallas", "pallas_interpret"):
-        raise ValueError(f"unknown attn_impl {attn_impl!r}")
-    n_slots, page_size = int(n_slots), int(page_size)
-    pages_per_slot = int(pages_per_slot)
-    V = page_size * pages_per_slot
-    scale = cfg.d_head ** -0.5
-    rows = jnp.arange(n_slots)
-    idx = jnp.arange(V)
-    use_pallas = attn_impl in ("pallas", "pallas_interpret")
-    tp_mesh = None
-    if use_pallas:
-        from mmlspark_tpu.parallel.pallas_attention import (
-            paged_decode_attention)
-        if cache_sharding is not None \
-                and cache_sharding.mesh.shape.get(AXIS_MODEL, 1) > 1:
-            # sharding-aware kernel dispatch: heads are independent in
-            # paged attention, so under a TP mesh each model-axis
-            # shard runs the SAME kernel on its own head slice (q
-            # [N, H/t, Dh], pool [pages, page, H/t, Dh]) with the
-            # page tables/positions replicated — per-shard head-slice
-            # grids, no collective in either direction. check_vma is
-            # irrelevant here (forward-only, nothing replicated is
-            # produced); False lets the interpret-mode parity tests
-            # run (see build_spmd_train_step on interpret + vma).
-            tp_mesh = cache_sharding.mesh
-
-    def _paged_attn(q, k_pool, v_pool, page_tables, pos):
-        interp = attn_impl == "pallas_interpret"
-        if tp_mesh is None:
-            return paged_decode_attention(
-                q, k_pool, v_pool, page_tables, pos, scale=scale,
-                page_size=page_size, interpret=interp)
-        from jax.sharding import PartitionSpec as P
-        f = jax.shard_map(
-            lambda q_, k_, v_, t_, p_: paged_decode_attention(
-                q_, k_, v_, t_, p_, scale=scale,
-                page_size=page_size, interpret=interp),
-            mesh=tp_mesh,
-            in_specs=(P(None, AXIS_MODEL, None),
-                      P(None, None, AXIS_MODEL, None),
-                      P(None, None, AXIS_MODEL, None),
-                      P(None, None), P(None)),
-            out_specs=P(None, AXIS_MODEL, None),
-            check_vma=False)
-        return f(q, k_pool, v_pool, page_tables, pos)
+    page_size = int(page_size)
+    rows = jnp.arange(int(n_slots))
+    from mmlspark_tpu.parallel.pallas_attention import (
+        paged_decode_attention)
+    kernel = _attn_kernel(
+        paged_decode_attention, attn_impl, cache_sharding,
+        (3, 4, 4, 0, 0), scale=cfg.d_head ** -0.5, page_size=page_size)
 
     def step(params, cache, tokens, pos, page_tables):
-        with jax.named_scope("embed"):
-            x = params["embed"][tokens]                # [N, D]
-        ck, cv = list(cache["k"]), list(cache["v"])
-        mask = idx[None, None, :] <= pos[:, None, None]  # [N, 1, V]
-        pg = page_tables[rows, pos // page_size]       # [N]
-        row = pos % page_size
-        for l, bp in enumerate(_decode_block_params(params, cfg)):
-            h = _rmsnorm(x, bp["ln1"])
-            with jax.named_scope("attn.qkv"):
-                q = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wq"]), pos)
-                k = _rope_at(jnp.einsum("nd,dhk->nhk", h, bp["wk"]), pos)
-                v = jnp.einsum("nd,dhk->nhk", h, bp["wv"])
-            with jax.named_scope("kv.write"):
-                ck[l] = ck[l].at[pg, row].set(k)
-                cv[l] = cv[l].at[pg, row].set(v)
-            if use_pallas:
-                # the layer's pool goes to the kernel whole: its page
-                # table names the pages that are read
-                with jax.named_scope("attn.core"):
-                    a = _paged_attn(q, ck[l], cv[l], page_tables, pos)
-            else:
-                with jax.named_scope("kv.gather"):
-                    lk = _gather_lane(ck[l], page_tables, n_slots, V,
-                                      cfg)
-                    lv = _gather_lane(cv[l], page_tables, n_slots, V,
-                                      cfg)
-                with jax.named_scope("attn.core"):
-                    s = jnp.einsum("nhk,nshk->nhs", q, lk) * scale
-                    s = jnp.where(mask, s, -1e30)
-                    p = jax.nn.softmax(s, axis=-1)
-                    a = jnp.einsum("nhs,nshk->nhk", p, lv)
-            with jax.named_scope("attn.out"):
-                x = x + jnp.einsum("nhk,hkd->nd", a, bp["wo"])
-            x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
-        h = _rmsnorm(x, params["final_norm"])
-        with jax.named_scope("head"):
-            logits = h @ params["head"]
-            return ({"k": ck, "v": cv},
-                    jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        view = _slot_pages(cache, page_tables, pos,
+                           page_tables[rows, pos // page_size], kernel)
+        h = _decode_layers(params, cfg, tokens, pos, view)
+        return (view.cache,) + _greedy_head(params, h)
 
-    kw = {}
-    out_sh = _decode_out_shardings(cache_sharding)
-    if out_sh is not None:
-        kw["out_shardings"] = out_sh
-    return jax.jit(step, donate_argnums=(1,) if donate else (), **kw)
+    return _jit_decode(step, donate, cache_sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -2382,9 +2295,7 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
     n_slots, width = int(n_slots), int(width)
     page_size, pages_per_slot = int(page_size), int(pages_per_slot)
     V = page_size * pages_per_slot
-    scale = cfg.d_head ** -0.5
     rows = jnp.arange(n_slots)
-    idx = jnp.arange(V)
     offs = jnp.arange(width)
     if ce_impl is None:
         ce_impl = verify_ce_engine(cfg, n_slots, width,
@@ -2393,55 +2304,24 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
         raise ValueError(f"unknown verify ce_impl {ce_impl!r}")
 
     def verify(params, cache, tokens, pos, page_tables):
-        with jax.named_scope("embed"):
-            x = params["embed"][tokens]                # [N, W, D]
-        ck, cv = list(cache["k"]), list(cache["v"])
         qpos = pos[:, None] + offs[None, :]            # [N, W]
-        # causal over the virtual lane: query j reads index <= pos + j
-        mask = idx[None, None, None, :] <= qpos[:, :, None, None]
         # a slot whose lane ends inside the window (pos + W > V — e.g.
         # a non-speculative slot riding the round near its lane end)
         # must not wrap its writes onto its own live pages: overflow
         # positions route to the scratch page instead
-        safe = qpos < V
         pg = jnp.where(
-            safe,
+            qpos < V,
             page_tables[rows[:, None],
                         jnp.minimum(qpos // page_size,
                                     pages_per_slot - 1)], 0)  # [N, W]
-        row = qpos % page_size
-        for l, bp in enumerate(_decode_block_params(params, cfg)):
-            h = _rmsnorm(x, bp["ln1"])
-            with jax.named_scope("attn.qkv"):
-                q = _rope_at(jnp.einsum("nwd,dhk->nwhk", h, bp["wq"]),
-                             qpos)
-                k = _rope_at(jnp.einsum("nwd,dhk->nwhk", h, bp["wk"]),
-                             qpos)
-                v = jnp.einsum("nwd,dhk->nwhk", h, bp["wv"])
-            with jax.named_scope("kv.write"):
-                ck[l] = ck[l].at[pg, row].set(k)
-                cv[l] = cv[l].at[pg, row].set(v)
-            with jax.named_scope("kv.gather"):
-                lk = _gather_lane(ck[l], page_tables, n_slots, V, cfg)
-                lv = _gather_lane(cv[l], page_tables, n_slots, V, cfg)
-            with jax.named_scope("attn.core"):
-                s = jnp.einsum("nwhk,nshk->nwhs", q, lk) * scale
-                s = jnp.where(mask, s, -1e30)          # [N, W, 1, V] bcast
-                p = jax.nn.softmax(s, axis=-1)
-                a = jnp.einsum("nwhs,nshk->nwhk", p, lv)
-            with jax.named_scope("attn.out"):
-                x = x + jnp.einsum("nwhk,hkd->nwd", a, bp["wo"])
-            x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
-        h = _rmsnorm(x, params["final_norm"])          # [N, W, D]
-        with jax.named_scope("head"):
-            logits = jnp.einsum("nwd,dv->nwv", h, params["head"])
-            out = ({"k": ck, "v": cv},
-                   jnp.argmax(logits, -1).astype(jnp.int32), logits)
+        view = _slot_pages(cache, page_tables, qpos, pg)
+        h = _decode_layers(params, cfg, tokens, qpos, view)  # [N, W, D]
+        toks, logits = _greedy_head(params, h)         # [N, W, vocab]
         if not with_scores:
-            return out
+            return view.cache, toks, logits
         with jax.named_scope("ce"):
-            return out + (score_proposals(tokens, h, logits,
-                                          params["head"]),)
+            return (view.cache, toks, logits,
+                    score_proposals(tokens, h, logits, params["head"]))
 
     def score_proposals(tokens, h, logits, head):
         labels = tokens[:, 1:].reshape(-1)             # proposals
@@ -2453,22 +2333,15 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
             ce = fused_softmax_xent(
                 h[:, :-1].reshape(-1, cfg.d_model), head,
                 labels, interpret=ce_impl == "fused_interpret")
-            scores = -ce.reshape(n_slots, width - 1)
-        else:
-            lg = logits[:, :-1].astype(jnp.float32)    # [N, W-1, V]
-            lse = jax.nn.logsumexp(lg, axis=-1)
-            gold = jnp.take_along_axis(
-                lg, tokens[:, 1:, None], axis=-1)[..., 0]
-            scores = gold - lse
-        return scores
+            return -ce.reshape(n_slots, width - 1)
+        lg = logits[:, :-1].astype(jnp.float32)        # [N, W-1, V]
+        lse = jax.nn.logsumexp(lg, axis=-1)
+        gold = jnp.take_along_axis(
+            lg, tokens[:, 1:, None], axis=-1)[..., 0]
+        return gold - lse
 
-    kw = {}
-    out_sh = _decode_out_shardings(cache_sharding)
-    if out_sh is not None:
-        if with_scores:
-            out_sh = out_sh + (out_sh[-1],)   # scores: replicated too
-        kw["out_shardings"] = out_sh
-    return jax.jit(verify, donate_argnums=(1,) if donate else (), **kw)
+    return _jit_decode(verify, donate, cache_sharding,
+                       n_replicated=3 if with_scores else 2)
 
 
 def build_draft_propose(cfg: TransformerConfig, n_slots: int,
@@ -2483,21 +2356,19 @@ def build_draft_propose(cfg: TransformerConfig, n_slots: int,
     slots need per-step draft distributions on host, so the scheduler
     falls back to ``width`` separate draft steps when one is active."""
     _check_decode_config(cfg)
-    n_slots, max_len, width = int(n_slots), int(max_len), int(width)
-    rows = jnp.arange(n_slots)
-    idx = jnp.arange(max_len)
+    rows = jnp.arange(int(n_slots))
 
     def propose(params, cache, tokens, pos):
-        ck, cv = cache["k"], cache["v"]
-        cur = tokens
         props = []
-        for j in range(width):
-            ck, cv, cur, _ = _dense_step_body(
-                params, cfg, ck, cv, cur, pos + j, rows, idx)
-            props.append(cur)
-        return {"k": ck, "v": cv}, jnp.stack(props, axis=1)
+        for j in range(int(width)):
+            view = _lanes(cache, rows, pos + j)
+            h = _decode_layers(params, cfg, tokens, pos + j, view)
+            tokens, _ = _greedy_head(params, h)
+            props.append(tokens)
+            cache = view.cache
+        return cache, jnp.stack(props, axis=1)
 
-    return jax.jit(propose, donate_argnums=(1,) if donate else ())
+    return _jit_decode(propose, donate, n_replicated=1)
 
 
 def layer_truncated_draft(params, cfg: TransformerConfig,

@@ -7,8 +7,8 @@ different times — batching whole requests would hold every member
 until the slowest one's last token. This module batches at the *slot*
 level instead:
 
-* a :class:`TransformerDecoder` owns ONE preallocated slot-indexed
-  KV-cache pool (``models/transformer.init_kv_cache``) plus the jitted
+* a :class:`TransformerDecoder` owns ONE preallocated KV-cache pool
+  (``models/transformer.init_paged_kv_cache``) plus the jitted
   prefill/step functions built over it — fixed shapes, donated cache,
   so a warm decode loop performs **zero device allocations and zero
   retraces** however requests churn;
@@ -28,7 +28,7 @@ frame plane. Tokens are emitted incrementally into the request's
 in-flight state (visible via ``GET /decode/stats``); the reply carries
 the full sequence once the request leaves its slot.
 
-The decode plane's memory is **paged** by default (docs/serving.md
+The decode plane's memory is **paged** (docs/serving.md
 "Paged KV cache"): the KV pool is a shared set of fixed-size pages
 plus per-slot page tables, so cache HBM is spent on rows sequences
 actually occupy — a :class:`PagePool` claims/frees pages between
@@ -108,16 +108,14 @@ class TransformerDecoder:
     after it, :meth:`n_compiles` staying flat is the zero-retrace
     evidence the bench gates on.
 
-    **Paged mode** (``paged=True``, the default): the pool is a
-    block-table layout — ``n_pages`` shared pages of ``page_size``
+    The pool is a block-table layout — ``n_pages`` shared pages of ``page_size``
     rows plus per-slot page tables — so cache HBM is spent on rows
     sequences actually occupy instead of ``max_len`` per slot (page 0
     is the scratch page; see ``models/transformer.py``). ``n_pages``
     defaults to the dense equivalent (every slot can hold a full
     lane); set it lower to serve more slots at the same HBM — the
     scheduler's :class:`PagePool` admission keeps the pool honest.
-    ``paged=False`` keeps the dense ``[n_slots, max_len]``-lane pool
-    as the A/B baseline. Callers without a scheduler (direct API,
+    Callers without a scheduler (direct API,
     ``testing/decode_load``) may omit page tables: an identity table
     (slot ``s`` -> pages ``[1 + s*pps, 1 + (s+1)*pps)``) stands in,
     which needs the full-size default pool.
@@ -130,12 +128,11 @@ class TransformerDecoder:
     them all at once; the scheduler accepts the longest agreeing
     prefix. The draft keeps a dense slot-lane cache (its layers are
     the cheap fraction — paging the target is where the HBM lives).
-    Requires paged mode and no mesh (the draft is replicated)."""
+    Requires no mesh (the draft is replicated)."""
 
     def __init__(self, params, cfg, n_slots: int = 8,
                  max_len: int = 256, eos_id: Optional[int] = None,
-                 donate: bool = True, mesh=None,
-                 paged: bool = True, page_size: int = 16,
+                 donate: bool = True, mesh=None, page_size: int = 16,
                  n_pages: Optional[int] = None,
                  draft_params=None, draft_cfg=None, spec_k: int = 4,
                  attn_impl: str = "auto",
@@ -148,7 +145,6 @@ class TransformerDecoder:
         self.max_len = int(max_len)
         self.eos_id = eos_id
         self.mesh = mesh
-        self.paged = bool(paged)
         self.quantized_ffn = bool(quantized_ffn)
         if self.quantized_ffn:
             # int8-compute FFN (ISSUE 17 tentpole a): per-channel
@@ -165,10 +161,10 @@ class TransformerDecoder:
             # mesh — heads/MLP-hidden shard over the model axis
             # (decode_param_specs), each device's cache holds exactly
             # its heads' lanes (decode_cache_spec — the head dim of
-            # every leaf: the paged pool is one array a layer, the
-            # dense pool one stack). The jitted machinery below
-            # compiles the SAME programs as sharded computations;
-            # shapes, donation, and compile-once are unchanged.
+            # every leaf: the pool is one array a layer). The jitted
+            # machinery below compiles the SAME programs as sharded
+            # computations; shapes, donation, and compile-once are
+            # unchanged.
             import jax
             from jax.sharding import NamedSharding, PartitionSpec
             is_spec = lambda x: isinstance(x, PartitionSpec)  # noqa: E731
@@ -179,109 +175,81 @@ class TransformerDecoder:
                 is_leaf=is_spec)
             params = jax.device_put(params, p_sh)
             cache_sharding = NamedSharding(
-                mesh, T.decode_cache_spec(mesh, paged=self.paged))
+                mesh, T.decode_cache_spec(mesh))
         self.params = params
-        if self.paged:
-            page_size = int(page_size)
-            if page_size < 1 or page_size & (page_size - 1):
-                # prompt buckets are powers of two: a pow2 page divides
-                # every bucket >= itself (whole-chunk scatters) and
-                # bounds the rest to the partial-page path — any other
-                # size leaves buckets the prefill cannot chunk
-                raise ValueError(
-                    f"page_size={page_size} must be a power of two")
-            if self.max_len % page_size:
-                raise ValueError(
-                    f"page_size={page_size} must divide "
-                    f"max_len={self.max_len}")
-            self.page_size = int(page_size)
-            self.pages_per_slot = self.max_len // self.page_size
-            # default pool = the dense equivalent + the scratch page:
-            # identical HBM and admission behavior until the operator
-            # shrinks it (or raises n_slots at the same pool)
-            self.n_pages = (int(n_pages) if n_pages is not None
-                            else 1 + self.n_slots * self.pages_per_slot)
-            if self.n_pages < 2:
-                raise ValueError("paged cache needs n_pages >= 2 "
-                                 "(page 0 is the scratch page)")
-            # the decode-step gather engine (ROADMAP item 5 / PR 11
-            # follow-up): "auto" runs the fused Pallas block-table
-            # kernel on TPU (the page table aims each page DMA via
-            # scalar prefetch — no per-layer lane materialization in
-            # HBM) and the dense gather everywhere else; "dense" /
-            # "pallas" / "pallas_interpret" force an engine
-            # (interpret = the CPU parity-test mode). Under a TP mesh
-            # the kernel dispatches sharding-aware: heads are
-            # independent, so each model-axis shard runs the kernel
-            # on its own head slice of the pool (a shard_map inside
-            # the step — per-shard head-slice grids, page tables
-            # replicated; token-for-token parity vs the dense gather
-            # is test-pinned for the mesh path too).
-            if attn_impl not in ("auto", "dense", "pallas",
-                                 "pallas_interpret"):
-                raise ValueError(f"unknown attn_impl {attn_impl!r}")
-            if attn_impl == "auto":
-                from mmlspark_tpu.parallel.pallas_attention import (
-                    paged_attention_available)
-                attn_impl = ("pallas" if paged_attention_available()
-                             else "dense")
-            self.attn_impl = attn_impl
-            self.cache = T.init_paged_kv_cache(cfg, self.n_pages,
-                                               self.page_size)
-            # the SAME resolved engine drives the prefill builders
-            # (ISSUE 17): "pallas" runs the streaming flash kernels —
-            # no [S, S] score matrix in the cold prefills, no [S, V]
-            # lane materialization in the offset/prefix prefill —
-            # "dense" keeps the softmax paths, interpret is CPU parity
-            self._prefill = T.build_paged_prefill(
+        page_size = int(page_size)
+        if page_size < 1 or page_size & (page_size - 1):
+            # prompt buckets are powers of two: a pow2 page divides
+            # every bucket >= itself (whole-chunk scatters) and
+            # bounds the rest to the partial-page path — any other
+            # size leaves buckets the prefill cannot chunk
+            raise ValueError(
+                f"page_size={page_size} must be a power of two")
+        if self.max_len % page_size:
+            raise ValueError(
+                f"page_size={page_size} must divide "
+                f"max_len={self.max_len}")
+        self.page_size = page_size
+        self.pages_per_slot = self.max_len // self.page_size
+        # default pool = the dense equivalent + the scratch page:
+        # identical HBM and admission behavior until the operator
+        # shrinks it (or raises n_slots at the same pool)
+        self.n_pages = (int(n_pages) if n_pages is not None
+                        else 1 + self.n_slots * self.pages_per_slot)
+        if self.n_pages < 2:
+            raise ValueError("paged cache needs n_pages >= 2 "
+                             "(page 0 is the scratch page)")
+        # the decode-step gather engine (ROADMAP item 5 / PR 11
+        # follow-up): "auto" runs the fused Pallas block-table
+        # kernel on TPU (the page table aims each page DMA via
+        # scalar prefetch — no per-layer lane materialization in
+        # HBM) and the dense gather everywhere else; "dense" /
+        # "pallas" / "pallas_interpret" force an engine
+        # (interpret = the CPU parity-test mode), and the builders
+        # refuse any other name. Under a TP mesh the kernel runs per
+        # head slice (transformer._attn_kernel; token-for-token parity
+        # vs the dense gather is test-pinned for the mesh path too).
+        if attn_impl == "auto":
+            from mmlspark_tpu.parallel.pallas_attention import (
+                paged_attention_available)
+            attn_impl = ("pallas" if paged_attention_available()
+                         else "dense")
+        self.attn_impl = attn_impl
+        # the SAME resolved engine drives the prefill builders
+        # (ISSUE 17): "pallas" runs the streaming flash kernels —
+        # no [S, S] score matrix in the cold prefills, no [S, V]
+        # lane materialization in the offset/prefix prefill —
+        # "dense" keeps the softmax paths, interpret is CPU parity
+        self._prefill = T.build_paged_prefill(
+            cfg, self.page_size, self.pages_per_slot,
+            donate=donate, cache_sharding=cache_sharding,
+            attn_impl=attn_impl)
+        self._step = T.build_paged_decode_step(
+            cfg, self.n_slots, self.page_size, self.pages_per_slot,
+            donate=donate, cache_sharding=cache_sharding,
+            attn_impl=attn_impl)
+        # the cross-request prefix cache's compute half: a
+        # partial/offset prefill that computes KV only for the
+        # uncached suffix [hit_len, S) while attending over the
+        # shared prefix pages (the scheduler's PrefixCache is the
+        # index half; prefix_cache=False skips building/warming it
+        # — the A/B baseline)
+        self._prefix_prefill = (
+            T.build_paged_prefix_prefill(
                 cfg, self.page_size, self.pages_per_slot,
                 donate=donate, cache_sharding=cache_sharding,
                 attn_impl=attn_impl)
-            self._step = T.build_paged_decode_step(
-                cfg, self.n_slots, self.page_size, self.pages_per_slot,
-                donate=donate, cache_sharding=cache_sharding,
-                attn_impl=attn_impl)
-            # the cross-request prefix cache's compute half: a
-            # partial/offset prefill that computes KV only for the
-            # uncached suffix [hit_len, S) while attending over the
-            # shared prefix pages (the scheduler's PrefixCache is the
-            # index half; prefix_cache=False skips building/warming it
-            # — the A/B baseline)
-            self._prefix_prefill = (
-                T.build_paged_prefix_prefill(
-                    cfg, self.page_size, self.pages_per_slot,
-                    donate=donate, cache_sharding=cache_sharding,
-                    attn_impl=attn_impl)
-                if prefix_cache else None)
-            if 1 + self.n_slots * self.pages_per_slot <= self.n_pages:
-                self._identity_tables = (
-                    1 + np.arange(self.n_slots * self.pages_per_slot,
-                                  dtype=np.int32)
-                ).reshape(self.n_slots, self.pages_per_slot)
-            else:
-                self._identity_tables = None   # pool is undersized on
-                # purpose: tables must come from the scheduler's pool
+            if prefix_cache else None)
+        self.cache = T.init_paged_kv_cache(cfg, self.n_pages,
+                                           self.page_size)
+        if 1 + self.n_slots * self.pages_per_slot <= self.n_pages:
+            self._identity_tables = (
+                1 + np.arange(self.n_slots * self.pages_per_slot,
+                              dtype=np.int32)
+            ).reshape(self.n_slots, self.pages_per_slot)
         else:
-            if attn_impl not in ("auto", "dense"):
-                # the kernel fuses the PAGED gather; the dense lane
-                # pool has none — refuse loudly rather than silently
-                # serving dense numbers under a 'pallas' flag
-                raise ValueError(
-                    f"attn_impl={attn_impl!r} needs the paged cache "
-                    "(paged=True); the dense lane pool has no gather "
-                    "to fuse")
-            self.page_size = self.pages_per_slot = 0
-            self.n_pages = 0
-            self.attn_impl = "dense"
-            self._identity_tables = None
-            self._prefix_prefill = None
-            self.cache = T.init_kv_cache(cfg, self.n_slots,
-                                         self.max_len)
-            self._prefill = T.build_prefill(
-                cfg, donate=donate, cache_sharding=cache_sharding)
-            self._step = T.build_decode_step(
-                cfg, self.n_slots, self.max_len, donate=donate,
-                cache_sharding=cache_sharding)
+            self._identity_tables = None   # pool is undersized on
+            # purpose: tables must come from the scheduler's pool
         if cache_sharding is not None:
             import jax
             self.cache = jax.device_put(self.cache, cache_sharding)
@@ -298,10 +266,6 @@ class TransformerDecoder:
                 raise ValueError("draft_params needs draft_cfg")
             if draft_cfg.vocab != cfg.vocab:
                 raise ValueError("draft and target must share a vocab")
-            if not self.paged:
-                raise ValueError(
-                    "speculative decoding rides the paged cache "
-                    "(paged=True)")
             if mesh is not None:
                 raise ValueError(
                     "speculative decoding with a mesh is not wired "
@@ -419,9 +383,8 @@ class TransformerDecoder:
     def prefill_logits(self, slot: int, prompt: np.ndarray,
                        page_table=None, draft: bool = True
                        ) -> "tuple[int, Any]":
-        """Fill ``slot``'s cache lane (dense) or its claimed pages
-        (paged — ``page_table``; identity fallback when omitted) from
-        ``prompt``; returns the first generated greedy token AND the
+        """Fill ``slot``'s claimed pages (``page_table``; identity
+        fallback when omitted) from ``prompt``; returns the first generated greedy token AND the
         last-position logits (a device array — only a sampling caller
         pays the host fetch). With a draft configured, the draft's
         slot lane is prefilled too (both models must agree on the
@@ -430,15 +393,10 @@ class TransformerDecoder:
         scheduler skips the wasted draft pass)."""
         import jax.numpy as jnp
         padded = self.pad_prompt(prompt)
-        if self.paged:
-            self.cache, nxt, logits = self._prefill(
-                self.params, self.cache, jnp.asarray(padded),
-                jnp.asarray(self._table_for(slot, page_table)),
-                np.int32(len(prompt)))
-        else:
-            self.cache, nxt, logits = self._prefill(
-                self.params, self.cache, jnp.asarray(padded),
-                np.int32(slot), np.int32(len(prompt)))
+        self.cache, nxt, logits = self._prefill(
+            self.params, self.cache, jnp.asarray(padded),
+            jnp.asarray(self._table_for(slot, page_table)),
+            np.int32(len(prompt)))
         if self.has_draft and draft:
             self.draft_cache, _, _ = self._draft_prefill(
                 self.draft_params, self.draft_cache,
@@ -489,11 +447,11 @@ class TransformerDecoder:
                     page_tables=None) -> "tuple[np.ndarray, Any]":
         """One token for every slot: ``tokens``/``pos`` are the full
         fixed ``[n_slots]`` arrays (free slots ride along at token 0 /
-        pos 0, paged free slots with an all-scratch table row).
+        pos 0 with an all-scratch table row).
         Returns greedy next tokens plus the full per-slot logits
         (device array; fetched only when a sampler needs it)."""
         import jax.numpy as jnp
-        if self.paged and page_tables is None:
+        if page_tables is None:
             if self._identity_tables is None:
                 raise ValueError("undersized paged pool needs "
                                  "scheduler page tables")
@@ -502,15 +460,10 @@ class TransformerDecoder:
         # the wait for the device and the copy back: two spans that land
         # in the pass the scheduler has open on this thread
         with span("decode.dispatch"):
-            if self.paged:
-                self.cache, nxt, logits = self._step(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(pos),
-                    jnp.asarray(np.asarray(page_tables, np.int32)))
-            else:
-                self.cache, nxt, logits = self._step(
-                    self.params, self.cache, jnp.asarray(tokens),
-                    jnp.asarray(pos))
+            self.cache, nxt, logits = self._step(
+                self.params, self.cache, jnp.asarray(tokens),
+                jnp.asarray(pos),
+                jnp.asarray(np.asarray(page_tables, np.int32)))
         with span("decode.fetch"):
             out = np.asarray(nxt)
         return out, logits
@@ -583,17 +536,17 @@ class TransformerDecoder:
     def warmup(self) -> int:
         """Compile the decode step, every prefill bucket, and (when
         speculation is on) the draft/propose/verify machinery before
-        traffic (the cache content it writes lands on scratch pages /
-        free lanes, which the next real prefill overwrites). Returns
+        traffic (the cache content it writes lands on the scratch page
+        and the draft's free lanes, which the next real prefill
+        overwrites). Returns
         the compile count — the post-warmup baseline."""
         zeros_t = np.zeros(self.n_slots, np.int32)
-        zero_tables = (np.zeros((self.n_slots, self.pages_per_slot),
-                                np.int32) if self.paged else None)
+        zero_tables = np.zeros((self.n_slots, self.pages_per_slot),
+                               np.int32)
         self.step(zeros_t, zeros_t.copy(), zero_tables)
         for bucket in self.prompt_buckets():
             self.prefill(0, np.zeros(min(bucket, self.max_len - 1),
-                                     np.int32),
-                         zero_tables[0] if self.paged else None)
+                                     np.int32), zero_tables[0])
         if self._prefix_prefill is not None:
             # the offset prefill compiles per SUFFIX bucket — the same
             # pow2 ladder (hit depth is a traced scalar, not a shape)
@@ -1220,7 +1173,7 @@ class _DecodeRequest:
         self.spec = spec
         self.produced: List[int] = []       # incremental emission
         self.slot: Optional[int] = None
-        self.pages: List[int] = []          # held KV pages (paged):
+        self.pages: List[int] = []          # held KV pages:
         # the first hit_len // page_size are SHARED prefix pages
         # (ref'd, read-only), the rest privately claimed
         # pages of the second kind of row (a finished window's
@@ -1289,33 +1242,27 @@ class DecodeScheduler:
         self.tracer = tracer
         self.idle_wait_s = float(idle_wait_s)
         self.pool = SlotPool(decoder.n_slots)
-        # the page plane (paged decoders): the shared page pool plus
-        # the live [n_slots, pages_per_slot] tables the jitted step/
-        # verify read — unclaimed entries stay 0 (the scratch page)
-        self.pages: Optional[PagePool] = None
-        self._tables: Optional[np.ndarray] = None
+        # the page plane: the shared page pool plus the live
+        # [n_slots, pages_per_slot] tables the jitted step/verify
+        # read — unclaimed entries stay 0 (the scratch page)
+        self.pages = PagePool(decoder.n_pages)
+        self._tables = np.zeros(
+            (decoder.n_slots, decoder.pages_per_slot), np.int32)
+        # the cross-request prefix cache: "auto" turns it on exactly
+        # when the decoder built the offset-prefill machinery
+        # (prefix_cache=False there is the A/B baseline)
         self.prefix: Optional[PrefixCache] = None
-        if decoder.paged:
-            self.pages = PagePool(decoder.n_pages)
-            self._tables = np.zeros(
-                (decoder.n_slots, decoder.pages_per_slot), np.int32)
-            # the cross-request prefix cache: "auto" turns it on
-            # exactly when the decoder built the offset-prefill
-            # machinery (prefix_cache=False there is the A/B baseline)
-            if prefix_cache == "auto":
-                prefix_cache = decoder.has_prefix_prefill
-            if prefix_cache:
-                if not decoder.has_prefix_prefill:
-                    raise ValueError(
-                        "prefix_cache=True needs a decoder built "
-                        "with prefix_cache=True (the offset-prefill "
-                        "machinery)")
-                self.prefix = PrefixCache(
-                    self.pages, decoder.page_size,
-                    max_pages=prefix_cache_pages, clock=clock)
-        elif prefix_cache is True:
-            raise ValueError("the prefix cache rides the paged pool "
-                             "(paged=True)")
+        if prefix_cache == "auto":
+            prefix_cache = decoder.has_prefix_prefill
+        if prefix_cache:
+            if not decoder.has_prefix_prefill:
+                raise ValueError(
+                    "prefix_cache=True needs a decoder built "
+                    "with prefix_cache=True (the offset-prefill "
+                    "machinery)")
+            self.prefix = PrefixCache(
+                self.pages, decoder.page_size,
+                max_pages=prefix_cache_pages, clock=clock)
         self._waiting: deque = deque()
         self._by_rid: Dict[str, _DecodeRequest] = {}
         self._active: Dict[int, _DecodeRequest] = {}
@@ -1438,18 +1385,17 @@ class DecodeScheduler:
              "accepted / proposed).", lambda: self.n_spec_accepted),
         ):
             m.counter(name, help_).set_function(fn)
-        if self.pages is not None:
-            m.gauge("serving_decode_pages_free",
-                    "Free KV-cache pages in the shared pool."
-                    ).set_function(lambda: self.pages.n_free)
-            m.gauge("serving_decode_pages_in_use",
-                    "KV-cache pages currently held by live slots "
-                    "(prefix-cache residents are NOT in use — see "
-                    "serving_decode_pages_cached).").set_function(
-                self._pages_in_use)
-            m.gauge("serving_decode_page_high_water",
-                    "Most pages ever simultaneously claimed."
-                    ).set_function(lambda: self.pages.high_water)
+        m.gauge("serving_decode_pages_free",
+                "Free KV-cache pages in the shared pool."
+                ).set_function(lambda: self.pages.n_free)
+        m.gauge("serving_decode_pages_in_use",
+                "KV-cache pages currently held by live slots "
+                "(prefix-cache residents are NOT in use — see "
+                "serving_decode_pages_cached).").set_function(
+            self._pages_in_use)
+        m.gauge("serving_decode_page_high_water",
+                "Most pages ever simultaneously claimed."
+                ).set_function(lambda: self.pages.high_water)
         if self.prefix is not None:
             m.gauge("serving_decode_pages_cached",
                     "KV-cache pages resident in the prefix-cache "
@@ -1517,10 +1463,9 @@ class DecodeScheduler:
                   "the goodput numerator; serving_decode_tokens_total "
                   "is the all-reasons denominator."
                   ).set_function(lambda: self.n_goodput_tokens)
-        if self.pages is not None:
-            m.gauge("serving_decode_kv_pool_bytes",
-                    "Live bytes held by the paged KV pool."
-                    ).set_function(self._cache_bytes)
+        m.gauge("serving_decode_kv_pool_bytes",
+                "Live bytes held by the paged KV pool."
+                ).set_function(self._cache_bytes)
         if self.prefix is not None:
             m.gauge("serving_decode_prefix_cache_bytes",
                     "Bytes held by prefix-cache resident pages."
@@ -1738,29 +1683,28 @@ class DecodeScheduler:
                 pending.payload))
         req = _DecodeRequest(pending, prompt, max_new, sampler, spec)
         req.t_submit = self.clock.now()
-        if self.pages is not None:
-            # admission-time page check: the prompt (plus the first
-            # generated row) must fit the pool outright. Advisory —
-            # running slots may grow before this request reaches a
-            # slot, and _admit_waiting re-checks — but it turns a
-            # full pool into an honest 429 instead of a queued
-            # request that can never start.
-            need = sum(self.decoder.prefill_pages(len(prompt)))
-            # cache-full admission sheds BEFORE touching shared state:
-            # cached pages count as reclaimable headroom (eviction
-            # frees them at claim time), but no lookup, ref, or
-            # eviction happens for a request that only sheds.
-            # n_cached is the O(1) UPPER bound (pinned cached pages
-            # are not really evictable) — an optimistic admit just
-            # waits head-of-line like any page-tight request, which
-            # this check is already advisory about.
-            avail = self.pages.n_free + (
-                self.prefix.n_cached if self.prefix is not None
-                else 0)
-            if avail < need:
-                raise DecodeOverloaded(
-                    f"decode page pool exhausted ({need} pages "
-                    f"needed, {avail} free or evictable)")
+        # admission-time page check: the prompt (plus the first
+        # generated row) must fit the pool outright. Advisory —
+        # running slots may grow before this request reaches a
+        # slot, and _admit_waiting re-checks — but it turns a
+        # full pool into an honest 429 instead of a queued
+        # request that can never start.
+        need = sum(self.decoder.prefill_pages(len(prompt)))
+        # cache-full admission sheds BEFORE touching shared state:
+        # cached pages count as reclaimable headroom (eviction
+        # frees them at claim time), but no lookup, ref, or
+        # eviction happens for a request that only sheds.
+        # n_cached is the O(1) UPPER bound (pinned cached pages
+        # are not really evictable) — an optimistic admit just
+        # waits head-of-line like any page-tight request, which
+        # this check is already advisory about.
+        avail = self.pages.n_free + (
+            self.prefix.n_cached if self.prefix is not None
+            else 0)
+        if avail < need:
+            raise DecodeOverloaded(
+                f"decode page pool exhausted ({need} pages "
+                f"needed, {avail} free or evictable)")
         with self._lock:
             if len(self._waiting) >= self.max_waiting:
                 raise DecodeOverloaded("decode waiting queue full")
@@ -1814,8 +1758,7 @@ class DecodeScheduler:
                 self._active.pop(req.slot, None)
             self._tokens[req.slot] = 0
             self._pos[req.slot] = 0
-            if self._tables is not None:
-                self._tables[req.slot, :] = 0
+            self._tables[req.slot, :] = 0
             self.pool.release(req.slot)
             self.release_ewma.note()
             t1 = self._now()
@@ -2062,8 +2005,8 @@ class DecodeScheduler:
             return self._waiting.popleft()
 
     def _admit_waiting(self) -> int:
-        """Between steps: claim free slots (and, paged, the prompt's
-        pages) for waiting requests — one prefill each, under a
+        """Between steps: claim free slots (and the prompt's pages)
+        for waiting requests — one prefill each, under a
         ``decode.prefill`` span whose two ends are the request's
         ``prefill`` span, ``prefill_s`` and the prefill histogram.
         Cancelled/expired/disconnected waiters resolve WITHOUT ever
@@ -2088,47 +2031,41 @@ class DecodeScheduler:
                 self._finish(req, "disconnected", status=500,
                              error="client disconnected")
                 continue
-            pages: List[int] = []
             hit_len = 0
-            n_sum = 0
-            if self.pages is not None:
-                shared: List[int] = []
-                if self.prefix is not None:
-                    # longest cached prefix: matched pages arrive
-                    # ref'd — on any bail-out below they are released
-                    # (the cache keeps its own reference)
-                    hit_len, shared = self.prefix.lookup(req.prompt)
-                # rows as the decoder counts them: the summary pages
-                # the prompt leaves, then the most window pages the
-                # prefill holds at once
-                n_sum, n_win = self.decoder.prefill_pages(len(req.prompt))
-                own = self._claim_pages(n_sum + n_win - len(shared))
-                if own is None:
-                    # not enough pages YET: head-of-line waits for
-                    # running requests to release theirs (it looks up
-                    # afresh next pass — the hit ledger only counts
-                    # ADMITTED requests, so retry ticks cost nothing)
-                    if shared:
-                        self.pages.release(shared)
-                    with self._lock:
-                        self._waiting.appendleft(req)
-                    return admitted
-                pages = shared + own
+            shared: List[int] = []
+            if self.prefix is not None:
+                # longest cached prefix: matched pages arrive
+                # ref'd — on any bail-out below they are released
+                # (the cache keeps its own reference)
+                hit_len, shared = self.prefix.lookup(req.prompt)
+            # rows as the decoder counts them: the summary pages
+            # the prompt leaves, then the most window pages the
+            # prefill holds at once
+            n_sum, n_win = self.decoder.prefill_pages(len(req.prompt))
+            own = self._claim_pages(n_sum + n_win - len(shared))
+            if own is None:
+                # not enough pages YET: head-of-line waits for
+                # running requests to release theirs (it looks up
+                # afresh next pass — the hit ledger only counts
+                # ADMITTED requests, so retry ticks cost nothing)
+                if shared:
+                    self.pages.release(shared)
+                with self._lock:
+                    self._waiting.appendleft(req)
+                return admitted
+            pages = shared + own
             slot = self.pool.claim()
             if slot is None:      # raced a concurrent release? retry
-                if pages:
-                    self.pages.release(pages)
+                self.pages.release(pages)
                 with self._lock:
                     self._waiting.appendleft(req)
                 return admitted
             if self.prefix is not None:
                 # one monotonic hit-ledger bump per ADMITTED request
                 self.prefix.count(hit_len)
-            table = None
-            if self._tables is not None:
-                self._tables[slot] = self.decoder.lane(pages[:n_sum],
-                                                       pages[n_sum:])
-                table = self._tables[slot]
+            self._tables[slot] = self.decoder.lane(pages[:n_sum],
+                                                   pages[n_sum:])
+            table = self._tables[slot]
             bucket = bucket_target(len(req.prompt) - hit_len,
                                    self.decoder.max_len)
             sp = span("decode.prefill", bucket=bucket,
@@ -2160,10 +2097,8 @@ class DecodeScheduler:
                             np.asarray(last_logits))
             except Exception as e:  # noqa: BLE001 — injected or real
                 self.pool.release(slot)
-                if pages:
-                    self.pages.release(pages)
-                if self._tables is not None:
-                    self._tables[slot, :] = 0
+                self.pages.release(pages)
+                self._tables[slot, :] = 0
                 self._add_span(req, "queue_wait", req.t_submit,
                                sp.t0 * 1e-9)
                 self._add_span(req, "prefill", sp.t0 * 1e-9,
@@ -2338,14 +2273,13 @@ class DecodeScheduler:
             elif p.deadline is not None and p.deadline.expired:
                 self._finish(req, "deadline", status=504,
                              error="deadline exceeded mid-decode")
-        if self.pages is not None:
-            for slot, req in list(self._active.items()):
-                if not self._ensure_pages(req, int(self._pos[slot])):
-                    # the pool cannot hold this slot's NEXT row: the
-                    # request ends with its partial output rather
-                    # than corrupt anyone — never a mid-decode OOM
-                    self.n_page_preempts += 1
-                    self._finish(req, "pages_exhausted")
+        for slot, req in list(self._active.items()):
+            if not self._ensure_pages(req, int(self._pos[slot])):
+                # the pool cannot hold this slot's NEXT row: the
+                # request ends with its partial output rather
+                # than corrupt anyone — never a mid-decode OOM
+                self.n_page_preempts += 1
+                self._finish(req, "pages_exhausted")
         spec: Dict[int, _DecodeRequest] = {}
         if self.decoder.has_draft:
             if self.spec_policy is not None \
@@ -2389,12 +2323,11 @@ class DecodeScheduler:
     def _run_step(self) -> None:
         with span("decode.prepare") as sp:
             spec = self._prepare_round()
-            paged = self.pages is not None
             sum_rows, win_rows = self._live_rows()
             sp.attrs = {
                 "active": len(self._active),
-                "pages_in_use": self._pages_in_use() if paged else None,
-                "n_pages": self.pages.n_pages - 1 if paged else None,
+                "pages_in_use": self._pages_in_use(),
+                "n_pages": self.pages.n_pages - 1,
                 # the rows this step reads, by kind
                 "window_rows": win_rows, "summary_rows": sum_rows,
                 "traces": [getattr(r.pending, "trace", None)
@@ -2641,25 +2574,23 @@ class DecodeScheduler:
                   "sampling": (r.sampler.describe()
                                if r.sampler is not None else None)}
                  for s, r in active]
-        pages = None
-        if self.pages is not None:
-            from mmlspark_tpu.parallel.dist import tree_bytes
-            claimable = self.pages.n_pages - 1
-            free = self.pages.n_free
-            cached = (self.prefix.n_cached
-                      if self.prefix is not None else 0)
-            pages = {"page_size": self.decoder.page_size,
-                     "n_pages": claimable,
-                     "free": free,
-                     # pages live requests hold (shared prefix pages
-                     # count once however many readers share them)
-                     "in_use": claimable - free - cached,
-                     "cached": cached,
-                     "high_water": self.pages.high_water,
-                     "n_preempts": self.n_page_preempts,
-                     "pool_bytes": tree_bytes(self.decoder.cache),
-                     "per_slot": {str(s): len(r.pages) + len(r.sum_pages)
-                                  for s, r in active}}
+        from mmlspark_tpu.parallel.dist import tree_bytes
+        claimable = self.pages.n_pages - 1
+        free = self.pages.n_free
+        cached = (self.prefix.n_cached
+                  if self.prefix is not None else 0)
+        pages = {"page_size": self.decoder.page_size,
+                 "n_pages": claimable,
+                 "free": free,
+                 # pages live requests hold (shared prefix pages
+                 # count once however many readers share them)
+                 "in_use": claimable - free - cached,
+                 "cached": cached,
+                 "high_water": self.pages.high_water,
+                 "n_preempts": self.n_page_preempts,
+                 "pool_bytes": tree_bytes(self.decoder.cache),
+                 "per_slot": {str(s): len(r.pages) + len(r.sum_pages)
+                              for s, r in active}}
         spec = None
         if self.decoder.has_draft:
             proposed = self.n_spec_proposed
@@ -2684,7 +2615,6 @@ class DecodeScheduler:
                 "slots_free": self.pool.n_free,
                 "slots_high_water": self.slots_high_water,
                 "max_len": self.decoder.max_len,
-                "paged": self.decoder.paged,
                 # the decode-step gather engine: "pallas" = the fused
                 # block-table kernel, "dense" = the materialized-lane
                 # gather (CPU/mesh fallback)
@@ -2692,11 +2622,8 @@ class DecodeScheduler:
                 # the prefill engine rides the same selection: under
                 # "pallas" the cold prefills run streaming flash
                 # attention (no [S, S] scores) and the prefix prefill
-                # the fused block-table kernel (no [S, V] lane); the
-                # non-paged decoder pins prefill to "dense"
-                "attn_impl_prefill": (
-                    self.decoder.attn_impl if self.decoder.paged
-                    else "dense"),
+                # the fused block-table kernel (no [S, V] lane)
+                "attn_impl_prefill": self.decoder.attn_impl,
                 # int8-compute FFN: True when the served tree carries
                 # quantize_decode_ffn's int8 weights + scale vectors
                 "quantized_ffn": getattr(self.decoder,

@@ -35,7 +35,6 @@ class EvaByteDecoder:
     compaction programs over it. Not thread-safe: one scheduler loop
     drives it (the cache is donated through every call)."""
 
-    paged = True
     mesh = None
     quantized_ffn = False
     has_draft = False

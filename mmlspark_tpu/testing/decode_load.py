@@ -1,8 +1,7 @@
 """Decode-serving load harness: continuous vs static whole-batch A/B.
 
-Shared by ``bench.py decode_continuous_v1`` and
-``tools/bench_decode.py`` so the gate and the exploratory tool time
-exactly the same simulation. Both modes drive the SAME
+The simulation ``bench.py decode_continuous_v1`` times. Both modes
+drive the SAME
 :class:`~mmlspark_tpu.serving.decode.TransformerDecoder` (same jitted
 prefill/step, same KV pool) over the same seeded workload of requests
 arriving at staggered wall-clock offsets; only the batching discipline
@@ -61,9 +60,7 @@ def make_workload(vocab: int, n_requests: int, seed: int = 0,
     prefixes (then a unique ``prompt_lens``-cycled suffix); the rest
     get a unique random prefix of the SAME length, so both arms of a
     cache A/B see identical prompt-length distributions and only the
-    overlap differs. One generator serves ``bench.py
-    decode_prefix_cache_v1`` and ``tools/bench_decode.py
-    --prefix-share``."""
+    overlap differs (``bench.py decode_prefix_cache_v1``)."""
     rng = np.random.default_rng(seed)
     gaps = rng.exponential(mean_gap_ms / 1000.0, size=n_requests)
     arrivals = np.cumsum(gaps)

@@ -209,10 +209,24 @@ def _program(kind: str) -> str:
                           jnp.ones((2, 8), jnp.float32)).compile().as_text()
     cache = T.init_paged_kv_cache(CFG, 9, 8)
     table = jnp.zeros(4, jnp.int32)
+    slots = (jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32))
     if kind == "step":
         fn = T.build_paged_decode_step(CFG, 2, 8, 4, donate=False)
-        args = (jnp.zeros(2, jnp.int32), jnp.zeros(2, jnp.int32),
+        args = slots + (jnp.zeros((2, 4), jnp.int32),)
+    elif kind == "verify":
+        fn = T.build_paged_verify_step(CFG, 2, 3, 8, 4, donate=False,
+                                       with_scores=True, ce_impl="xla")
+        args = (jnp.zeros((2, 3), jnp.int32), slots[1],
                 jnp.zeros((2, 4), jnp.int32))
+    elif kind in ("propose", "lane_prefill"):
+        # the draft's programs, over its unpaged lane pool
+        cache = T.init_kv_cache(CFG, 2, 32)
+        if kind == "propose":
+            fn, args = T.build_draft_propose(CFG, 2, 32, 3,
+                                             donate=False), slots
+        else:
+            fn = T.build_prefill(CFG, donate=False)
+            args = (jnp.zeros(16, jnp.int32), np.int32(1), np.int32(3))
     elif kind == "prefill":
         fn = T.build_paged_prefill(CFG, 8, 4, donate=False)
         args = (jnp.zeros(16, jnp.int32), table, np.int32(3))
@@ -232,6 +246,10 @@ _PROGRAMS = {
     "prefill": ("jit_prefill", _FORWARD + ("kv.write",), ()),
     "prefix_prefill": ("jit_prefill",
                        _FORWARD + ("kv.write", "kv.gather"), ()),
+    "verify": ("jit_verify",
+               _FORWARD + ("kv.write", "kv.gather", "ce"), ()),
+    "propose": ("jit_propose", _FORWARD + ("kv.write", "kv.gather"), ()),
+    "lane_prefill": ("jit_prefill", _FORWARD + ("kv.write",), ()),
 }
 
 

@@ -926,32 +926,27 @@ class TestPagedScheduler:
     the scheduler, page-leak ledger over every release reason,
     page-exhaustion admission, mid-decode preemption."""
 
-    def test_paged_tokens_match_dense_scheduler(self):
-        """The same prompts through a paged and a dense scheduler
-        produce identical greedy sequences (4 prompt lengths)."""
+    def test_scheduler_tokens_match_greedy_reference(self):
+        """Four prompt lengths through the scheduler (two slots over
+        a nine-page pool) produce the full-context greedy
+        sequences."""
         rng = np.random.default_rng(21)
         prompts = [_prompt(rng, n) for n in (1, 3, 6, 9)]
-        outs = {}
-        for name, kw in (("dense", dict(paged=False)),
-                         ("paged", dict(paged=True, page_size=8,
-                                        n_pages=9))):
-            sched = DecodeScheduler(
-                _decoder(n_slots=2, **kw)).start()
-            try:
-                pendings = [_Pending({"prompt": p,
-                                      "max_new_tokens": 5}, f"r{i}")
-                            for i, p in enumerate(prompts)]
-                for p in pendings:
-                    sched.submit(p)
-                for p in pendings:
-                    assert p.event.wait(30)
-                outs[name] = [json.loads(p.reply)["tokens"]
-                              for p in pendings]
-            finally:
-                sched.stop()
-            assert sched.pool.n_free == 2
-        assert outs["dense"] == outs["paged"]
-        for pr, toks in zip(prompts, outs["paged"]):
+        sched = DecodeScheduler(
+            _decoder(n_slots=2, page_size=8, n_pages=9)).start()
+        try:
+            pendings = [_Pending({"prompt": p,
+                                  "max_new_tokens": 5}, f"r{i}")
+                        for i, p in enumerate(prompts)]
+            for p in pendings:
+                sched.submit(p)
+            for p in pendings:
+                assert p.event.wait(30)
+            outs = [json.loads(p.reply)["tokens"] for p in pendings]
+        finally:
+            sched.stop()
+        assert sched.pool.n_free == 2
+        for pr, toks in zip(prompts, outs):
             assert toks == _greedy_reference(pr, 5)
 
     def test_page_reclaim_after_every_release_reason(self):
@@ -967,7 +962,7 @@ class TestPagedScheduler:
         # lets expire are still decoding when it does (2,044 steps to
         # the lane's end)
         sched = DecodeScheduler(
-            _decoder(n_slots=3, max_len=2048, paged=True, page_size=8,
+            _decoder(n_slots=3, max_len=2048, page_size=8,
                      eos_id=eos),
             clock=clock).start()
         try:
@@ -1030,7 +1025,7 @@ class TestPagedScheduler:
         free, the same request admits and completes."""
         # 4 claimable pages of 4 rows; a 13-token prompt claims all 4
         sched = DecodeScheduler(
-            _decoder(n_slots=2, max_len=16, paged=True, page_size=4,
+            _decoder(n_slots=2, max_len=16, page_size=4,
                      n_pages=5)).start()
         rng = np.random.default_rng(23)
         try:
@@ -1062,7 +1057,7 @@ class TestPagedScheduler:
         # pages each? no — 2 pages needed each, only 3 exist, so the
         # second waits; instead one slot grows past its claim
         sched = DecodeScheduler(
-            _decoder(n_slots=2, max_len=16, paged=True, page_size=4,
+            _decoder(n_slots=2, max_len=16, page_size=4,
                      n_pages=4)).start()
         rng = np.random.default_rng(24)
         try:
@@ -1088,7 +1083,7 @@ class TestPagedScheduler:
         assert sched.pool.n_free == 2
 
     def test_undersized_pool_raises_without_scheduler_tables(self):
-        dec = _decoder(n_slots=2, max_len=16, paged=True, page_size=4,
+        dec = _decoder(n_slots=2, max_len=16, page_size=4,
                        n_pages=4)
         with pytest.raises(ValueError, match="PagePool"):
             dec.prefill(0, np.asarray([1, 2], np.int32))
@@ -1705,7 +1700,10 @@ class TestSpeculativeScheduler:
         finally:
             sched.stop()
 
-    def test_spec_requires_paged_and_matching_vocab(self):
+    def test_spec_requires_matching_vocab(self):
+        """A draft over another vocabulary is refused, and the unpaged
+        target is not an option any more (gone, not ignored)."""
+        import dataclasses
         from mmlspark_tpu.testing.decode_load import (
             make_spec_model_pair,
         )
@@ -1713,10 +1711,13 @@ class TestSpeculativeScheduler:
                                   d_head=8, d_ff=32, n_stages=1,
                                   layers_per_stage=4)
         params, dp, dcfg = make_spec_model_pair(cfg, draft_layers=1)
-        with pytest.raises(ValueError, match="paged"):
+        with pytest.raises(ValueError, match="share a vocab"):
+            TransformerDecoder(
+                params, cfg, n_slots=2, max_len=32, draft_params=dp,
+                draft_cfg=dataclasses.replace(dcfg, vocab=32))
+        with pytest.raises(TypeError, match="paged"):
             TransformerDecoder(params, cfg, n_slots=2, max_len=32,
-                               paged=False, draft_params=dp,
-                               draft_cfg=dcfg)
+                               paged=False)
 
     def test_speculation_policy_gates_rounds(self):
         from mmlspark_tpu.serving.policy import SpeculationPolicy
@@ -1744,8 +1745,8 @@ class TestReviewHardening:
         prompt buckets — the constructor must refuse, not crash at
         prefill."""
         with pytest.raises(ValueError, match="power of two"):
-            _decoder(max_len=96, paged=True, page_size=24)
-        _decoder(max_len=96, paged=True, page_size=32)   # fine
+            _decoder(max_len=96, page_size=24)
+        _decoder(max_len=96, page_size=32)   # fine
 
     def test_stream_query_parsed_not_substringed(self):
         """?stream=10 / ?upstream=1 must NOT upgrade to SSE."""
