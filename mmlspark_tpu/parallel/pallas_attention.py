@@ -32,14 +32,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-Q_TILE = 128         # the floor of :func:`flash_tiles`, and the tile of
-KV_TILE = 128        # the Pallas backward kernels (not tuned)
+Q_TILE = 128         # the unit the lengths are padded to, and the floor
+KV_TILE = 128        # of :func:`flash_tiles` and :func:`flash_bwd_tiles`
 LANE = 128           # lanes of a vector register: a block's VMEM width
 _NEG_INF = -1e30
 _PAD_POS = np.iinfo(np.int32).max  # sentinel: padded key, always masked
 
 # what one grid step of the forward kernel may hold in VMEM by
-# :func:`_flash_vmem_bytes`' count, and the kernel's scoped-VMEM limit
+# :func:`_flash_vmem_bytes`' count (the backward's by
+# :func:`_flash_bwd_vmem_bytes`'), and the kernel's scoped-VMEM limit
 # (the v5e's default is 16 MiB of its 128). Mosaic's own count for
 # 1024 x 1024 tiles, compiled ahead of time for the v5e: 9-12 MiB at
 # head_dim 64 in bf16 and at 128 in f32 (the count here says 11 and
@@ -74,6 +75,20 @@ def _tile_choices(s: int):
             if n % m == 0 and Q_TILE * m <= _MAX_TILE]
 
 
+def _largest_tiles(sq: int, sk: int, vmem_bytes) -> tuple:
+    """Of the ``(tq, tk)`` that divide the lengths padded to 128 and
+    whose ``vmem_bytes(tq, tk)`` fits :data:`_FLASH_VMEM_BUDGET`, the
+    pair with the most score elements a grid step, ties to the longer
+    KV tile; the floor is ``(Q_TILE, KV_TILE)``."""
+    fits = [(tq * tk, tk, tq) for tq in _tile_choices(sq)
+            for tk in _tile_choices(sk)
+            if vmem_bytes(tq, tk) <= _FLASH_VMEM_BUDGET]
+    if not fits:
+        return Q_TILE, KV_TILE
+    _, tk, tq = max(fits)
+    return tq, tk
+
+
 def flash_tiles(sq: int, sk: int, d: int, dtype) -> tuple:
     """``(tq, tk)`` of the forward kernel for these lengths, head_dim
     and operand dtype: of the pairs that divide the lengths padded to
@@ -85,13 +100,8 @@ def flash_tiles(sq: int, sk: int, d: int, dtype) -> tuple:
     and 0.90 in 1024 x 1024 (PERF.md, PR 27). Ties go to the longer KV
     tile (1024 x 512 took 1.33). The floor is ``(Q_TILE, KV_TILE)``."""
     itemsize = jnp.dtype(dtype).itemsize
-    fits = [(tq * tk, tk, tq) for tq in _tile_choices(sq)
-            for tk in _tile_choices(sk)
-            if _flash_vmem_bytes(tq, tk, d, itemsize) <= _FLASH_VMEM_BUDGET]
-    if not fits:
-        return Q_TILE, KV_TILE
-    _, tk, tq = max(fits)
-    return tq, tk
+    return _largest_tiles(
+        sq, sk, lambda tq, tk: _flash_vmem_bytes(tq, tk, d, itemsize))
 
 
 def _tile_live(qpos, kpos, causal: bool):
@@ -310,170 +320,218 @@ def flash_available() -> bool:
 # The ring path above streams (m, l, o) partials and is forward-only; this
 # is the standalone differentiable kernel for the un-ring-sharded (dense)
 # attention path in models/transformer.py — the path the single-chip train
-# bench measures. Forward reuses _flash_call; backward is the
-# FlashAttention-2 recipe: save (q, k, v, out, lse), recompute each score
-# tile in VMEM, and accumulate dq (kv-innermost grid) and dk/dv
-# (q-innermost grid) in scratch. No (S x S) matrix ever reaches HBM in
-# either direction — at seq 1024 x 8 heads x 8 layers the dense path
-# round-trips ~2 GB of scores+probabilities per train step, which is pure
-# HBM-bandwidth stall on a TPU.
+# cell measures. Forward reuses _flash_call; backward is the
+# FlashAttention-2 recipe: save q, k, v in the kernels' (B*H, S, D)
+# layout with out and the lse, recompute each score tile in VMEM, and
+# accumulate dk/dv and dq in float32 scratch. No (S x S) matrix ever
+# reaches HBM in either direction.
+
+# the backward kernel's scoped-VMEM limit (of the v5e's 128 MiB): its
+# tiles fit :data:`_FLASH_VMEM_BUDGET` by :func:`_flash_bwd_vmem_bytes`'
+# count, but the dq of the whole sequence stays in VMEM, 1 KiB a query
+# row at head_dim <= 128 in bf16 (so the kernel ends near 50,000 tokens)
+_FLASH_BWD_VMEM_LIMIT = 64 * 2**20
 
 
-def _flash_dq_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, do_ref,
-                     lse_ref, delta_ref, dq_ref, dq_acc,
-                     *, scale: float, causal: bool):
-    kv_idx = pl.program_id(2)
+def _flash_bwd_vmem_bytes(tq: int, tk: int, d: int, itemsize: int,
+                          sq_p: int) -> int:
+    """VMEM one backward grid step holds: the q/do/k/v blocks
+    double-buffered; the dk/dv blocks and the whole-sequence dq block
+    double-buffered, and their f32 accumulators; and of the (tq x tk)
+    tile two f32 temporaries (scores/probabilities, ``dp``/``ds``) and
+    ``p`` and ``ds`` in the operands' dtype. Mosaic's own count,
+    compiled ahead of time for the v5e at head_dim 64 in bf16 over 2048
+    tokens: PERF.md, PR 32 (the count here says 18 MiB for 1024 x 1024
+    tiles)."""
+    d_l = _round_up(d, LANE)
+    blocks = 2 * (2 * tq + 2 * tk) * d_l * itemsize
+    outs = 2 * (2 * tk + sq_p) * d_l * itemsize
+    scratch = (2 * tk + sq_p) * d_l * 4
+    scores = tq * tk * (2 * 4 + 2 * itemsize)
+    return blocks + outs + scratch + scores
 
-    @pl.when(kv_idx == 0)
+
+def flash_bwd_tiles(sq: int, sk: int, d: int, dtype) -> tuple:
+    """``(tq, tk)`` of the backward kernel: :func:`flash_tiles`' rule
+    over the backward's own VMEM count. On the v5e the backward of 64
+    heads of 2048 causal bf16 tokens at head_dim 64 takes 11.9 ms in
+    128 x 128 tiles, 4.31 in 256 x 256, 2.30 in 512 x 512, 2.24 in
+    512 x 1024, 2.23 in 1024 x 512 and 2.09 in 1024 x 1024 (PERF.md,
+    PR 32; XLA's einsums over the whole ``[b,H,S,S]`` took 7.12)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sq_p = _round_up(sq, Q_TILE)
+    return _largest_tiles(
+        sq, sk,
+        lambda tq, tk: _flash_bwd_vmem_bytes(tq, tk, d, itemsize, sq_p))
+
+
+def _first_live_q(j, tq: int, tk: int):
+    """Index of the first q tile that sees kv tile ``j`` under the
+    causal rule with ``arange`` positions."""
+    return (j * tk) // tq
+
+
+def _flash_bwd_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, do_ref,
+                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                      dq_acc, dk_acc, dv_acc, *, scale: float, causal: bool):
+    """One (batch*head, kv-tile, q-tile) step, q innermost: dk/dv of the
+    kv tile accumulate over the q tiles, dq of the WHOLE sequence stays
+    in VMEM over both axes, so a tile's scores are recomputed once (five
+    tile products, and one pass over the scores, for the seven and two
+    of separate dq and dk/dv kernels: 2.09 ms against 2.80 on the chip).
+    ``lse`` and ``delta = sum(do * out)`` arrive as lane-dense (1, TQ)
+    rows; ``p`` and ``ds`` are rounded to the operands' dtype before
+    their products, every accumulation is f32."""
+    j, i = pl.program_id(1), pl.program_id(2)
+    last_j, last_i = pl.num_programs(1) - 1, pl.num_programs(2) - 1
+    tq = q_ref.shape[1]
+
+    @pl.when((j == 0) & (i == 0))
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(_tile_live(qpos_ref[0], kpos_ref[0], causal))
-    def _():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = (kpos_ref[0] != _PAD_POS)[None, :]
-        if causal:
-            mask = mask & (qpos_ref[0][:, None] >= kpos_ref[0][None, :])
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)   # (TQ, TK)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0])).astype(k.dtype)      # (TQ, TK)
-        dq_acc[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    @pl.when(kv_idx == pl.num_programs(2) - 1)
-    def _():
-        dq_ref[0] = dq_acc[:]
-
-
-def _flash_dkv_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, do_ref,
-                      lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                      *, scale: float, causal: bool):
-    q_idx = pl.program_id(2)
-
-    @pl.when(q_idx == 0)
+    @pl.when(i == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(_tile_live(qpos_ref[0], kpos_ref[0], causal))
-    def _():
+    qpos, kpos = qpos_ref[0], kpos_ref[0]
+
+    def step(masked: bool):
         q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        mask = (kpos_ref[0] != _PAD_POS)[None, :]
-        if causal:
-            mask = mask & (qpos_ref[0][:, None] >= kpos_ref[0][None, :])
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)   # (TQ, TK)
+        # a row with no visible key carries lse = +BIG: its p is 0
+        p = jnp.exp(s - lse_ref[0, 0][:, None])            # (TQ, TK)
+        if masked:
+            if causal:
+                # a padded key's sentinel lies past every query
+                mask = qpos[:, None] >= kpos[None, :]
+            else:
+                mask = (kpos != _PAD_POS)[None, :]
+            p = jnp.where(mask, p, 0.0)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, 0][:, None])).astype(q.dtype)
         dv_acc[:] += jax.lax.dot_general(                   # p^T @ do
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0])).astype(q.dtype)
         dk_acc[:] += jax.lax.dot_general(                   # ds^T @ q
             ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(i * tq, tq), tq)
+        dq_acc[rows, :] += jnp.dot(ds, k,
+                                   preferred_element_type=jnp.float32)
 
-    @pl.when(q_idx == pl.num_programs(2) - 1)
+    # dead and unmasked tiles as in the forward kernel
+    live = _tile_live(qpos, kpos, causal)
+    full = _tile_full(qpos, kpos, causal)
+
+    @pl.when(live & full)
     def _():
-        dk_ref[0] = dk_acc[:]
-        dv_ref[0] = dv_acc[:]
+        step(masked=False)
+
+    @pl.when(live & jnp.logical_not(full))
+    def _():
+        step(masked=True)
+
+    @pl.when(i == last_i)
+    def _():
+        dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when((j == last_j) & (i == last_i))
+    def _():
+        dq_ref[0] = (dq_acc[:] * scale).astype(dq_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "causal", "interpret"))
+                   static_argnames=("scale", "causal", "interpret", "tiles"))
 def _flash_bwd_call(q, k, v, do, lse, delta, q_pos, k_pos,
-                    scale: float, causal: bool, interpret: bool):
-    """q/k/v/do (BH, S_pad, D) in their own dtype; lse/delta
-    (BH, S_pad, 1) f32; S_pad a multiple of the 128 tiles."""
+                    scale: float, causal: bool, interpret: bool,
+                    tiles: tuple):
+    """q/k/v/do (BH, S_pad, D) in their own dtype; lse and delta
+    (BH, 1, Sq_pad) f32, a lane-dense row a head (a (.., S, 1) array
+    takes 128 lanes a row in HBM: 67 MB at the train cell's shape);
+    positions (1, S_pad) int32, ``arange`` but for the padding; S_pad a
+    multiple of ``tiles`` = (tq, tk). Returns (dq, dk, dv) in the
+    operands' dtype and layout. Under the causal rule a dead tile's
+    q-side blocks repeat the kv tile's first live q block, so the
+    pipeline fetches nothing for it."""
     bh, sq, d = q.shape
     sk = k.shape[1]
-    nq, nk = sq // Q_TILE, sk // KV_TILE
-    q_spec = pl.BlockSpec((1, Q_TILE, d), lambda b, i, j: (b, i, 0))
-    kv_spec_dq = pl.BlockSpec((1, KV_TILE, d), lambda b, i, j: (b, j, 0))
-    stat_spec = pl.BlockSpec((1, Q_TILE, 1), lambda b, i, j: (b, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, scale=scale, causal=causal),
-        grid=(bh, nq, nk),
+    tq, tk = tiles
+    vma = _vma(q)
+
+    def q_idx(j, i):
+        return jnp.maximum(i, _first_live_q(j, tq, tk)) if causal else i
+
+    q_spec = pl.BlockSpec((1, tq, d), lambda b, j, i: (b, q_idx(j, i), 0))
+    kv_spec = pl.BlockSpec((1, tk, d), lambda b, j, i: (b, j, 0))
+    stat_spec = pl.BlockSpec((1, 1, tq), lambda b, j, i: (b, 0, q_idx(j, i)))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=scale, causal=causal),
+        grid=(bh, sk // tk, sq // tq),
         in_specs=[
-            pl.BlockSpec((1, Q_TILE), lambda b, i, j: (0, i)),
-            pl.BlockSpec((1, KV_TILE), lambda b, i, j: (0, j)),
-            q_spec, kv_spec_dq, kv_spec_dq, q_spec, stat_spec, stat_spec,
+            pl.BlockSpec((1, tq), lambda b, j, i: (0, i)),
+            pl.BlockSpec((1, tk), lambda b, j, i: (0, j)),
+            q_spec, kv_spec, kv_spec, q_spec,               # q, k, v, do
+            stat_spec, stat_spec,                           # lse, delta
         ],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32,
-                                       vma=_vma(q)),
-        scratch_shapes=[pltpu.VMEM((Q_TILE, d), jnp.float32)],
+        out_specs=[pl.BlockSpec((1, sq, d), lambda b, j, i: (b, 0, 0)),
+                   kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((bh, sk, d), k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((bh, sk, d), v.dtype, vma=vma)],
+        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32),
+                        pltpu.VMEM((tk, d), jnp.float32),
+                        pltpu.VMEM((tk, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            # dq accumulates over the kv axis too
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_FLASH_BWD_VMEM_LIMIT),
         interpret=interpret,
     )(q_pos, k_pos, q, k, v, do, lse, delta)
 
-    # dk/dv accumulate across q tiles -> q is the innermost grid axis
-    q_spec2 = pl.BlockSpec((1, Q_TILE, d), lambda b, j, i: (b, i, 0))
-    kv_spec2 = pl.BlockSpec((1, KV_TILE, d), lambda b, j, i: (b, j, 0))
-    stat_spec2 = pl.BlockSpec((1, Q_TILE, 1), lambda b, j, i: (b, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, scale=scale, causal=causal),
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, Q_TILE), lambda b, j, i: (0, i)),
-            pl.BlockSpec((1, KV_TILE), lambda b, j, i: (0, j)),
-            q_spec2, kv_spec2, kv_spec2, q_spec2, stat_spec2, stat_spec2,
-        ],
-        out_specs=[kv_spec2, kv_spec2],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32, vma=_vma(q)),
-            jax.ShapeDtypeStruct((bh, sk, d), jnp.float32, vma=_vma(q)),
-        ],
-        scratch_shapes=[pltpu.VMEM((KV_TILE, d), jnp.float32),
-                        pltpu.VMEM((KV_TILE, d), jnp.float32)],
-        interpret=interpret,
-    )(q_pos, k_pos, q, k, v, do, lse, delta)
-    return dq, dk, dv
 
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = True, scale=None,
-                    interpret: bool = False, bwd_impl: str = "xla"):
+                    interpret: bool = False):
     """Differentiable flash attention, [B, S, H, Dh] in/out.
 
     Forward = the streaming kernel above (normalized, saves the
-    log-sum-exp). Backward recomputes ``p = exp(s - lse)`` and applies
-    the FlashAttention-2 gradient algebra, via one of two engines:
-
-    - ``bwd_impl="xla"`` (default): the recompute as XLA einsums. The
-      (S x S) probabilities exist transiently but XLA fuses the chain.
-      It became the default when the Pallas backward below padded
-      head_dim 64 to 128 lanes (it no longer does); no chip record
-      compares the two (ROADMAP A6, C3).
-    - ``bwd_impl="pallas"``: dq and dk/dv Pallas kernels accumulating in
-      VMEM scratch — nothing (S x S) ever reaches HBM, the right regime
-      for long sequences where the dense recompute stops fitting.
+    log-sum-exp). Backward = one Pallas kernel (``_flash_bwd_call``)
+    that recomputes ``p = exp(s - lse)`` tile by tile in VMEM and
+    applies the FlashAttention-2 gradient algebra: ``p`` and ``ds`` are
+    rounded to the operands' dtype before their products, every
+    accumulation is float32, and nothing (S x S) reaches HBM. Its tiles
+    come from the shape (:func:`flash_bwd_tiles`), it skips and does not
+    fetch the dead causal tiles, and it masks only the tiles that need
+    it, as the forward does.
 
     Numerically equivalent to :func:`ring_attention.dense_attention` in
     value and gradients to f32 tolerance (tests/test_transformer.py).
     ``interpret=True`` runs the kernels interpreted for CPU tests.
     """
-    out, _ = _flash_fwd(q, k, v, causal, scale, interpret, bwd_impl)
+    out, _ = _flash_fwd(q, k, v, causal, scale, interpret)
     return out
 
 
-def _layout(q, k, v):
-    """Shared fwd/bwd (B*H, S_pad, D) layout: the lengths padded to 128
-    (a multiple of every tile) and the ``arange`` positions."""
+def _bh_rows(x):
+    """(B, S, H, D) -> (B*H, S, D); keeps dtype and head_dim."""
+    return _to_bh(x, x.shape[1])
+
+
+def _pad_rows(x, s_pad: int):
+    return jnp.pad(x, ((0, 0), (0, s_pad - x.shape[1]), (0, 0)))
+
+
+def _flash_fwd(q, k, v, causal, scale, interpret):
     b, sq, h, d = q.shape
     sk = k.shape[1]
     sq_p, sk_p = _round_up(sq, Q_TILE), _round_up(sk, KV_TILE)
     qpos, kpos = _padded_positions(jnp.arange(sq), jnp.arange(sk),
                                    sq_p, sk_p)
-    return (b, sq, sk, h, d, sq_p, sk_p, qpos, kpos)
-
-
-def _flash_fwd(q, k, v, causal, scale, interpret, bwd_impl):
-    (b, sq, sk, h, d, sq_p, sk_p, qpos, kpos) = _layout(q, k, v)
     scale_f = float(scale) if scale is not None else d ** -0.5
     tq, tk = flash_tiles(sq, sk, d, q.dtype)
     # the relayout around the kernel and the kernel (the jitted
@@ -481,72 +539,49 @@ def _flash_fwd(q, k, v, causal, scale, interpret, bwd_impl):
     # says which tiles ran) under names of their own, inside the
     # caller's ``attn.core``
     with jax.named_scope("flash.layout"):
-        qb, kb, vb = _to_bh(q, sq_p), _to_bh(k, sk_p), _to_bh(v, sk_p)
+        qb, kb, vb = _bh_rows(q), _bh_rows(k), _bh_rows(v)
     with jax.named_scope(f"flash.t{tq}x{tk}"):
         # normalized f32 (BH, Sq_p, D) and lse (BH, Sq_p, 1)
         out_bh, lse_bh = _flash_call(
-            qb, kb, vb, qpos, kpos, scale_f, causal, interpret,
+            _pad_rows(qb, sq_p), _pad_rows(kb, sk_p), _pad_rows(vb, sk_p),
+            qpos, kpos, scale_f, causal, interpret,
             tiles=(tq, tk), normalize=True, arange_pos=True)
     with jax.named_scope("flash.layout"):
         out = out_bh[:, :sq].reshape(b, h, sq, d).swapaxes(1, 2)
-        return out.astype(q.dtype), (q, k, v, out_bh, lse_bh)
+        # the residuals: q, k, v in the kernels' layout, unpadded (their
+        # shapes carry the lengths), so the backward transposes nothing
+        # again; the lse as one lane-dense row a head (a (.., S, 1)
+        # array takes 128 lanes a row in HBM)
+        return out.astype(q.dtype), (qb, kb, vb, out,
+                                     lse_bh.reshape(b * h, 1, sq_p))
 
 
-def _flash_bwd(causal, scale, interpret, bwd_impl, res, dout):
-    q, k, v, out_bh, lse_bh = res
-    (b, sq, sk, h, d, sq_p, sk_p, qpos, kpos) = _layout(q, k, v)
+def _flash_bwd(causal, scale, interpret, res, dout):
+    qb, kb, vb, out, lse = res
+    b, sq, h, d = dout.shape
+    sk = kb.shape[1]
+    sq_p, sk_p = lse.shape[2], _round_up(sk, KV_TILE)
+    qpos, kpos = _padded_positions(jnp.arange(sq), jnp.arange(sk),
+                                   sq_p, sk_p)
     scale_f = float(scale) if scale is not None else d ** -0.5
-
-    if bwd_impl == "xla":
-        with jax.named_scope("flash.bwd_xla"):
-            return _flash_bwd_xla(q, k, v, out_bh, lse_bh, dout, scale_f,
-                                  causal)
-
-    do_bh = _to_bh(dout, sq_p)
-    delta = jnp.sum(do_bh.astype(jnp.float32) * out_bh, axis=-1,
-                    keepdims=True)                       # (BH, Sq_p, 1)
-    dq, dk, dv = _flash_bwd_call(
-        _to_bh(q, sq_p), _to_bh(k, sk_p), _to_bh(v, sk_p),
-        do_bh, lse_bh, delta, qpos, kpos, scale_f, causal, interpret)
+    tq, tk = flash_bwd_tiles(sq, sk, d, qb.dtype)
+    with jax.named_scope("flash.layout"):
+        do_bh = _to_bh(dout, sq_p)
+        # delta = sum(do * out), one row a head like the lse
+        delta = jnp.sum(dout.astype(jnp.float32) * out, axis=-1)
+        delta = jnp.pad(delta.swapaxes(1, 2).reshape(b * h, 1, sq),
+                        ((0, 0), (0, 0), (0, sq_p - sq)))
+    with jax.named_scope(f"flash.bwd_t{tq}x{tk}"):
+        dq, dk, dv = _flash_bwd_call(
+            _pad_rows(qb, sq_p), _pad_rows(kb, sk_p), _pad_rows(vb, sk_p),
+            do_bh, lse, delta, qpos, kpos, scale_f, causal, interpret,
+            tiles=(tq, tk))
 
     def from_bh(x, s):
         return x[:, :s].reshape(b, h, s, d).swapaxes(1, 2)
 
-    return (from_bh(dq, sq).astype(q.dtype),
-            from_bh(dk, sk).astype(k.dtype),
-            from_bh(dv, sk).astype(v.dtype))
-
-
-def _flash_bwd_xla(q, k, v, out_bh, lse_bh, dout, scale_f: float,
-                   causal: bool):
-    """The XLA backward of :func:`flash_attention`: dense recompute of
-    p from the saved lse, then the FA-2 gradient algebra as einsums
-    (bf16 matmuls, f32 accumulation)."""
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    lse = lse_bh[:, :sq, 0].reshape(b, h, sq)        # (B, H, Sq)
-    out = out_bh[:, :sq].reshape(b, h, sq, d).swapaxes(1, 2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * scale_f
-    p = jnp.exp(s - lse[..., None])                  # (B, H, Sq, Sk)
-    if causal:
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
-        p = jnp.where(mask[None, None], p, 0.0)
-    do = dout.astype(jnp.float32)
-    delta = jnp.sum(do * out, axis=-1)               # (B, Sq, H)
-    pc = p.astype(q.dtype)
-    dv = jnp.einsum("bhqk,bqhd->bkhd", pc, dout,
-                    preferred_element_type=jnp.float32)
-    dp = jnp.einsum("bqhd,bkhd->bhqk", dout, v,
-                    preferred_element_type=jnp.float32)
-    ds = (p * (dp - jnp.swapaxes(delta, 1, 2)[..., None])) \
-        .astype(q.dtype)
-    dq = jnp.einsum("bhqk,bkhd->bqhd", ds, k,
-                    preferred_element_type=jnp.float32) * scale_f
-    dk = jnp.einsum("bhqk,bqhd->bkhd", ds, q,
-                    preferred_element_type=jnp.float32) * scale_f
-    return (dq.astype(q.dtype), dk.astype(k.dtype),
-            dv.astype(v.dtype))
+    with jax.named_scope("flash.layout"):
+        return from_bh(dq, sq), from_bh(dk, sk), from_bh(dv, sk)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

@@ -193,16 +193,29 @@ class TestFlashAttentionVJP:
         "s1024-d128-f32": ((1, 1024, 1, 128), "float32", (1024, 1024)),
     }
 
+    # keys of another length than the queries, and lengths that pad (to
+    # 256 x 384 and 128 x 128): ``sk`` beside the shape
+    CROSS = {
+        "s130x300-d64-f32": ((1, 130, 2, 64), 300, "float32"),
+        "s300x130-d16-bf16": ((1, 300, 2, 16), 130, "bfloat16"),
+        "s48x96-d16-f32": ((2, 48, 2, 16), 96, "float32"),
+    }
+
     @pytest.mark.parametrize("causal", [True, False])
-    @pytest.mark.parametrize("bwd_impl", ["xla", "pallas"])
-    @pytest.mark.parametrize("case", list(SHAPES))
-    def test_value_and_grads_match_dense(self, rng, causal, bwd_impl, case):
+    @pytest.mark.parametrize("case", list(SHAPES) + list(CROSS))
+    def test_value_and_grads_match_dense(self, rng, causal, case):
         from mmlspark_tpu.parallel.pallas_attention import (
             flash_attention, flash_tiles)
-        shape, dtype, tiles = self.SHAPES[case]
-        assert flash_tiles(shape[1], shape[1], shape[3], dtype) == tiles
-        q, k, v = (jnp.asarray(rng.normal(size=shape), dtype)
-                   for _ in range(3))
+        if case in self.SHAPES:
+            shape, dtype, tiles = self.SHAPES[case]
+            sk = shape[1]
+            assert flash_tiles(sk, sk, shape[3], dtype) == tiles
+        else:
+            shape, sk, dtype = self.CROSS[case]
+        kv_shape = (shape[0], sk) + shape[2:]
+        q = jnp.asarray(rng.normal(size=shape), dtype)
+        k, v = (jnp.asarray(rng.normal(size=kv_shape), dtype)
+                for _ in range(2))
         w = jnp.asarray(rng.normal(size=shape).astype(np.float32))
         # bf16 operands: p is rounded to bf16 for P.V (and ds for the
         # gradients), 2^-9 relative an element
@@ -210,13 +223,13 @@ class TestFlashAttentionVJP:
 
         def loss_flash(q, k, v):
             return jnp.sum(
-                flash_attention(q, k, v, causal, None, True, bwd_impl) * w)
+                flash_attention(q, k, v, causal, None, True) * w)
 
         def loss_dense(q, k, v):
             return jnp.sum(dense_attention(q, k, v, causal=causal) * w)
 
         f32 = [x.astype(jnp.float32) for x in (q, k, v)]
-        out_f = flash_attention(q, k, v, causal, None, True, bwd_impl)
+        out_f = flash_attention(q, k, v, causal, None, True)
         out_d = dense_attention(*f32, causal=causal)
         assert out_f.dtype == q.dtype
         np.testing.assert_allclose(np.asarray(out_f, np.float32),
@@ -232,7 +245,7 @@ class TestFlashAttentionVJP:
 
     # the two cells' calls, every prefill bucket of the serve cell
     # (B = 1, 16 heads x 128, f32), and shapes that pad
-    @pytest.mark.parametrize("sq,sk,d,dtype,want", [
+    TILE_SHAPES = [
         (2048, 2048, 64, "bfloat16", (1024, 1024)),     # pretrain-2k
         (16, 16, 128, "float32", (128, 128)),
         (32, 32, 128, "float32", (128, 128)),
@@ -245,7 +258,9 @@ class TestFlashAttentionVJP:
         (130, 300, 64, "bfloat16", (256, 384)),
         (4096, 4096, 128, "bfloat16", (1024, 1024)),
         (1024, 2048, 512, "float32", (512, 1024)),      # VMEM binds
-    ])
+    ]
+
+    @pytest.mark.parametrize("sq,sk,d,dtype,want", TILE_SHAPES)
     def test_flash_tiles(self, sq, sk, d, dtype, want):
         """The pure tile choice: tiles divide the lengths padded to 128
         (never further), fit the VMEM budget, and are as large as both
@@ -257,6 +272,48 @@ class TestFlashAttentionVJP:
         assert PA._round_up(sk, 128) % tk == 0
         assert (tq, tk) == (128, 128) or PA._flash_vmem_bytes(
             tq, tk, d, jnp.dtype(dtype).itemsize) <= PA._FLASH_VMEM_BUDGET
+
+    @pytest.mark.parametrize("sq,sk,d,dtype",
+                             [c[:4] for c in TILE_SHAPES])
+    def test_flash_bwd_tiles(self, sq, sk, d, dtype):
+        """The backward's tile choice over ``test_flash_tiles``' shapes:
+        tiles divide the lengths padded to 128 (never further), fit the
+        backward's own VMEM count, and no larger pair that divides
+        fits; 128 x 128 is the floor."""
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        tq, tk = PA.flash_bwd_tiles(sq, sk, d, dtype)
+        sq_p, sk_p = PA._round_up(sq, 128), PA._round_up(sk, 128)
+        assert sq_p % tq == 0 and sk_p % tk == 0
+        assert tq >= 128 and tk >= 128
+
+        def fits(a, b):
+            return PA._flash_bwd_vmem_bytes(
+                a, b, d, jnp.dtype(dtype).itemsize, sq_p) \
+                <= PA._FLASH_VMEM_BUDGET
+
+        assert (tq, tk) == (128, 128) or fits(tq, tk)
+        assert not any(fits(a, b) and a * b > tq * tk
+                       for a in PA._tile_choices(sq)
+                       for b in PA._tile_choices(sk))
+
+    @pytest.mark.parametrize("tq,tk,sq,sk", [
+        (1024, 1024, 2048, 2048), (512, 512, 2048, 2048),
+        (512, 1024, 2048, 2048), (1024, 512, 2048, 2048),
+        (256, 384, 256, 384), (128, 384, 384, 1152),
+    ])
+    def test_dead_causal_tiles_fetch_nothing(self, tq, tk, sq, sk):
+        """The backward's index map for the blocks that walk its inner
+        (q) axis, on the pure function: a dead causal tile (every key
+        after every query) names the block of the kv tile's first live
+        q tile, so the pipeline fetches nothing for it; a live tile
+        names its own."""
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        for j in range(sk // tk):
+            first = PA._first_live_q(j, tq, tk)
+            assert (first + 1) * tq - 1 >= j * tk       # live itself
+            for i in range(sq // tq):
+                dead = (i + 1) * tq - 1 < j * tk
+                assert max(i, first) == (first if dead else i)
 
     @pytest.mark.parametrize("causal", [True, False])
     @pytest.mark.slow

@@ -76,12 +76,11 @@ def body_forward(params, tokens, cfg, attn_mode):
                     flash_attention_folded)
                 a = flash_attention_folded(q.astype(dt), k.astype(dt),
                                            v.astype(dt), True)
-            elif attn_mode in ("flash_xla", "flash_pallas"):
+            elif attn_mode == "flash":
                 from mmlspark_tpu.parallel.pallas_attention import (
                     flash_attention)
                 a = flash_attention(q.astype(dt), k.astype(dt), v.astype(dt),
-                                    True, None, False,
-                                    attn_mode.split("_")[1])
+                                    True)
             elif attn_mode == "bf16p":
                 dh = q.shape[-1]
                 s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(dt), k.astype(dt),
@@ -194,9 +193,8 @@ VARIANTS = {
     "full_b16": dict(batch=16),
     "bf16p_b16": dict(attn_mode="bf16p", batch=16),
     "full_b32": dict(batch=32),
-    "flash_xla": dict(attn_mode="flash_xla"),
-    "flash_pallas": dict(attn_mode="flash_pallas"),
-    "flash_pallas_b16": dict(attn_mode="flash_pallas", batch=16),
+    "flash": dict(attn_mode="flash"),
+    "flash_b16": dict(attn_mode="flash", batch=16),
     "folded": dict(attn_mode="folded"),
     "folded_b16": dict(attn_mode="folded", batch=16),
     "folded_noopt": dict(attn_mode="folded", opt=False),
@@ -210,7 +208,7 @@ VARIANTS = {
     "folded_ce512": dict(attn_mode="folded", ce_mode="chunked:512"),
     "folded_s4096_b2": dict(attn_mode="folded", batch=2, seq=4096),
     "full_s4096_b2": dict(batch=2, seq=4096),
-    "flashxla_s4096_b2": dict(attn_mode="flash_xla", batch=2, seq=4096),
+    "flash_s4096_b2": dict(attn_mode="flash", batch=2, seq=4096),
 }
 
 
