@@ -1188,11 +1188,15 @@ def _fring_bwd_call(qf, kf, vf, dof, lse, delta, qpos, kpos_t,
 
 def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                        acc, m_scr, l_scr, *, scale: float,
-                       page_size: int):
+                       page_size: int, groups: int = 1):
     """One (slot, page) step: q (1, H, Dh) against the slot's p-th
-    claimed page (1, page, H, Dh), streaming-softmax stats carried in
-    VMEM scratch across the page axis."""
+    claimed page (1, page, H_kv, Dh), streaming-softmax stats carried
+    in VMEM scratch across the page axis. With ``groups`` > 1 (grouped
+    queries) the q block holds its heads group-major, ``groups`` runs of
+    ``H_kv`` rows, and the ONE fetched page serves each run in turn;
+    with one group every index below is the whole ref."""
     n, p = pl.program_id(0), pl.program_id(1)
+    h_kv = k_ref.shape[2]
 
     @pl.when(p == 0)
     def _():
@@ -1203,36 +1207,51 @@ def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     pos = pos_ref[n]
     base = p * page_size
 
+    def rows_of(g):
+        """Group ``g``'s rows of the q block, the accumulator and the
+        output, and its row of the statistics."""
+        if groups == 1:
+            return slice(None), slice(None)
+        return pl.ds(g * h_kv, h_kv), pl.ds(g, 1)
+
     # dead-page skip: the whole page is past this slot's position
     # (scratch-aimed unclaimed entries always are) — no DMA was free,
     # but the compute is
     @pl.when(base <= pos)
     def _():
-        q = q_ref[0]                                    # (H, Dh)
-        k = k_ref[0]                                    # (page, H, Dh)
-        v = v_ref[0]
-        # per-head scores via broadcast-multiply-reduce (the op is
-        # bandwidth-bound at decode widths; no MXU tile pays off at
-        # page_size x head_dim)
-        s = jnp.sum(k.astype(jnp.float32) * q[None].astype(jnp.float32),
-                    axis=2) * scale                     # (page, H)
-        idx = base + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)               # (page, 1)
-        s = jnp.where(idx <= pos, s, _NEG_INF)
-        m_prev = m_scr[:]                               # (1, H)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        pw = jnp.where(idx <= pos, jnp.exp(s - m_new), 0.0)  # (page, H)
-        alpha = jnp.exp(m_prev - m_new)                 # (1, H)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(pw, axis=0,
-                                              keepdims=True)
-        acc[:] = acc[:] * alpha.T + jnp.sum(
-            pw[:, :, None] * v.astype(jnp.float32), axis=0)  # (H, Dh)
-        m_scr[:] = m_new
+        for g in range(groups):
+            rows, stat = rows_of(g)
+            q = q_ref[0] if groups == 1 else q_ref[0, rows]   # (H_kv, Dh)
+            k = k_ref[0]                                # (page, H_kv, Dh)
+            v = v_ref[0]
+            # per-head scores via broadcast-multiply-reduce (the op is
+            # bandwidth-bound at decode widths; no MXU tile pays off at
+            # page_size x head_dim)
+            s = jnp.sum(k.astype(jnp.float32) * q[None].astype(jnp.float32),
+                        axis=2) * scale                 # (page, H_kv)
+            idx = base + jax.lax.broadcasted_iota(
+                jnp.int32, (page_size, 1), 0)           # (page, 1)
+            s = jnp.where(idx <= pos, s, _NEG_INF)
+            m_prev = m_scr[stat]                        # (1, H_kv)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            pw = jnp.where(idx <= pos, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)             # (1, H_kv)
+            l_scr[stat] = l_scr[stat] * alpha + jnp.sum(pw, axis=0,
+                                                        keepdims=True)
+            acc[rows] = acc[rows] * alpha.T + jnp.sum(
+                pw[:, :, None] * v.astype(jnp.float32), axis=0)
+            m_scr[stat] = m_new
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _():
-        l_safe = jnp.maximum(l_scr[:], 1e-30)           # (1, H)
-        o_ref[0] = (acc[:] / l_safe.T).astype(o_ref.dtype)
+        for g in range(groups):
+            rows, stat = rows_of(g)
+            l_safe = jnp.maximum(l_scr[stat], 1e-30)    # (1, H_kv)
+            out = (acc[rows] / l_safe.T).astype(o_ref.dtype)
+            if groups == 1:
+                o_ref[0] = out
+            else:
+                o_ref[0, rows] = out
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "page_size",
@@ -1243,16 +1262,30 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
     """Fused paged-attention for one decode step of one layer.
 
     ``q`` (N, H, Dh) — each slot's single query (rope applied);
-    ``k_pages``/``v_pages`` (n_pages, page_size, H, Dh) — the layer's
+    ``k_pages``/``v_pages`` (n_pages, page_size, H_kv, Dh) — the layer's
     shared page pool AFTER this step's K/V write; ``page_tables``
     (N, pages_per_slot) int32; ``pos`` (N,) int32. Returns the
     normalized attention output (N, H, Dh) — numerically the paged
     dense-gather path (softmax over ``index <= pos`` of the virtual
-    lane), computed without ever materializing the lane."""
+    lane), computed without ever materializing the lane.
+
+    **Grouped queries**: ``H_kv`` may divide ``H`` (query head ``j``
+    reads K/V head ``j // (H / H_kv)``). The pool keeps ``H_kv`` heads,
+    a page is fetched once a grid step and serves its ``H / H_kv`` query
+    heads there; nothing is repeated in HBM. With ``H_kv == H`` the
+    program is the one this function always built."""
     n, h, d = q.shape
+    h_kv = k_pages.shape[2]
+    if h % h_kv:
+        raise ValueError(f"{h} query heads over {h_kv} K/V heads")
+    groups = h // h_kv
     pps = page_tables.shape[1]
     kernel = functools.partial(_paged_attn_kernel, scale=float(scale),
-                               page_size=int(page_size))
+                               page_size=int(page_size), groups=groups)
+    if groups > 1:
+        # group-major rows: run g holds query heads g, g + G, ... so
+        # that row i of a run reads K/V head i
+        q = q.reshape(n, h_kv, groups, d).swapaxes(1, 2).reshape(n, h, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n, pps),
@@ -1261,26 +1294,29 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
             # the paged gather itself: the page DMA is AIMED by the
             # scalar-prefetched table — block (table[n, p], ...) of the
             # shared pool streams in, no host- or HBM-side gather
-            pl.BlockSpec((1, page_size, h, d),
+            pl.BlockSpec((1, page_size, h_kv, d),
                          lambda n_, p_, tbl, ps_: (tbl[n_, p_], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, h, d),
+            pl.BlockSpec((1, page_size, h_kv, d),
                          lambda n_, p_, tbl, ps_: (tbl[n_, p_], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, h, d),
                                lambda n_, p_, tbl, ps_: (n_, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),    # acc
-            pltpu.VMEM((1, h), jnp.float32),    # running max
-            pltpu.VMEM((1, h), jnp.float32),    # normalizer
+            pltpu.VMEM((h, d), jnp.float32),            # acc
+            pltpu.VMEM((groups, h_kv), jnp.float32),    # running max
+            pltpu.VMEM((groups, h_kv), jnp.float32),    # normalizer
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, h, d), q.dtype),
         interpret=interpret,
     )(page_tables.astype(jnp.int32), pos.astype(jnp.int32),
       q, k_pages, v_pages)
+    if groups > 1:
+        out = out.reshape(n, groups, h_kv, d).swapaxes(1, 2).reshape(n, h, d)
+    return out
 
 
 def paged_attention_available() -> bool:
@@ -1316,15 +1352,51 @@ def paged_attention_available() -> bool:
 
 
 def flash_prefill_attention(q, k, v, scale=None,
-                            interpret: bool = False):
+                            interpret: bool = False, q_offset=None):
     """Normalized causal flash self-attention for the in-flight
     prefill path: ``q``/``k``/``v`` [B, S, H, Dh] -> [B, S, H, Dh],
     forward-only, no [S, S] score matrix in HBM. Numerics match
     ``dense_attention(q, k, v, causal=True)`` (same default
     ``Dh**-0.5`` scale, f32 accumulation) to streaming-softmax
     reassociation tolerance; token-for-token argmax parity is
-    test-pinned."""
-    return flash_attention(q, k, v, True, scale, interpret)
+    test-pinned.
+
+    **Grouped queries and a tile of a longer lane**: ``k``/``v`` may be
+    [B, S_k, H_kv, Dh] with ``H_kv`` dividing ``H`` (query head ``j``
+    reads K/V head ``j // (H / H_kv)``) and, with ``q_offset`` (a traced
+    scalar), ``S_k >= S`` rows at positions ``arange(S_k)`` that the
+    queries, at ``q_offset + arange(S)``, see causally. A K/V head's
+    ``H / H_kv`` query heads are folded into the kernel's query rows, so
+    each K/V tile is fetched once for all of them and nothing is
+    repeated in HBM. With ``H_kv == H`` and no offset this is the
+    program this function always built."""
+    if q_offset is None and k.shape[2] == q.shape[2]:
+        return flash_attention(q, k, v, True, scale, interpret)
+    b, s, h, d = q.shape
+    sk, h_kv = k.shape[1], k.shape[2]
+    if h % h_kv:
+        raise ValueError(f"{h} query heads over {h_kv} K/V heads")
+    g = h // h_kv
+    s_p, sk_p = _round_up(s, Q_TILE), _round_up(sk, KV_TILE)
+    q0 = 0 if q_offset is None else q_offset
+    q_pos = jnp.tile(jnp.pad(q0 + jnp.arange(s, dtype=jnp.int32),
+                             (0, s_p - s)), g)
+    qpos_p, kpos_p = _padded_positions(q_pos, jnp.arange(sk), g * s_p, sk_p)
+    scale_f = float(scale) if scale is not None else d ** -0.5
+    tiles = flash_tiles(g * s_p, sk, d, q.dtype)
+    with jax.named_scope("flash.layout"):
+        # (B, S, H_kv, G, D) -> (B * H_kv, G * S_p, D): a K/V head's
+        # query heads one after the other
+        qf = jnp.pad(q.reshape(b, s, h_kv, g, d),
+                     ((0, 0), (0, s_p - s), (0, 0), (0, 0), (0, 0)))
+        qf = qf.transpose(0, 2, 3, 1, 4).reshape(b * h_kv, g * s_p, d)
+    with jax.named_scope(f"flash.t{tiles[0]}x{tiles[1]}"):
+        o, _ = _flash_call(qf, _to_bh(k, sk_p), _to_bh(v, sk_p), qpos_p,
+                           kpos_p, scale_f, True, interpret, tiles=tiles,
+                           normalize=True)
+    with jax.named_scope("flash.layout"):
+        o = o.reshape(b, h_kv, g, s_p, d)[:, :, :, :s]
+        return o.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d).astype(q.dtype)
 
 
 def _paged_prefix_kernel(tbl_ref, hit_ref, q_ref, k_ref, v_ref, o_ref,

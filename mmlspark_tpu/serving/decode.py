@@ -325,6 +325,9 @@ class TransformerDecoder:
 
     #: windows turned into summary rows: none, for this kind
     n_compactions = 0
+    #: a slot holds nothing but its rows (a decoder whose slots also
+    #: hold a recurrent state says so: ``serving/hybrid_decode.py``)
+    has_slot_state = False
 
     def lane(self, sum_pages, win_pages) -> np.ndarray:
         """A slot's page-table row from the pages it holds: the one
@@ -574,6 +577,9 @@ def decoder_for(params, cfg, **kwargs):
     if kind == "eva":
         from mmlspark_tpu.serving.eva_decode import EvaByteDecoder
         return EvaByteDecoder(params, cfg, **kwargs)
+    if kind == "granite_hybrid":
+        from mmlspark_tpu.serving.hybrid_decode import HybridDecoder
+        return HybridDecoder(params, cfg, **kwargs)
     if kind != "softmax":
         raise ValueError(f"no decoder for block kind {kind!r}")
     return TransformerDecoder(params, cfg, **kwargs)
@@ -2328,6 +2334,10 @@ class DecodeScheduler:
                 "active": len(self._active),
                 "pages_in_use": self._pages_in_use(),
                 "n_pages": self.pages.n_pages - 1,
+                # slots whose recurrent state this step advances (a
+                # state a slot, beside the rows a position)
+                "state_slots": (len(self._active)
+                                if self.decoder.has_slot_state else 0),
                 # the rows this step reads, by kind
                 "window_rows": win_rows, "summary_rows": sum_rows,
                 "traces": [getattr(r.pending, "trace", None)
@@ -2563,6 +2573,7 @@ class DecodeScheduler:
             active = sorted(self._active.items())
             releases = dict(self.releases)
             rows = self._live_rows()
+            routings = getattr(self.decoder, "expert_routings", None)
         slots = [{"slot": s,
                   "rid": r.pending.rid,
                   "prompt_len": int(len(r.prompt)),
@@ -2671,6 +2682,14 @@ class DecodeScheduler:
                 # both), and the rows the live slots hold now
                 "n_compactions": self.decoder.n_compactions,
                 "window_rows": rows[1], "summary_rows": rows[0],
+                # a state a slot (docs/serving.md): requests whose
+                # first prefill tile reset their slot's recurrent
+                # state, and the routings each expert this chip holds
+                # received over every step (None: no such block kind)
+                "n_state_resets": getattr(self.decoder,
+                                          "n_state_resets", 0),
+                "expert_routings": (None if routings is None
+                                    else routings.tolist()),
                 "n_compiles": self.decoder.n_compiles(),
                 # the live honest-429 inputs: slot-release gap EWMA
                 # and the Retry-After a shed client would be told now

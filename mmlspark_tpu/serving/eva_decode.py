@@ -39,6 +39,7 @@ class EvaByteDecoder:
     quantized_ffn = False
     has_draft = False
     has_prefix_prefill = False
+    has_slot_state = False
     #: the smallest prefill bucket, as a share of the window
     MIN_BUCKET_SHARE = 16
 

@@ -444,3 +444,69 @@ class TestEvaBytePrograms:
             ints(cfg.summaries_per_window // self.PAGE)).compile()
         assert c.memory_analysis().temp_size_in_bytes \
             < self._pool_bytes(cfg, self.N_PAGES, self.PAGE) // 4
+
+
+# ---------------------------------------------------------------------------
+# the Granite-hybrid block kind at the cell's shapes
+# (benchmark/configs/granite-4.0-h-small.json): the two programs the
+# decoder compiles, against abstract weights
+
+
+class TestGraniteHybridPrograms:
+    N_SLOTS, MAX_LEN, N_PAGES, TILE = 16, 10240, 10241, 512
+
+    @pytest.fixture(scope="class")
+    def shapes(self, topo):
+        from mmlspark_tpu.models import granite_hybrid as GH
+        cfg = GH.GraniteHybridConfig(vocab=50176,
+                                     experts_held=tuple(range(36)))
+        one = NamedSharding(_mesh(topo, {"x": 1}), P())
+        params = _abstract(jax.eval_shape(lambda: GH.init_params(cfg, 0)),
+                           one)
+        cache = _abstract(jax.eval_shape(
+            lambda: GH.init_cache(cfg, self.N_SLOTS, self.N_PAGES, PAGE)),
+            one)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+        return GH, cfg, params, cache, i32
+
+    @staticmethod
+    def _nbytes(tree) -> int:
+        return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                   for x in jax.tree.leaves(tree))
+
+    def test_step(self, shapes):
+        """One token for 16 slots: the recurrent state and the K/V
+        pools are updated where they lie (every cache byte aliased, no
+        temporary the size of a state), and the grouped-query paged
+        kernel is the one Mosaic call."""
+        GH, cfg, params, cache, i32 = shapes
+        c = GH.build_hybrid_step(cfg, PAGE, attn_impl="pallas").lower(
+            params, cache, i32(self.N_SLOTS), i32(self.N_SLOTS),
+            i32(self.N_SLOTS, self.MAX_LEN // PAGE)).compile()
+        mem = c.memory_analysis()
+        assert mem.alias_size_in_bytes == self._nbytes(cache)
+        assert mem.temp_size_in_bytes < 64 * 2**20
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 12 * 2**30
+        assert _n_mosaic(c) == 1
+        assert "paged_decode_attention" in c.as_text()
+        # 4.76 B parameters in bfloat16 on this chip
+        assert 9.4e9 < self._nbytes(params) < 9.6e9
+
+    def test_prefill_tile(self, shapes):
+        """A 512-token tile: the flash kernel over the lane so far, the
+        experts as grouped products (two a layer), the state carried."""
+        GH, cfg, params, cache, i32 = shapes
+        c = GH.build_hybrid_prefill(cfg, PAGE, attn_impl="pallas").lower(
+            params, cache, i32(self.TILE), i32(self.MAX_LEN // PAGE),
+            i32(), i32(), i32()).compile()
+        mem = c.memory_analysis()
+        assert mem.alias_size_in_bytes == self._nbytes(cache)
+        assert mem.temp_size_in_bytes < 512 * 2**20
+        text = c.as_text()
+        assert len(_flash_call_names(c)) == 1
+        assert text.count("ragged-dot-metadata") >= 1
+        assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 2 * cfg.n_layers

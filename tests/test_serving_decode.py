@@ -1822,3 +1822,157 @@ class TestReviewHardening:
             sched.stop()
         assert sched.pool.n_free == 2
         assert _pages_idle(sched)
+
+
+class TestStateASlot:
+    """The cache manager with a decoder whose slots hold a recurrent
+    state beside their rows (the Granite-hybrid block kind, ``serving/
+    hybrid_decode.py``: two Mamba layers around one grouped-query
+    attention layer, 2 of 4 experts held, tiles of 8, pages of 4 rows):
+    pages count the attention layer's K/V rows only, the state is the
+    slot's and is reset by a request's first tile, on the release path
+    every request shares."""
+
+    G_CFG = None
+
+    @classmethod
+    def _decoder(cls, **kw):
+        from mmlspark_tpu.models import granite_hybrid as GH
+        from mmlspark_tpu.serving.decode import decoder_for
+        if cls.G_CFG is None:
+            cls.G_CFG = GH.GraniteHybridConfig(
+                vocab=64, d_model=16, n_heads=4, n_kv_heads=2, d_head=8,
+                layer_types=("mamba", "attention", "mamba"), n_experts=4,
+                top_k=2, experts_held=(0, 1), d_expert=8, d_shared=16,
+                ssm_heads=4, ssm_head_dim=8, ssm_state=8, ssm_chunk=4,
+                embed_std=0.01, dtype="float32")
+            cls.G_PARAMS = GH.init_params(cls.G_CFG, seed=3)
+        kw = dict(dict(n_slots=4, max_len=64, page_size=4, prefill_tile=8,
+                       attn_impl="dense"), **kw)
+        return decoder_for(cls.G_PARAMS, cls.G_CFG, **kw)
+
+    def test_sixteen_requests_through_four_slots(self):
+        """Every request resets the slot it lands in, reads as it does
+        alone, and leaves nothing behind; two programs whatever the
+        lengths."""
+        rng = np.random.default_rng(51)
+        prompts = [_prompt(rng, n) for n in rng.integers(3, 40, size=16)]
+        alone = self._decoder()
+        want = []
+        for prompt in prompts:
+            toks = [alone.prefill(0, np.asarray(prompt, np.int32))]
+            for i in range(5):
+                t, at = np.zeros(4, np.int32), np.zeros(4, np.int32)
+                t[0], at[0] = toks[-1], len(prompt) + i
+                toks.append(int(alone.step(t, at)[0]))
+            want.append(toks)
+        dec = self._decoder()
+        warm = dec.warmup()
+        sched = DecodeScheduler(dec).start()
+        try:
+            pend = [_Pending({"prompt": p, "max_new_tokens": 6}, f"s{i}")
+                    for i, p in enumerate(prompts)]
+            for p in pend:
+                sched.submit(p)
+            assert all(p.event.wait(60) for p in pend)
+            stats = sched.stats()
+        finally:
+            sched.stop()
+        assert [json.loads(p.reply)["tokens"] for p in pend] == want
+        assert warm == 2 and stats["n_compiles"] == 2
+        assert stats["n_state_resets"] == 16
+        assert stats["slots_high_water"] == 4
+        assert sum(stats["expert_routings"]) > 0
+        assert len(stats["expert_routings"]) == 2
+        assert stats["n_compactions"] == 0
+        assert _pages_idle(sched) and sched.pool.n_free == 4
+
+    def test_passes_carry_the_state_and_the_routings(self):
+        """``decode.prepare`` stamps the slots whose state a step
+        advances, ``decode.prefill`` the tiles walked, and the step's
+        ``decode.fetch`` what the held experts received."""
+        from mmlspark_tpu.core.tracing import Tracer
+        from mmlspark_tpu.serving.decode import pass_view
+        tracer = Tracer()
+        sched = DecodeScheduler(self._decoder(), tracer=tracer).start()
+        rng = np.random.default_rng(52)
+        try:
+            p = _Pending({"prompt": _prompt(rng, 19),
+                          "max_new_tokens": 4}, "stamps")
+            sched.submit(p)
+            assert p.event.wait(30)
+        finally:
+            sched.stop()
+        views = [pass_view(sp.attrs["phases"])
+                 for sp in tracer.recorder.scan("decode.pass")]
+        (walk,) = [q for v in views for q in v["prefills"]]
+        assert walk["tiles"] == 3 and walk["prompt_tokens"] == 19
+        steps = [v for v in views if "dispatch" in v["phases_ms"]]
+        assert len(steps) == 3
+        for v in steps:
+            assert v["state_slots"] == 1 and v["window_rows"] > 19
+            # one live slot, top 2 of 4 over three layers, 2 held
+            assert len(v["expert_routings"]) == 2
+            assert 0 <= sum(v["expert_routings"]) <= 6
+            assert v["expert_load_max"] == max(v["expert_routings"])
+            assert v["experts_touched"] <= 6
+
+    @pytest.mark.parametrize("reason", ["length", "eos", "cancelled",
+                                        "deadline", "error",
+                                        "pages_exhausted"])
+    def test_pool_back_to_its_start_after_every_release_reason(
+            self, reason):
+        """The K/V pages come back whatever ends the request, and the
+        next request in the slot starts from a zero state."""
+        clock = ManualClock()
+        rng = np.random.default_rng(53)
+        prompt = _prompt(rng, 20)
+        kw = {}
+        if reason == "pages_exhausted":
+            # the 21 rows the prefill leaves and two pages more
+            kw["n_pages"] = 1 + 6 + 2
+        dec = self._decoder(**kw)
+        if reason == "eos":
+            probe = DecodeScheduler(dec).start()
+            p = _Pending({"prompt": prompt, "max_new_tokens": 16}, "probe")
+            probe.submit(p)
+            assert p.event.wait(30)
+            probe.stop()
+            dec.eos_id = json.loads(p.reply)["tokens"][14]
+        sched = DecodeScheduler(dec, clock=clock).start()
+        inner = dec.step_logits
+
+        def step_logits(tokens, pos, tables=None):
+            if sched._active.get(0) is not None and int(pos[0]) == 30:
+                if reason == "cancelled":
+                    sched.cancel("r")
+                elif reason == "deadline":
+                    clock.advance(5.0)
+                elif reason == "error":
+                    raise RuntimeError("scripted step fault")
+            return inner(tokens, pos, tables)
+
+        dec.step_logits = step_logits
+        try:
+            p = _Pending({"prompt": prompt,
+                          "max_new_tokens": 16 if reason == "length"
+                          else 40}, "r",
+                         deadline=Deadline(1.0, clock=clock)
+                         if reason == "deadline" else None)
+            sched.submit(p)
+            assert p.event.wait(30)
+            out = json.loads(p.reply)
+            assert out["finish_reason"] == reason, out
+            # the slot's next request reads as in a fresh decoder
+            again = _Pending({"prompt": prompt[:9], "max_new_tokens": 3},
+                             "again")
+            sched.submit(again)
+            assert again.event.wait(30)
+            stats = sched.stats()
+        finally:
+            sched.stop()
+        fresh = self._decoder()
+        first = fresh.prefill(0, np.asarray(prompt[:9], np.int32))
+        assert json.loads(again.reply)["tokens"][0] == first
+        assert stats["n_state_resets"] >= 2
+        assert _pages_idle(sched) and sched.pool.n_free == 4

@@ -21,7 +21,10 @@ import re
 import sys
 
 SCOPES = ("embed", "norm", "attn.qkv", "attn.core", "attn.out", "ffn",
-          "moe", "head", "ce", "optimizer", "kv.write", "kv.gather")
+          "moe", "head", "ce", "optimizer", "kv.write", "kv.gather",
+          # the Granite-hybrid programs' parts (models/granite_hybrid.py)
+          "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out",
+          "moe.route", "moe.experts", "moe.shared")
 
 
 def scope_of(tf_op: str) -> str:
