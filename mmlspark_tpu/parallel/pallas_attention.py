@@ -1175,83 +1175,239 @@ def _fring_bwd_call(qf, kf, vf, dof, lse, delta, qpos, kpos_t,
 # written back to HBM) before one masked attention over it: at decode
 # the op is bandwidth-bound, and that intermediate doubles the bytes
 # every step moves. This kernel fuses gather + streaming-softmax
-# attention: the page table rides SCALAR PREFETCH (the index map reads
-# ``table[n, p]`` to aim each K/V page DMA), so pages stream
-# HBM -> VMEM exactly once, scores and the running (m, l, acc) stats
-# live in VMEM, and nothing lane-shaped ever lands in HBM. Dead pages
-# (whole page past the slot's position — including every unclaimed
-# entry aimed at the scratch page) skip their compute entirely.
+# attention: the pools stay in HBM, the page table and the positions
+# ride SCALAR PREFETCH, and the kernel itself copies the pages a slot's
+# table names into VMEM, :func:`paged_fetch_pages` of them a fetch, the
+# next fetch (the next slot's first, at a slot's end) in flight while
+# this one is summed. A slot's walk covers its LIVE entries and nothing
+# else: the first ``pos // page_size + 1`` of its row. Entries past
+# them (every unclaimed one aims at the scratch page) cost no grid
+# step, no copy and no arithmetic, and rows past ``pos`` in the last
+# live page are removed with ``where``, whatever they hold. Scores and
+# the running (m, l, acc) stats live in VMEM; nothing lane-shaped ever
+# lands in HBM.
 #
 # The dense gather stays the CPU/interpret fallback with token-for-
 # token parity pinned (tests/test_transformer.py TestPagedAttnKernel).
 
+# what the K and the V pages of two fetches (the one summed, the one in
+# flight) may hold in VMEM (the kernel asks for no scoped-VMEM limit of
+# its own: the v5e's default is 16 MiB), and the most pages a fetch may
+# name
+_PAGED_VMEM_BUDGET = 4 * 2**20
+_PAGED_MAX_FETCH = 32
 
-def _paged_attn_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc, m_scr, l_scr, *, scale: float,
-                       page_size: int, groups: int = 1):
-    """One (slot, page) step: q (1, H, Dh) against the slot's p-th
-    claimed page (1, page, H_kv, Dh), streaming-softmax stats carried
-    in VMEM scratch across the page axis. With ``groups`` > 1 (grouped
-    queries) the q block holds its heads group-major, ``groups`` runs of
-    ``H_kv`` rows, and the ONE fetched page serves each run in turn;
-    with one group every index below is the whole ref."""
+
+def _paged_page_vmem_bytes(page_size: int, h_kv: int, d: int,
+                           dtype) -> int:
+    """VMEM one ``(page_size, h_kv, d)`` page takes: its last two
+    dimensions in whole tiles of 8 x 128 elements (Mosaic keeps 8 heads
+    of bfloat16 in ``T(8,128)(2,1)`` tiles, unpadded: compiled ahead of
+    time for the v5e, 250 such pages a fetch fit 32 MiB, 300 do not)."""
+    return (page_size * _round_up(h_kv, 8) * _round_up(d, LANE)
+            * jnp.dtype(dtype).itemsize)
+
+
+def paged_fetch_pages(pages_per_slot: int, page_size: int, h_kv: int,
+                      d: int, dtype) -> int:
+    """How many consecutive table entries of a slot
+    :func:`paged_decode_attention` copies at a time: as many as fit
+    :data:`_PAGED_VMEM_BUDGET` with K and V double-buffered, at most
+    :data:`_PAGED_MAX_FETCH`, at least 2, and never more than the
+    table has. A fetch has a fixed cost (its copies' latency is hidden
+    behind the fetch before it, its bookkeeping is not): on the v5e
+    one 16-row page a grid step ran at 6-37% of the HBM roofline, and
+    a call over 690 live bfloat16 pages of 32 heads x 128 takes 0.376 /
+    0.355 / 0.354 / 0.356 ms at 2 / 4 / 8 / 16 pages a fetch, one over
+    190 float32 pages of 16 heads 0.095 / 0.096 / 0.086 / 0.092
+    (PERF.md, PR 34)."""
+    page = _paged_page_vmem_bytes(page_size, h_kv, d, dtype)
+    fit = _PAGED_VMEM_BUDGET // (4 * page)
+    return min(pages_per_slot, max(2, min(fit, _PAGED_MAX_FETCH)))
+
+
+def paged_walk(pos, page_size: int):
+    """Number of entries of its table a slot at position ``pos`` reads:
+    entries ``[0, pos // page_size]``, the pages that hold rows
+    ``index <= pos``."""
+    return pos // page_size + 1
+
+
+def _paged_fetch_live(walk, i, fetch: int):
+    """Live entries in fetch ``i`` of a walk of ``walk`` entries,
+    ``fetch`` at a time: all but a slot's last fetch are whole."""
+    return jnp.minimum(fetch, walk - i * fetch)
+
+
+def _paged_init(acc, m_scr, l_scr):
+    acc[:] = jnp.zeros_like(acc)
+    m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+
+
+def _paged_page(q_ref, k, v, acc, m_scr, l_scr, *, scale: float,
+                groups: int, live=None):
+    """Sum one page ``k``/``v`` (page, H_kv, Dh) into the streaming-
+    softmax stats ``acc`` (H, Dh), ``m_scr``/``l_scr`` (groups, H_kv, 1)
+    of the slot's query ``q_ref`` (1, H, Dh). With ``groups`` > 1
+    (grouped queries) the q block holds its heads group-major,
+    ``groups`` runs of ``H_kv`` rows, and the page serves each run in
+    turn. ``live`` (page, 1, 1) marks the rows ``index <= pos`` of a
+    slot's LAST page, the only one that has others: they are removed
+    from the scores and from the products alike, because a dead row may
+    hold anything; with ``live`` None every row counts."""
+    f32 = jnp.float32
+    h_kv = k.shape[1]
+    k, v = k.astype(f32), v.astype(f32)
+    if live is not None:
+        v = jnp.where(live, v, 0.0)
+    for g in range(groups):
+        rows = pl.ds(g * h_kv, h_kv)
+        q = q_ref[0, rows].astype(f32)              # (H_kv, Dh)
+        # per-head scores by multiply and reduce over the lanes (the
+        # float32 cell's products have to stay float32-exact, and one
+        # MXU pass is bfloat16), kept (page, H_kv, 1): heads stay on
+        # the sublanes from the page to the accumulator, so nothing is
+        # relaid out
+        s = jnp.sum(k * q[None], axis=2, keepdims=True) * scale
+        if live is not None:
+            s = jnp.where(live, s, _NEG_INF)
+        m_prev = m_scr[g]                           # (H_kv, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        pw = jnp.exp(s - m_new[None])               # (page, H_kv, 1)
+        alpha = jnp.exp(m_prev - m_new)             # (H_kv, 1)
+        l_scr[g] = l_scr[g] * alpha + jnp.sum(pw, axis=0)
+        acc[rows] = acc[rows] * alpha + jnp.sum(pw * v, axis=0)
+        m_scr[g] = m_new
+
+
+def _paged_live_rows(pos, page_size: int):
+    """(page, 1, 1) mask of the rows ``index <= pos`` in the page that
+    holds row ``pos``."""
+    return jax.lax.broadcasted_iota(
+        jnp.int32, (page_size, 1, 1), 0) <= pos % page_size
+
+
+def _paged_finish(o_ref, acc, l_scr):
+    groups, h_kv, _ = l_scr.shape
+    for g in range(groups):
+        rows = pl.ds(g * h_kv, h_kv)
+        l_safe = jnp.maximum(l_scr[g], 1e-30)       # (H_kv, 1)
+        o_ref[0, rows] = (acc[rows] / l_safe).astype(o_ref.dtype)
+
+
+def _paged_walk_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       k_buf, v_buf, sem, acc, m_scr, l_scr, n_fetched,
+                       *, page_size: int, fetch: int, **page_kw):
+    """One slot, by the kernel's own copies: its query ``q_ref``
+    (1, H, Dh) against the rows ``index <= pos`` of its virtual lane,
+    ``fetch`` pages of (page, H_kv, Dh) at a time from the pools in HBM
+    into one of two VMEM buffers each for K and V (``k_buf``/``v_buf``
+    (2, fetch, page, H_kv, Dh)). ``n_fetched`` (SMEM) counts the
+    fetches of the slots before this one: the buffers alternate across
+    slots, because a slot's last fetch starts the next slot's first."""
+    n = pl.program_id(0)
+
+    def walk(s):
+        """Entries slot ``s`` reads; never past its table's row, so no
+        position aims a copy outside the pool."""
+        return jnp.minimum(paged_walk(pos_ref[s], page_size),
+                           tbl_ref.shape[1])
+
+    def n_pages(s, i):
+        """Live entries in fetch ``i`` of slot ``s``."""
+        return _paged_fetch_live(walk(s), i, fetch)
+
+    def each_copy(s, i, b, act):
+        """``act`` on the K and the V copy of every live entry of fetch
+        ``i`` of slot ``s`` into buffer ``b``."""
+        def one(j, carry):
+            page = tbl_ref[s, i * fetch + j]
+            act(pltpu.make_async_copy(k_hbm.at[page], k_buf.at[b, j],
+                                      sem.at[b]))
+            act(pltpu.make_async_copy(v_hbm.at[page], v_buf.at[b, j],
+                                      sem.at[b]))
+            return carry
+        jax.lax.fori_loop(0, n_pages(s, i), one, 0)
+
+    def start(s, i, b):
+        each_copy(s, i, b, lambda c: c.start())
+
+    @pl.when(n == 0)
+    def _():
+        n_fetched[0] = 0
+        start(0, 0, 0)
+
+    _paged_init(acc, m_scr, l_scr)
+    pos = pos_ref[n]
+    n_fetch = pl.cdiv(walk(n), fetch)
+    first = n_fetched[0]
+
+    def page(b, j, live=None):
+        _paged_page(q_ref, k_buf[b, j], v_buf[b, j], acc, m_scr, l_scr,
+                    live=live, **page_kw)
+
+    def fetch_body(i, carry):
+        b = (first + i) % 2
+        last = i + 1 == n_fetch
+
+        @pl.when(jnp.logical_not(last))
+        def _():
+            start(n, i + 1, 1 - b)
+
+        @pl.when(last & (n + 1 < pl.num_programs(0)))
+        def _():
+            start(n + 1, 0, 1 - b)
+
+        each_copy(n, i, b, lambda c: c.wait())
+        # every page but the slot's last is live in every row
+        full = n_pages(n, i) - last.astype(jnp.int32)
+
+        def full_page(j, c):
+            page(b, j)
+            return c
+        jax.lax.fori_loop(0, full, full_page, 0)
+
+        @pl.when(last)
+        def _():
+            page(b, full, _paged_live_rows(pos, page_size))
+        return carry
+
+    jax.lax.fori_loop(0, n_fetch, fetch_body, 0)
+    n_fetched[0] = first + n_fetch
+    _paged_finish(o_ref, acc, l_scr)
+
+
+def _paged_grid_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
+                       acc, m_scr, l_scr, *, page_size: int, **page_kw):
+    """One (slot, table entry) grid step, by the ``BlockSpec``
+    pipeline: the feed for a head_dim that is not whole 128-lane
+    registers (Mosaic slices no such memref, so the kernel cannot aim
+    its own copies there). An entry past the slot's walk names the
+    walk's last page again, so nothing is fetched for it, and is
+    skipped; it is still a grid step."""
     n, p = pl.program_id(0), pl.program_id(1)
-    h_kv = k_ref.shape[2]
 
     @pl.when(p == 0)
     def _():
-        acc[:] = jnp.zeros_like(acc)
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+        _paged_init(acc, m_scr, l_scr)
 
     pos = pos_ref[n]
-    base = p * page_size
+    last = paged_walk(pos, page_size) - 1
 
-    def rows_of(g):
-        """Group ``g``'s rows of the q block, the accumulator and the
-        output, and its row of the statistics."""
-        if groups == 1:
-            return slice(None), slice(None)
-        return pl.ds(g * h_kv, h_kv), pl.ds(g, 1)
+    def page(live=None):
+        _paged_page(q_ref, k_ref[0], v_ref[0], acc, m_scr, l_scr,
+                    live=live, **page_kw)
 
-    # dead-page skip: the whole page is past this slot's position
-    # (scratch-aimed unclaimed entries always are) — no DMA was free,
-    # but the compute is
-    @pl.when(base <= pos)
+    pl.when(p < last)(page)
+
+    @pl.when(p == last)
     def _():
-        for g in range(groups):
-            rows, stat = rows_of(g)
-            q = q_ref[0] if groups == 1 else q_ref[0, rows]   # (H_kv, Dh)
-            k = k_ref[0]                                # (page, H_kv, Dh)
-            v = v_ref[0]
-            # per-head scores via broadcast-multiply-reduce (the op is
-            # bandwidth-bound at decode widths; no MXU tile pays off at
-            # page_size x head_dim)
-            s = jnp.sum(k.astype(jnp.float32) * q[None].astype(jnp.float32),
-                        axis=2) * scale                 # (page, H_kv)
-            idx = base + jax.lax.broadcasted_iota(
-                jnp.int32, (page_size, 1), 0)           # (page, 1)
-            s = jnp.where(idx <= pos, s, _NEG_INF)
-            m_prev = m_scr[stat]                        # (1, H_kv)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-            pw = jnp.where(idx <= pos, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)             # (1, H_kv)
-            l_scr[stat] = l_scr[stat] * alpha + jnp.sum(pw, axis=0,
-                                                        keepdims=True)
-            acc[rows] = acc[rows] * alpha.T + jnp.sum(
-                pw[:, :, None] * v.astype(jnp.float32), axis=0)
-            m_scr[stat] = m_new
+        page(_paged_live_rows(pos, page_size))
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _():
-        for g in range(groups):
-            rows, stat = rows_of(g)
-            l_safe = jnp.maximum(l_scr[stat], 1e-30)    # (1, H_kv)
-            out = (acc[rows] / l_safe.T).astype(o_ref.dtype)
-            if groups == 1:
-                o_ref[0] = out
-            else:
-                o_ref[0, rows] = out
+        _paged_finish(o_ref, acc, l_scr)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "page_size",
@@ -1269,44 +1425,63 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, pos,
     dense-gather path (softmax over ``index <= pos`` of the virtual
     lane), computed without ever materializing the lane.
 
+    **The work follows the live entries**: slot ``n`` reads entries
+    ``[0, pos[n] // page_size]`` of its row (:func:`paged_walk`),
+    :func:`paged_fetch_pages` of them a fetch, and no entry past them
+    is looked at; a free slot (position 0, an all-scratch table) reads
+    one page and returns a finite row that nobody reads. (With a
+    head_dim that is not a multiple of 128 the pages come through the
+    ``BlockSpec`` pipeline, one a grid step, dead entries skipped:
+    :func:`_paged_grid_kernel`.)
+
     **Grouped queries**: ``H_kv`` may divide ``H`` (query head ``j``
     reads K/V head ``j // (H / H_kv)``). The pool keeps ``H_kv`` heads,
-    a page is fetched once a grid step and serves its ``H / H_kv`` query
-    heads there; nothing is repeated in HBM. With ``H_kv == H`` the
-    program is the one this function always built."""
+    a page is fetched once and serves its ``H / H_kv`` query heads
+    there; nothing is repeated in HBM."""
     n, h, d = q.shape
     h_kv = k_pages.shape[2]
     if h % h_kv:
         raise ValueError(f"{h} query heads over {h_kv} K/V heads")
     groups = h // h_kv
+    page_size = int(page_size)
     pps = page_tables.shape[1]
-    kernel = functools.partial(_paged_attn_kernel, scale=float(scale),
-                               page_size=int(page_size), groups=groups)
     if groups > 1:
         # group-major rows: run g holds query heads g, g + G, ... so
         # that row i of a run reads K/V head i
         q = q.reshape(n, h_kv, groups, d).swapaxes(1, 2).reshape(n, h, d)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n, pps),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda n_, p_, tbl, ps_: (n_, 0, 0)),
-            # the paged gather itself: the page DMA is AIMED by the
-            # scalar-prefetched table — block (table[n, p], ...) of the
-            # shared pool streams in, no host- or HBM-side gather
-            pl.BlockSpec((1, page_size, h_kv, d),
-                         lambda n_, p_, tbl, ps_: (tbl[n_, p_], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, h_kv, d),
-                         lambda n_, p_, tbl, ps_: (tbl[n_, p_], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d),
-                               lambda n_, p_, tbl, ps_: (n_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),            # acc
-            pltpu.VMEM((groups, h_kv), jnp.float32),    # running max
-            pltpu.VMEM((groups, h_kv), jnp.float32),    # normalizer
-        ],
-    )
+    kw = dict(scale=float(scale), page_size=page_size, groups=groups)
+    stats = [pltpu.VMEM((h, d), jnp.float32),            # acc
+             pltpu.VMEM((groups, h_kv, 1), jnp.float32),  # running max
+             pltpu.VMEM((groups, h_kv, 1), jnp.float32)]  # normalizer
+    if d % LANE == 0:
+        fetch = paged_fetch_pages(pps, page_size, h_kv, d, k_pages.dtype)
+        buf = (2, fetch, page_size, h_kv, d)
+        kernel = functools.partial(_paged_walk_kernel, fetch=fetch, **kw)
+        q_spec = pl.BlockSpec((1, h, d), lambda n_, tbl, ps_: (n_, 0, 0))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n,),
+            # the pools stay where they are: the kernel's own copies,
+            # aimed by the scalar-prefetched table, are the paged gather
+            in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM(buf, k_pages.dtype),
+                            pltpu.VMEM(buf, v_pages.dtype),
+                            pltpu.SemaphoreType.DMA((2,)), *stats,
+                            pltpu.SMEM((1,), jnp.int32)])
+    else:
+        def live_page(n_, p_, tbl, ps_):
+            last = paged_walk(ps_[n_], page_size) - 1
+            return tbl[n_, jnp.minimum(p_, last)], 0, 0, 0
+        kernel = functools.partial(_paged_grid_kernel, **kw)
+        q_spec = pl.BlockSpec((1, h, d),
+                              lambda n_, p_, tbl, ps_: (n_, 0, 0))
+        page_spec = pl.BlockSpec((1, page_size, h_kv, d), live_page)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n, pps),
+            in_specs=[q_spec, page_spec, page_spec],
+            out_specs=q_spec, scratch_shapes=stats)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -2306,14 +2306,19 @@ class DecodeScheduler:
                 spec[slot] = req
         return spec
 
-    def _live_rows(self) -> "tuple[int, int]":
-        """``(summary_rows, window_rows)`` over the live slots, as the
-        decoder counts them at each slot's position."""
+    def _live_rows(self) -> "tuple[int, int, int]":
+        """``(summary_rows, window_rows, table_entries)`` over the live
+        slots, as the decoder counts them at each slot's position: the
+        rows a step reads by kind, and the entries of the slots' page
+        tables that name them (a slot's rows lie in the first
+        ``cdiv(rows, page_size)`` entries of its row, and the decode
+        attention kernel walks those and no others)."""
         live = list(self._active)
         if not live:
-            return 0, 0
+            return 0, 0, 0
         n_sum, n_win = self.decoder.rows_at(self._pos[live])
-        return int(np.sum(n_sum)), int(np.sum(n_win))
+        entries = -(-(n_sum + n_win) // self.decoder.page_size)
+        return int(np.sum(n_sum)), int(np.sum(n_win)), int(np.sum(entries))
 
     def _device_interval(self, i0: int) -> "tuple[float, float]":
         """Seconds (the tracer's clock) from the start of the first to
@@ -2329,7 +2334,7 @@ class DecodeScheduler:
     def _run_step(self) -> None:
         with span("decode.prepare") as sp:
             spec = self._prepare_round()
-            sum_rows, win_rows = self._live_rows()
+            sum_rows, win_rows, live_entries = self._live_rows()
             sp.attrs = {
                 "active": len(self._active),
                 "pages_in_use": self._pages_in_use(),
@@ -2340,6 +2345,10 @@ class DecodeScheduler:
                                 if self.decoder.has_slot_state else 0),
                 # the rows this step reads, by kind
                 "window_rows": win_rows, "summary_rows": sum_rows,
+                # the page tables' entries, and those of them that name
+                # a live row: what the attention kernel fetches
+                "table_entries": int(self._tables.size),
+                "table_entries_live": live_entries,
                 "traces": [getattr(r.pending, "trace", None)
                            for r in self._active.values()]}
         if not self._active:

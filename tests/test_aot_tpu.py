@@ -126,17 +126,38 @@ class TestKernels:
             ((FULL.d_model, FULL.vocab), jnp.float32), ((t,), jnp.int32))
         assert _n_mosaic(c) == 3            # fwd, dh, dW
 
-    def test_paged_decode_attention(self, topo):
-        from mmlspark_tpu.parallel.pallas_attention import (
-            paged_decode_attention)
-        pool = ((N_PAGES, PAGE, self.H, self.DH), jnp.float32)
+    # the three serving cells' tables (slots, table entries a slot,
+    # query heads, K/V heads, head_dim, page dtype; pages of 16 rows),
+    # and the smoke test's: at head_dim 64 the pages come through the
+    # ``BlockSpec`` pipeline (Mosaic slices no memref of half a lane
+    # register, so the kernel cannot aim its own copies there)
+    @pytest.mark.parametrize("n,pps,h,h_kv,d,dtype", [
+        (8, 64, 16, 16, 128, jnp.float32),    # pythia-1.4b.chat-closed
+        (8, 192, 32, 32, 128, jnp.bfloat16),  # evabyte-6.5b.doc-closed
+        (16, 640, 32, 8, 128, jnp.bfloat16),  # granite-4.0-h-small.rag-closed
+        (FULL.n_slots, PAGES_PER_SLOT, FULL.n_heads, FULL.n_heads,
+         FULL.d_head, jnp.float32),
+    ])
+    def test_paged_decode_attention(self, topo, n, pps, h, h_kv, d, dtype):
+        """ONE Mosaic call that walks the table, named after the jitted
+        function (the benchmark finds the kernel in a trace by that
+        name), with the K and V pages of two fetches inside the budget
+        the rule states and the whole kernel inside the v5e's default
+        scoped VMEM (it asks for no limit of its own; or the compile
+        fails)."""
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        pool = ((1 + n * pps, PAGE, h_kv, d), dtype)
         c = _compile_on_one(
-            topo, functools.partial(paged_decode_attention,
-                                    scale=self.DH ** -0.5, page_size=PAGE),
-            ((FULL.n_slots, self.H, self.DH), jnp.float32), pool, pool,
-            ((FULL.n_slots, PAGES_PER_SLOT), jnp.int32),
-            ((FULL.n_slots,), jnp.int32))
+            topo, functools.partial(PA.paged_decode_attention,
+                                    scale=d ** -0.5, page_size=PAGE),
+            ((n, h, d), dtype), pool, pool,
+            ((n, pps), jnp.int32), ((n,), jnp.int32))
         assert _n_mosaic(c) == 1
+        assert len(re.findall(r"%(\S*paged_decode_attention\S*) = .* "
+                              r"custom-call\(", c.as_text())) == 1
+        fetch = PA.paged_fetch_pages(pps, PAGE, h_kv, d, dtype)
+        assert 4 * fetch * PA._paged_page_vmem_bytes(
+            PAGE, h_kv, d, dtype) <= PA._PAGED_VMEM_BUDGET
 
     @pytest.mark.parametrize("s", [8, 128, FULL.max_len])
     def test_flash_prefill_attention(self, topo, s):

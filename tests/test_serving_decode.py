@@ -1976,3 +1976,43 @@ class TestStateASlot:
         assert json.loads(again.reply)["tokens"][0] == first
         assert stats["n_state_resets"] >= 2
         assert _pages_idle(sched) and sched.pool.n_free == 4
+
+
+@pytest.mark.parametrize("make,prompt_len,n_new,want", [
+    # one kind of row, pages of 16: positions 14..17 hold 15..18 rows
+    (lambda: _decoder(), 14, 5, [1, 1, 2, 2]),
+    # two kinds (window 16, 4 summaries a window, pages of 4): positions
+    # 37..40 hold 8 summary rows and 6..9 window rows in ONE lane
+    (lambda: TestTwoRowKinds._decoder(), 37, 5, [4, 4, 4, 5]),
+    # a state a slot beside the rows (pages of 4): positions 19..21
+    (lambda: TestStateASlot._decoder(), 19, 4, [5, 6, 6]),
+], ids=["one_kind", "two_kinds", "state_a_slot"])
+def test_prepare_counts_the_table_entries(make, prompt_len, n_new, want):
+    """``decode.prepare`` stamps the page tables' entries and those of
+    them that name a live row, from the positions the scheduler holds:
+    ``cdiv(rows, page_size)`` a live slot, which is what
+    ``paged_decode_attention`` walks (``parallel/pallas_attention.
+    paged_walk``); the rest of a table is never looked at."""
+    from mmlspark_tpu.core.tracing import Tracer
+    from mmlspark_tpu.parallel.pallas_attention import paged_walk
+    from mmlspark_tpu.serving.decode import pass_view
+    tracer = Tracer()
+    sched = DecodeScheduler(make(), tracer=tracer).start()
+    dec = sched.decoder
+    rng = np.random.default_rng(prompt_len)
+    try:
+        p = _Pending({"prompt": _prompt(rng, prompt_len),
+                      "max_new_tokens": n_new}, "entries")
+        sched.submit(p)
+        assert p.event.wait(30)
+    finally:
+        sched.stop()
+    steps = [v for v in (pass_view(sp.attrs["phases"])
+                         for sp in tracer.recorder.scan("decode.pass"))
+             if "dispatch" in v["phases_ms"]]
+    assert [v["table_entries_live"] for v in steps] == want
+    assert {v["table_entries"] for v in steps} \
+        == {dec.n_slots * dec.pages_per_slot}
+    # the kernel's own count at the same rows: the last row's index
+    rows = [v["summary_rows"] + v["window_rows"] for v in steps]
+    assert [int(paged_walk(r - 1, dec.page_size)) for r in rows] == want

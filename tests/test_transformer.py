@@ -315,6 +315,47 @@ class TestFlashAttentionVJP:
                 dead = (i + 1) * tq - 1 < j * tk
                 assert max(i, first) == (first if dead else i)
 
+    # the benchmark's three serving cells: (table entries a slot,
+    # K/V heads, dtype) at pages of 16 rows of head_dim 128
+    PAGED_CELLS = [(64, 16, "float32"), (192, 32, "bfloat16"),
+                   (640, 8, "bfloat16")]
+
+    @pytest.mark.parametrize("pps,h_kv,dtype", PAGED_CELLS)
+    def test_paged_fetch_pages(self, pps, h_kv, dtype):
+        """The pure choice of how many table entries the paged decode
+        kernel copies at a time: K and V, double-buffered, fit the
+        budget the module states; at least 2; no more than the cap;
+        and the whole table when the table is shorter."""
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        p = PA.paged_fetch_pages(pps, 16, h_kv, 128, dtype)
+        page = 16 * h_kv * 128 * jnp.dtype(dtype).itemsize
+        assert PA._paged_page_vmem_bytes(16, h_kv, 128, dtype) >= page
+        assert 2 <= p <= PA._PAGED_MAX_FETCH
+        assert 4 * p * PA._paged_page_vmem_bytes(16, h_kv, 128, dtype) \
+            <= PA._PAGED_VMEM_BUDGET
+        # as many as fit, and the smaller the page the more of them
+        assert p == PA._PAGED_MAX_FETCH or 4 * (p + 1) * page \
+            > PA._PAGED_VMEM_BUDGET
+        for short in (1, 2, p - 1):
+            assert PA.paged_fetch_pages(short, 16, h_kv, 128, dtype) \
+                == short
+
+    @pytest.mark.parametrize("fetch", [2, 8, 32])
+    def test_paged_walk_names_the_live_entries(self, fetch):
+        """The walk of a slot at ``pos``, on the pure functions the
+        kernel runs: ``cdiv(pos + 1, 16)`` entries in ``cdiv(walk,
+        fetch)`` fetches, each entry of ``[0, walk)`` once and none
+        past it, whatever the fetch; a dead entry is never named."""
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        for pos in list(range(0, 70)) + [16 * fetch - 1, 16 * fetch,
+                                         16 * 640 - 1]:
+            walk = int(PA.paged_walk(pos, 16))
+            assert walk == -(-(pos + 1) // 16)
+            named = [i * fetch + j for i in range(-(-walk // fetch))
+                     for j in range(int(PA._paged_fetch_live(
+                         walk, i, fetch)))]
+            assert named == list(range(walk))
+
     @pytest.mark.parametrize("causal", [True, False])
     @pytest.mark.slow
     def test_folded_value_and_grads_match_dense(self, rng, causal):
@@ -1400,6 +1441,87 @@ class TestPagedAttnKernel:
         p /= p.sum(-1, keepdims=True)
         ref = np.einsum("nhs,nshk->nhk", p, lane_v)
         np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5)
+
+    ROWS = 16                           # a page of the cells
+
+    @classmethod
+    def _pool(cls, pps, groups, dtype, seed, free_slot=True):
+        """Six slots over tables of ``pps`` entries at the positions
+        where the walk changes: a free slot (position 0, an all-scratch
+        table; ``free_slot=False`` claims its page), the last row of a
+        page and the first of the next, the last row of a fetch and the
+        first of the next, the table's last row. Entries past a slot's
+        walk name the scratch page. head_dim 128, so the kernel walks
+        the table by its own copies (the tests above, at head_dim 8,
+        are fed by the ``BlockSpec`` pipeline)."""
+        from mmlspark_tpu.parallel import pallas_attention as PA
+        ps, h_kv, d = cls.ROWS, 2, 128
+        fetch = PA.paged_fetch_pages(pps, ps, h_kv, d, dtype)
+        assert fetch < pps                   # several fetches a slot
+        pos = np.array([0, 3 * ps - 1, 3 * ps, fetch * ps - 1,
+                        fetch * ps, pps * ps - 1], np.int32)
+        walk = pos // ps + 1
+        rng = np.random.default_rng(seed)
+        n_pages = 1 + int(walk.sum())
+        k, v = (jnp.asarray(rng.normal(size=(n_pages, ps, h_kv, d)),
+                            jnp.float32).astype(dtype) for _ in range(2))
+        q = jnp.asarray(rng.normal(size=(len(pos), groups * h_kv, d)),
+                        jnp.float32).astype(dtype)
+        claimed = iter(rng.permutation(np.arange(1, n_pages)))
+        tables = np.zeros((len(pos), pps), np.int32)
+        for t, w in zip(tables, walk):
+            t[:w] = [next(claimed) for _ in range(w)]
+        if free_slot:
+            tables[0] = 0
+        return q, k, v, tables, pos
+
+    @pytest.mark.parametrize("pps", [64, 192, 640])
+    @pytest.mark.parametrize("groups", [1, 4])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_kernel_matches_dense_twin(self, dtype, groups, pps):
+        """The kernel (interpreted) against the dense twin (every
+        slot's lane gathered, one masked softmax) at the three cells'
+        table lengths, with and without grouped queries, in both page
+        dtypes: every row ``index <= pos`` is read and no other."""
+        from mmlspark_tpu.models.granite_hybrid import _lane_attention
+        from mmlspark_tpu.parallel.pallas_attention import (
+            paged_decode_attention)
+        q, k, v, tables, pos = self._pool(pps, groups, dtype, seed=pps)
+        got = paged_decode_attention(q, k, v, jnp.asarray(tables),
+                                     jnp.asarray(pos), scale=0.3,
+                                     page_size=self.ROWS, interpret=True)
+        assert got.dtype == q.dtype and got.shape == q.shape
+        f32 = jnp.float32
+        want = _lane_attention(q.astype(f32), k.astype(f32), v.astype(f32),
+                               jnp.asarray(tables), jnp.asarray(pos), 0.3)
+        np.testing.assert_allclose(
+            np.asarray(got.astype(f32)), np.asarray(want),
+            atol=1e-5 if dtype == "float32" else 2e-2)
+
+    def test_nothing_dead_reaches_the_result(self):
+        """The scratch page, every page no live entry names and every
+        row past ``pos`` in a slot's last page hold NaN, and the output
+        is the clean pool's bit for bit: a dead entry is neither
+        copied nor read, a dead row is removed, not multiplied by 0."""
+        from mmlspark_tpu.parallel.pallas_attention import (
+            paged_decode_attention)
+        q, k, v, tables, pos = self._pool(64, 1, "float32", seed=7,
+                                          free_slot=False)
+        dead = np.ones(k.shape[:2], bool)             # (page, row)
+        for t, p in zip(tables, pos):
+            dead[t[:p // self.ROWS + 1]] = False
+            dead[t[p // self.ROWS], p % self.ROWS + 1:] = True
+        assert not dead[tables[np.arange(len(pos)), pos // self.ROWS],
+                        pos % self.ROWS].any() and dead[0].all()
+        outs = []
+        for poison in (False, True):
+            kk, vv = (jnp.where(dead[:, :, None, None] & poison, jnp.nan, x)
+                      for x in (k, v))
+            outs.append(np.asarray(paged_decode_attention(
+                q, kk, vv, jnp.asarray(tables), jnp.asarray(pos),
+                scale=0.3, page_size=self.ROWS, interpret=True)))
+        assert np.isfinite(outs[0]).all()
+        np.testing.assert_array_equal(outs[0], outs[1])
 
 
 class TestVerifyScores:
