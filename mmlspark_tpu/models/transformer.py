@@ -48,6 +48,63 @@ from mmlspark_tpu.parallel.topology import (
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockRecipe:
+    """What one layer of a softmax configuration is, and how often the
+    stack runs: data the decode programs read (``_decode_layers``), not
+    flags at their call sites. The defaults are the block this module
+    trains (``_attention`` / ``_mlp``): the train step builders, the
+    int8 FFN and tensor-parallel decode implement them and nothing
+    else, and refuse another recipe by name; the verify step and the
+    unpaged draft programs read the recipe too, but run one pass.
+
+    ``ffn``        ``"relu_bias"`` (``w1``/``b1``/``w2``/``b2``) or
+                   ``"gated_silu"`` (``w_gate``/``w_up``/``w_down``, no
+                   bias).
+    ``norms``      ``"pre"`` (``ln1``/``ln2`` before attention and FFN)
+                   or ``"sandwich"`` (also ``ln1_post``/``ln2_post``
+                   over each sublayer's output, before the residual
+                   add).
+    ``rope_layout``
+                   ``"interleaved"`` (pairs ``(2i, 2i + 1)``) or
+                   ``"half"`` (pairs ``(i, i + Dh / 2)``: rotate_half)
+                   over the whole head, at base ``rope_base``.
+    ``n_loops``    passes over the SAME layers a token makes: the final
+                   norm between passes, an exit gate (``exit_w``,
+                   ``exit_b``) read after each, a K/V row of its own a
+                   (pass, layer, position). ``exit_threshold`` is the
+                   mass of the exit distribution at which a token would
+                   leave the loop; only 1.0 (every pass, always) is
+                   built (ROADMAP B12)."""
+
+    ffn: str = "relu_bias"
+    norms: str = "pre"
+    rope_layout: str = "interleaved"
+    rope_base: float = 10000.0
+    norm_eps: float = 1e-6
+    n_loops: int = 1
+    exit_threshold: float = 1.0
+
+    def __post_init__(self):
+        for field, allowed in (("ffn", ("relu_bias", "gated_silu")),
+                               ("norms", ("pre", "sandwich")),
+                               ("rope_layout", ("interleaved", "half"))):
+            if getattr(self, field) not in allowed:
+                raise ValueError(f"recipe {field}={getattr(self, field)!r}:"
+                                 f" one of {allowed}")
+        if self.n_loops < 1:
+            raise ValueError(f"n_loops={self.n_loops} must be >= 1")
+
+    @property
+    def looped(self) -> bool:
+        """The passes are a ``fori_loop`` and the layers' weights its
+        carry (``_decode_layers``): the ONE condition behind everything
+        a looped stack does differently, in the programs (the pool's
+        shift, the gate, ``_heads``' product, the programs' names) and
+        in what is refused."""
+        return self.n_loops > 1
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     """Architecture + schedule. ``n_stages`` must equal the pipe-axis size."""
 
@@ -117,10 +174,45 @@ class TransformerConfig:
     # einsum + logsumexp path; "auto" = fused on TPU when eligible
     # (d_model lane-aligned), xla otherwise
     ce_impl: str = "auto"
+    # what a layer is and how often the stack runs (decode programs)
+    recipe: BlockRecipe = BlockRecipe()
 
     @property
     def n_layers(self) -> int:
         return self.n_stages * self.layers_per_stage
+
+    @classmethod
+    def from_hf(cls, hf: Dict[str, Any], dtype: str = "bfloat16"
+                ) -> "TransformerConfig":
+        """From a ``config.json`` of ``model_type`` ``ouro`` (its HF
+        keys): the looped stack's sizes and recipe. What the keys do
+        not fix (sandwich norms, the gate's form, no biases, half-split
+        rotary over the whole head) is the family's published layer,
+        stated in ``benchmark/reference_ouro.py``."""
+        if hf.get("model_type") != "ouro":
+            raise ValueError(f"no recipe for model_type "
+                             f"{hf.get('model_type')!r}")
+        if hf.get("hidden_act", "silu") != "silu" or hf.get("rope_scaling") \
+                or hf.get("sliding_window") or hf.get("tie_word_embeddings"):
+            raise ValueError("an ouro config with another activation, "
+                             "scaled rotary, a sliding window or a tied "
+                             "head is not this recipe")
+        heads = int(hf["num_attention_heads"])
+        if int(hf.get("num_key_value_heads", heads)) != heads:
+            raise ValueError("the softmax block keeps every head's K/V")
+        return cls(
+            vocab=int(hf["vocab_size"]), d_model=int(hf["hidden_size"]),
+            n_heads=heads,
+            d_head=int(hf.get("head_dim")
+                       or int(hf["hidden_size"]) // heads),
+            d_ff=int(hf["intermediate_size"]), n_stages=1,
+            layers_per_stage=int(hf["num_hidden_layers"]), dtype=dtype,
+            recipe=BlockRecipe(
+                ffn="gated_silu", norms="sandwich", rope_layout="half",
+                rope_base=float(hf["rope_theta"]),
+                norm_eps=float(hf["rms_norm_eps"]),
+                n_loops=int(hf["total_ut_steps"]),
+                exit_threshold=float(hf["early_exit_threshold"])))
 
 
 # ---------------------------------------------------------------------------
@@ -152,10 +244,17 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
             "wo": _dense(next(ks), (s, h, dh, d)),
             "ln2": jnp.ones((s, d)),
         }
+        if cfg.recipe.norms == "sandwich":
+            b["ln1_post"] = jnp.ones((s, d))
+            b["ln2_post"] = jnp.ones((s, d))
         if cfg.n_experts:
             b["router"] = _dense(next(ks), (s, d, cfg.n_experts))
             b["ew1"] = _dense(next(ks), (s, cfg.n_experts, d, f))
             b["ew2"] = _dense(next(ks), (s, cfg.n_experts, f, d))
+        elif cfg.recipe.ffn == "gated_silu":
+            b["w_gate"] = _dense(next(ks), (s, d, f))
+            b["w_up"] = _dense(next(ks), (s, d, f))
+            b["w_down"] = _dense(next(ks), (s, f, d))
         else:
             b["w1"] = _dense(next(ks), (s, d, f))
             b["b1"] = jnp.zeros((s, f))
@@ -163,6 +262,9 @@ def init_params(cfg: TransformerConfig, seed: int = 0) -> Dict[str, Any]:
             b["b2"] = jnp.zeros((s, d))
         blocks.append(b)
     p["blocks"] = blocks
+    if cfg.recipe.looped:
+        p["exit_w"] = _dense(next(ks), (cfg.d_model,))
+        p["exit_b"] = jnp.zeros(())
     return p
 
 
@@ -979,6 +1081,7 @@ def _validate_mesh_config(cfg: TransformerConfig, mesh) -> "_Axes":
     (manual shard_map and pjit): every mesh/config mismatch fails
     loudly at build, never as a cryptic XLA partitioning error."""
     ax = _Axes.of(mesh)
+    _check_default_recipe(cfg, "the train step")
     if ax.pipe and mesh.shape[ax.pipe] != cfg.n_stages:
         raise ValueError(
             f"n_stages={cfg.n_stages} != pipe axis size {mesh.shape[ax.pipe]}")
@@ -1522,30 +1625,75 @@ def _decode_block_params(params, cfg: TransformerConfig
     return out
 
 
-def _rope_at(x, pos):
+def _rope_at(x, pos, recipe: BlockRecipe = BlockRecipe()):
     """Rotary embedding for mid-sequence tokens: ``x`` [..., H, Dh] at
     positions ``pos`` matching the leading dims (``[N]`` for the
     single-token step, ``[N, W]`` for the speculative verify step —
     each slot is mid-sequence at its own depth, the batched analogue
-    of :func:`_rope` at short S)."""
+    of :func:`_rope` at short S). ``recipe`` gives the base and which
+    columns pair up."""
     dh = x.shape[-1]
-    freqs = 1.0 / (10000.0 ** (jnp.arange(0, dh, 2) / dh))
+    freqs = 1.0 / (recipe.rope_base ** (jnp.arange(0, dh, 2) / dh))
     ang = pos[..., None].astype(jnp.float32) * freqs      # [..., Dh/2]
     cos = jnp.cos(ang)[..., None, :]                      # [..., 1, Dh/2]
     sin = jnp.sin(ang)[..., None, :]
+    if recipe.rope_layout == "half":
+        x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1)
     x1, x2 = x[..., 0::2], x[..., 1::2]
     r1 = x1 * cos - x2 * sin
     r2 = x1 * sin + x2 * cos
     return jnp.stack([r1, r2], axis=-1).reshape(x.shape)
 
 
-def _check_decode_config(cfg: TransformerConfig) -> None:
+def _check_default_recipe(cfg: TransformerConfig, what: str) -> None:
+    """``what`` implements the default recipe and no other: refuse by
+    name instead of running another block under this one's sizes."""
+    ours = BlockRecipe()
+    odd = [f"{f.name}={getattr(cfg.recipe, f.name)!r}"
+           for f in dataclasses.fields(BlockRecipe)
+           if getattr(cfg.recipe, f.name) != getattr(ours, f.name)]
+    if odd:
+        raise NotImplementedError(
+            f"{what} implements the default block recipe only, not "
+            f"{', '.join(odd)}: the paged decode programs "
+            f"(build_paged_prefill / _prefix_prefill / _decode_step) "
+            f"read the recipe")
+
+
+def _check_decode_config(cfg: TransformerConfig, looped: bool = True
+                         ) -> None:
+    """Refuse what the decode programs do not implement, by name.
+    ``looped=False``: the unpaged draft programs and the verify step,
+    which run one pass and hold no pass-keyed rows."""
+    r = cfg.recipe
     if cfg.n_experts and cfg.moe_router == "expert_choice":
         raise NotImplementedError(
             "expert-choice MoE has no decode form: each expert picks "
             "its top tokens ACROSS the batch, so slots would couple — "
             "the property continuous batching forbids. Token-choice "
             "MoE decodes via dense dispatch (_decode_ffn).")
+    if cfg.n_experts and r.ffn != "relu_bias":
+        raise NotImplementedError(
+            f"recipe ffn={r.ffn!r} with n_experts={cfg.n_experts}: the "
+            f"decode MoE is the ReLU expert pair (_decode_ffn)")
+    if cfg.dtype not in ("float32", "bfloat16"):
+        raise NotImplementedError(
+            f"decode compute dtype {cfg.dtype!r}: float32 or bfloat16")
+    if r.looped and r.exit_threshold < 1.0:
+        raise NotImplementedError(
+            f"exit_threshold={r.exit_threshold} < 1: a token that "
+            f"leaves the loop before its last pass is not built (a "
+            f"step whose cost differs by slot, later tokens wanting "
+            f"rows of passes an earlier token never ran: ROADMAP B12); "
+            f"the published threshold 1.0 runs every pass")
+    if r.looped and not looped:
+        raise NotImplementedError(
+            f"n_loops={r.n_loops}: the unpaged lanes (the speculation "
+            f"draft's) and the verify step hold one row a (layer, "
+            f"position); a looped stack has no draft yet (a model of "
+            f"fewer passes: ROADMAP B12)")
 
 
 def _q_matmul(x, w_q, w_s, act_dtype=jnp.bfloat16):
@@ -1583,9 +1731,9 @@ def quantize_decode_ffn(params, cfg: TransformerConfig,
     != 1.0 — the chaos knob the rollout-verify tests use to prove a
     broken quantized config fails parity and never flips."""
     _check_decode_config(cfg)
-    if cfg.n_experts:
+    if cfg.n_experts or cfg.recipe.ffn != "relu_bias":
         raise NotImplementedError(
-            "quantized decode FFN supports dense-MLP configs only")
+            "quantized decode FFN supports dense ReLU-MLP configs only")
     out = dict(params)
     blocks = []
     for bp_all in params["blocks"]:
@@ -1622,6 +1770,40 @@ def _decode_ffn(bp, h, cfg: TransformerConfig):
         return _decode_ffn_body(bp, h, cfg)
 
 
+def _dmm(a, w, dt):
+    """A decode program's matrix product ``a @ w``: operands in the
+    compute dtype ``dt``, float32 out."""
+    return jnp.matmul(a.astype(dt), w.astype(dt),
+                      preferred_element_type=jnp.float32)
+
+
+def _dproj(spec: str, a, w, dt):
+    """:func:`_dmm` for the head-shaped projections (an einsum)."""
+    return jnp.einsum(spec, a.astype(dt), w.astype(dt),
+                      preferred_element_type=jnp.float32)
+
+
+def _heads(a, w, cfg: TransformerConfig):
+    """``a [..., D]`` through ``w [D, H, Dh]`` -> float32 ``[..., H,
+    Dh]``. In a looped stack (``recipe.looped``) the weights are a
+    loop's carry, whose layout XLA is free to pick: for this product it
+    wants the heads outermost and copies every q, k and v matrix to get
+    them there, once a step (1.2 GB of temporaries and 2.4 GB of
+    traffic at 48 layers of 2048 x 16 x 128, by the AOT compiler's
+    memory analysis for the v5e; the two forms' step times were not
+    measured against each other on the chip: PERF.md section 7). So
+    there it is ONE product over ``w`` seen as ``[D, H * Dh]``, the
+    layout the weights arrive in, and the barrier keeps the reshape
+    behind it from being folded back in. The condition is the carry,
+    not the dtype: an unlooped stack keeps the einsum in either."""
+    dt = _compute_dtype(cfg)
+    if not cfg.recipe.looped:
+        return _dproj("...d,dhk->...hk", a, w, dt)
+    d, h, dh = w.shape
+    y = jax.lax.optimization_barrier(_dmm(a, w.reshape(d, h * dh), dt))
+    return y.reshape(a.shape[:-1] + (h, dh))
+
+
 def _decode_ffn_body(bp, h, cfg: TransformerConfig):
     shape = h.shape
     hf = h.reshape(-1, shape[-1])
@@ -1645,8 +1827,13 @@ def _decode_ffn_body(bp, h, cfg: TransformerConfig):
                         + bp["b1"])
         return (_q_matmul(z, bp["w2_q"], bp["w2_s"])
                 + bp["b2"]).reshape(shape)
-    z = jax.nn.relu(hf @ bp["w1"] + bp["b1"])
-    return (z @ bp["w2"] + bp["b2"]).reshape(shape)
+    dt = _compute_dtype(cfg)
+    if cfg.recipe.ffn == "gated_silu":
+        z = jax.nn.silu(_dmm(hf, bp["w_gate"], dt)) * _dmm(hf, bp["w_up"],
+                                                           dt)
+        return _dmm(z, bp["w_down"], dt).reshape(shape)
+    z = jax.nn.relu(_dmm(hf, bp["w1"], dt) + bp["b1"])
+    return (_dmm(z, bp["w2"], dt) + bp["b2"]).reshape(shape)
 
 
 def decode_param_specs(cfg: TransformerConfig, mesh,
@@ -1664,6 +1851,8 @@ def decode_param_specs(cfg: TransformerConfig, mesh,
     from jax.sharding import PartitionSpec as P
 
     _check_decode_config(cfg)
+    _check_default_recipe(cfg, "tensor-parallel decode "
+                               "(decode_param_specs)")
     model = AXIS_MODEL if AXIS_MODEL in mesh.axis_names else None
     tp = mesh.shape.get(AXIS_MODEL, 1)
     if model and cfg.n_heads % tp:
@@ -1724,7 +1913,7 @@ def init_kv_cache(cfg: TransformerConfig, n_slots: int, max_len: int
     decode path mirrors the reference forward's numerics so greedy
     decode matches the full-context argmax token-for-token). Allocated
     ONCE; every prefill/decode call donates it back in."""
-    _check_decode_config(cfg)
+    _check_decode_config(cfg, looped=False)
     shape = (cfg.n_layers, int(n_slots), int(max_len),
              cfg.n_heads, cfg.d_head)
     return {"k": jnp.zeros(shape, jnp.float32),
@@ -1732,11 +1921,12 @@ def init_kv_cache(cfg: TransformerConfig, n_slots: int, max_len: int
 
 
 def _jit_decode(fn, donate: bool, cache_sharding=None,
-                n_replicated: int = 2):
+                n_replicated: int = 2, looped: bool = False):
     """The decode builders' jit epilogue: ``fn(params, cache, ...) ->
     (cache, *outs)`` under its own ``__name__`` (``jit_step``,
-    ``jit_prefill``: what traces and the benchmark find it by), the
-    cache donated. Under tensor parallelism the output layout is
+    ``jit_prefill``; a looped stack's are ``jit_looped_step``,
+    ``jit_looped_prefill``: what traces and the benchmark find it by),
+    the cache donated. Under tensor parallelism the output layout is
     pinned: the cache keeps its canonical head sharding through every
     donated call (otherwise XLA may pick a different layout for the
     prefill's output than the step expects — one silent retrace per
@@ -1750,6 +1940,8 @@ def _jit_decode(fn, donate: bool, cache_sharding=None,
         kw["out_shardings"] = (
             {"k": cache_sharding, "v": cache_sharding},
         ) + (repl,) * n_replicated
+    if looped:
+        fn.__name__ = "looped_" + fn.__name__
     return jax.jit(fn, donate_argnums=(1,) if donate else (), **kw)
 
 
@@ -1794,9 +1986,11 @@ def _inflight_attention(cfg: TransformerConfig, attn_impl: str,
     ``"pallas_interpret"`` the kernel interpreted for CPU parity."""
     from mmlspark_tpu.parallel.pallas_attention import (
         flash_prefill_attention)
+    dt = _compute_dtype(cfg)
     core = _attn_kernel(flash_prefill_attention, attn_impl, cache_sharding,
                         (4, 4, 4), scale=cfg.d_head ** -0.5) \
-        or functools.partial(dense_attention, causal=True)
+        or functools.partial(dense_attention, causal=True,
+                             compute_dtype=None if dt == jnp.float32 else dt)
 
     def attend(l, q, k, v):
         # both engines take a batch: a prefill is a batch of one prompt
@@ -1816,10 +2010,12 @@ def _lane_attention(q, lk, lv, qpos):
     scratch page) are dead by construction."""
     w = "w" if q.ndim == 4 else ""
     mask = jnp.arange(lk.shape[1]) <= qpos[..., None, None]
-    s = jnp.einsum(f"n{w}hk,nshk->n{w}hs", q, lk) * q.shape[-1] ** -0.5
+    s = jnp.einsum(f"n{w}hk,nshk->n{w}hs", q, lk,
+                   preferred_element_type=jnp.float32) * q.shape[-1] ** -0.5
     s = jnp.where(mask, s, -1e30)                  # [N, (W,) 1, V] bcast
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum(f"n{w}hs,nshk->n{w}hk", p, lv)
+    return jnp.einsum(f"n{w}hs,nshk->n{w}hk", p.astype(lv.dtype), lv,
+                      preferred_element_type=jnp.float32)
 
 
 def _attend_pages(k_l, v_l, q, tables, qpos, kernel=None, kernel_pos=None):
@@ -1921,39 +2117,135 @@ def _slot_pages(cache, page_tables, qpos, pg, kernel=None) -> _CacheView:
     return _CacheView({"k": ck, "v": cv}, write, attend)
 
 
-def _decode_layers(params, cfg: TransformerConfig, tokens, pos,
-                   view: _CacheView):
-    """The softmax block as the decode programs run it, stated once:
-    ``tokens`` at positions ``pos`` (one shape: ``[S]`` in a prefill,
-    ``[N]`` in a step, ``[N, W]`` in a verify) through every layer and
-    the final norm -> ``[..., D]``. Programs differ in ``view``."""
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens]                    # [..., D]
-    for l, bp in enumerate(_decode_block_params(params, cfg)):
-        h = _rmsnorm(x, bp["ln1"])
+def _shifted(pages, shift):
+    """Page ids ``pages`` as pass ``shift // n_pages`` of a looped
+    stack addresses them (``_decode_layers``); the first and only pass
+    of an unlooped one names them as they are."""
+    return pages if isinstance(shift, int) and shift == 0 \
+        else pages + shift
+
+
+def _decode_pass(blocks, cfg: TransformerConfig, x, pos, view: _CacheView):
+    """One pass of the stack, the ONE statement of a softmax decode
+    layer: the residual stream ``x [..., D]`` (float32) at positions
+    ``pos`` through every layer of ``blocks``, as ``cfg.recipe`` says a
+    layer is and in ``cfg.dtype`` (weights, matmul operands and K/V rows
+    in it; accumulation, the stream, norms, rotary and softmax
+    float32)."""
+    r, dt = cfg.recipe, _compute_dtype(cfg)
+    sandwich = r.norms == "sandwich"
+    for l, bp in enumerate(blocks):
+        h = _rmsnorm(x, bp["ln1"], r.norm_eps)
         with jax.named_scope("attn.qkv"):
-            q = _rope_at(jnp.einsum("...d,dhk->...hk", h, bp["wq"]), pos)
-            k = _rope_at(jnp.einsum("...d,dhk->...hk", h, bp["wk"]), pos)
-            v = jnp.einsum("...d,dhk->...hk", h, bp["wv"])
+            q = _rope_at(_heads(h, bp["wq"], cfg), pos, r).astype(dt)
+            k = _rope_at(_heads(h, bp["wk"], cfg), pos, r).astype(dt)
+            v = _heads(h, bp["wv"], cfg).astype(dt)
         with jax.named_scope("kv.write"):
             view.write(l, k, v)
         a = view.attend(l, q, k, v)
         with jax.named_scope("attn.out"):
-            x = x + jnp.einsum("...hk,hkd->...d", a, bp["wo"])
-        x = x + _decode_ffn(bp, _rmsnorm(x, bp["ln2"]), cfg)
-    return _rmsnorm(x, params["final_norm"])
+            o = _dproj("...hk,hkd->...d", a, bp["wo"], dt)
+            x = x + (_rmsnorm(o, bp["ln1_post"], r.norm_eps)
+                     if sandwich else o)
+        m = _decode_ffn(bp, _rmsnorm(x, bp["ln2"], r.norm_eps), cfg)
+        x = x + (_rmsnorm(m, bp["ln2_post"], r.norm_eps)
+                 if sandwich else m)
+    return x
 
 
-def _greedy_head(params, h, last=None):
+def _decode_layers(params, cfg: TransformerConfig, tokens, pos, cache,
+                   view_of: Callable):
+    """The softmax stack as the decode programs run it: ``tokens`` at
+    positions ``pos`` (one shape: ``[S]`` in a prefill, ``[N]`` in a
+    step, ``[N, W]`` in a verify) through ``recipe.n_loops`` passes of
+    every layer -> ``(cache, h [..., D], lam)``, ``h`` final-normed.
+    Programs differ in ``view_of(cache, shift)``, their cache view.
+
+    A looped stack (``n_loops > 1``) runs the SAME layers every pass,
+    the final norm between passes (scope ``loop.norm``: the normalised
+    state feeds the next pass), and reads the exit gate after each
+    (``loop.gate``): ``lam [n_loops, ...]``, else None. Each layer's
+    pool holds ``n_loops`` x ``n_pages`` pages and pass ``t`` addresses
+    page ``id + t * n_pages`` (``shift``): its OWN K/V rows at every
+    position, the same page ids in every pass, page 0 of each pass its
+    scratch page. The passes are a ``fori_loop`` whose carry is the
+    pool: the program holds one body a layer, not ``n_loops``."""
+    r = cfg.recipe
+    blocks = _decode_block_params(params, cfg)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(jnp.float32)    # [..., D]
+    if not r.looped:
+        view = view_of(cache, 0)
+        x = _decode_pass(blocks, cfg, x, pos, view)
+        return view.cache, _rmsnorm(x, params["final_norm"],
+                                    r.norm_eps), None
+    n_pages = cache["k"][0].shape[0] // r.n_loops
+
+    def one_pass(t, carry):
+        pool, x, lam = carry
+        view = view_of(pool, t * n_pages)
+        x = _decode_pass(blocks, cfg, x, pos, view)
+        with jax.named_scope("loop.norm"):
+            h = _rmsnorm(x, params["final_norm"], r.norm_eps)
+        with jax.named_scope("loop.gate"):
+            # a D -> 1 linear, on the VPU: float32 to the last bit
+            gate = jax.nn.sigmoid(
+                jnp.sum(h * params["exit_w"].astype(jnp.float32), -1)
+                + params["exit_b"].astype(jnp.float32))
+        return view.cache, h, lam.at[t].set(gate)
+
+    lam = jnp.zeros((r.n_loops,) + x.shape[:-1], jnp.float32)
+    return jax.lax.fori_loop(0, r.n_loops, one_pass, (cache, x, lam))
+
+
+def exit_distribution(lam):
+    """The looped stack's exit distribution from its gates ``lam [T,
+    ...]``: ``p_t = lam_t prod_{j<t} (1 - lam_j)`` for ``t < T`` and
+    ``p_T = prod_{j<T} (1 - lam_j)``, so that it sums to one. A token
+    leaves at the first pass whose cumulated ``p`` reaches the
+    configuration's threshold; at the published 1.0 that is pass
+    ``T``."""
+    stay = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([(lam * before)[:-1], before[-1:]])
+
+
+def expected_exit_pass(lam):
+    """``sum_t t p_t`` over passes counted from 1: ``[...]`` float32."""
+    p = exit_distribution(lam)
+    t = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)
+    return jnp.tensordot(t, p, axes=1)
+
+
+def _greedy_head(params, cfg: TransformerConfig, h, lam=None, last=None):
     """The vocab head over final-normed ``h [..., D]`` -> ``(greedy
-    tokens int32, logits)``. A prefill names ``last``, the one row of
-    its ``[S, D]`` anybody reads (the prompt's last position)."""
+    tokens int32, float32 logits)``. A prefill names ``last``, the one
+    row of its ``[S, D]`` anybody reads (the prompt's last position).
+    A looped stack hands its gates ``lam``: everything the host reads
+    back is then ONE int32 vector, ``[tokens | expected exit pass]``
+    (the float32's bits: :func:`split_fetched`)."""
     with jax.named_scope("head"):
         if last is not None:
             h = jax.lax.dynamic_index_in_dim(h, last, axis=0,
                                              keepdims=False)
-        logits = h @ params["head"]
-        return jnp.argmax(logits, -1).astype(jnp.int32), logits
+        logits = _dmm(h, params["head"], _compute_dtype(cfg))
+        tokens = jnp.argmax(logits, -1).astype(jnp.int32)
+        if lam is None:
+            return tokens, logits
+        if last is not None:
+            lam = jax.lax.dynamic_index_in_dim(lam, last, axis=1,
+                                               keepdims=False)
+        exits = jax.lax.bitcast_convert_type(expected_exit_pass(lam),
+                                             jnp.int32)
+        return jnp.concatenate([tokens.reshape(-1),
+                                exits.reshape(-1)]), logits
+
+
+def split_fetched(fetched: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """A looped program's one fetch, on the host: ``(tokens int32 [n],
+    expected exit pass float32 [n])``."""
+    tokens, exits = np.split(np.asarray(fetched), 2)
+    return tokens, exits.view(np.float32)
 
 
 def build_prefill(cfg: TransformerConfig, donate: bool = True,
@@ -1973,14 +2265,14 @@ def build_prefill(cfg: TransformerConfig, donate: bool = True,
     ``next_token`` is the greedy argmax at position ``length - 1`` —
     the first generated token. ``attn_impl`` picks the in-flight
     attention engine (see :func:`_inflight_attention`)."""
-    _check_decode_config(cfg)
+    _check_decode_config(cfg, looped=False)
     inflight = _inflight_attention(cfg, attn_impl, cache_sharding)
 
     def prefill(params, cache, tokens, slot, length):
-        view = _lanes(cache, slot, inflight=inflight)
-        h = _decode_layers(params, cfg, tokens,
-                           jnp.arange(tokens.shape[0]), view)
-        return (view.cache,) + _greedy_head(params, h, length - 1)
+        cache, h, _ = _decode_layers(
+            params, cfg, tokens, jnp.arange(tokens.shape[0]), cache,
+            lambda c, _: _lanes(c, slot, inflight=inflight))
+        return (cache,) + _greedy_head(params, cfg, h, last=length - 1)
 
     return _jit_decode(prefill, donate, cache_sharding)
 
@@ -2001,13 +2293,13 @@ def build_decode_step(cfg: TransformerConfig, n_slots: int,
     batch between steps. Free slots ride along with ``token 0 @ pos
     0`` (their lane row 0 is rewritten by the next prefill); their
     outputs are garbage the host never reads."""
-    _check_decode_config(cfg)
+    _check_decode_config(cfg, looped=False)
     rows = jnp.arange(int(n_slots))
 
     def step(params, cache, tokens, pos):
-        view = _lanes(cache, rows, pos)
-        h = _decode_layers(params, cfg, tokens, pos, view)
-        return (view.cache,) + _greedy_head(params, h)
+        cache, h, _ = _decode_layers(params, cfg, tokens, pos, cache,
+                                     lambda c, _: _lanes(c, rows, pos))
+        return (cache,) + _greedy_head(params, cfg, h)
 
     return _jit_decode(step, donate, cache_sharding)
 
@@ -2042,14 +2334,19 @@ def build_decode_step(cfg: TransformerConfig, n_slots: int,
 def init_paged_kv_cache(cfg: TransformerConfig, n_pages: int,
                         page_size: int) -> Dict[str, List[jax.Array]]:
     """The shared page pool: ``{"k", "v"}``, each a LIST of one
-    ``[n_pages, page_size, n_heads, d_head]`` array a layer (f32, like
-    the dense pool — decode mirrors the reference numerics). Allocated
-    once and donated through every prefill/step/verify call. Page 0
-    is the scratch page (see module section comment); a pool of
-    ``n_pages`` therefore holds ``n_pages - 1`` claimable pages."""
+    ``[n_loops * n_pages, page_size, n_heads, d_head]`` array a layer,
+    in ``cfg.dtype`` (float32 or bfloat16, as the programs' K/V rows
+    are). Allocated once and donated through every prefill/step/verify
+    call. Page 0 is the scratch page (see module section comment); a
+    pool of ``n_pages`` therefore holds ``n_pages - 1`` claimable
+    pages. A looped stack keeps a row a (pass, layer, position): pass
+    ``t``'s rows of page ``id`` lie in page ``id + t * n_pages`` of the
+    layer's array, so a page id names a position's rows in every pass
+    and whoever counts pages counts a position once."""
     _check_decode_config(cfg)
-    shape = (int(n_pages), int(page_size), cfg.n_heads, cfg.d_head)
-    return {name: [jnp.zeros(shape, jnp.float32)
+    shape = (cfg.recipe.n_loops * int(n_pages), int(page_size),
+             cfg.n_heads, cfg.d_head)
+    return {name: [jnp.zeros(shape, _compute_dtype(cfg))
                    for _ in range(cfg.n_layers)] for name in ("k", "v")}
 
 
@@ -2105,12 +2402,14 @@ def build_paged_prefill(cfg: TransformerConfig, page_size: int,
     inflight = _inflight_attention(cfg, attn_impl, cache_sharding)
 
     def prefill(params, cache, tokens, page_table, length):
-        view = _table_pages(cache, page_table, 0, inflight=inflight)
-        h = _decode_layers(params, cfg, tokens,
-                           jnp.arange(tokens.shape[0]), view)
-        return (view.cache,) + _greedy_head(params, h, length - 1)
+        cache, h, lam = _decode_layers(
+            params, cfg, tokens, jnp.arange(tokens.shape[0]), cache,
+            lambda c, shift: _table_pages(c, _shifted(page_table, shift),
+                                          0, inflight=inflight))
+        return (cache,) + _greedy_head(params, cfg, h, lam, length - 1)
 
-    return _jit_decode(prefill, donate, cache_sharding)
+    return _jit_decode(prefill, donate, cache_sharding,
+                       looped=cfg.recipe.looped)
 
 
 def build_paged_prefix_prefill(cfg: TransformerConfig, page_size: int,
@@ -2166,13 +2465,15 @@ def build_paged_prefix_prefill(cfg: TransformerConfig, page_size: int,
 
     def prefill(params, cache, tokens, page_table, length, hit_len):
         pos = hit_len + jnp.arange(tokens.shape[0])    # virtual rows
-        view = _table_pages(cache, page_table, hit_len, pos,
-                            kernel=kernel)
-        h = _decode_layers(params, cfg, tokens, pos, view)
-        return (view.cache,) + _greedy_head(params, h,
-                                            length - 1 - hit_len)
+        cache, h, lam = _decode_layers(
+            params, cfg, tokens, pos, cache,
+            lambda c, shift: _table_pages(c, _shifted(page_table, shift),
+                                          hit_len, pos, kernel=kernel))
+        return (cache,) + _greedy_head(params, cfg, h, lam,
+                                       length - 1 - hit_len)
 
-    return _jit_decode(prefill, donate, cache_sharding)
+    return _jit_decode(prefill, donate, cache_sharding,
+                       looped=cfg.recipe.looped)
 
 
 def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
@@ -2211,12 +2512,15 @@ def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
         (3, 4, 4, 0, 0), scale=cfg.d_head ** -0.5, page_size=page_size)
 
     def step(params, cache, tokens, pos, page_tables):
-        view = _slot_pages(cache, page_tables, pos,
-                           page_tables[rows, pos // page_size], kernel)
-        h = _decode_layers(params, cfg, tokens, pos, view)
-        return (view.cache,) + _greedy_head(params, h)
+        pg = page_tables[rows, pos // page_size]
+        cache, h, lam = _decode_layers(
+            params, cfg, tokens, pos, cache,
+            lambda c, shift: _slot_pages(c, _shifted(page_tables, shift),
+                                         pos, _shifted(pg, shift), kernel))
+        return (cache,) + _greedy_head(params, cfg, h, lam)
 
-    return _jit_decode(step, donate, cache_sharding)
+    return _jit_decode(step, donate, cache_sharding,
+                       looped=cfg.recipe.looped)
 
 
 # ---------------------------------------------------------------------------
@@ -2291,7 +2595,7 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
     (``log p = -ce``), the XLA path reuses the verify's own logits.
     Both are f32-accumulated and parity-pinned in
     tests/test_transformer.py."""
-    _check_decode_config(cfg)
+    _check_decode_config(cfg, looped=False)
     n_slots, width = int(n_slots), int(width)
     page_size, pages_per_slot = int(page_size), int(pages_per_slot)
     V = page_size * pages_per_slot
@@ -2314,13 +2618,14 @@ def build_paged_verify_step(cfg: TransformerConfig, n_slots: int,
             page_tables[rows[:, None],
                         jnp.minimum(qpos // page_size,
                                     pages_per_slot - 1)], 0)  # [N, W]
-        view = _slot_pages(cache, page_tables, qpos, pg)
-        h = _decode_layers(params, cfg, tokens, qpos, view)  # [N, W, D]
-        toks, logits = _greedy_head(params, h)         # [N, W, vocab]
+        cache, h, _ = _decode_layers(
+            params, cfg, tokens, qpos, cache,
+            lambda c, _: _slot_pages(c, page_tables, qpos, pg))  # [N, W, D]
+        toks, logits = _greedy_head(params, cfg, h)    # [N, W, vocab]
         if not with_scores:
-            return view.cache, toks, logits
+            return cache, toks, logits
         with jax.named_scope("ce"):
-            return (view.cache, toks, logits,
+            return (cache, toks, logits,
                     score_proposals(tokens, h, logits, params["head"]))
 
     def score_proposals(tokens, h, logits, head):
@@ -2355,17 +2660,17 @@ def build_draft_propose(cfg: TransformerConfig, n_slots: int,
     half of the speculative dispatch saving. Greedy only: sampled
     slots need per-step draft distributions on host, so the scheduler
     falls back to ``width`` separate draft steps when one is active."""
-    _check_decode_config(cfg)
+    _check_decode_config(cfg, looped=False)
     rows = jnp.arange(int(n_slots))
 
     def propose(params, cache, tokens, pos):
         props = []
         for j in range(int(width)):
-            view = _lanes(cache, rows, pos + j)
-            h = _decode_layers(params, cfg, tokens, pos + j, view)
-            tokens, _ = _greedy_head(params, h)
+            cache, h, _ = _decode_layers(
+                params, cfg, tokens, pos + j, cache,
+                lambda c, _, j=j: _lanes(c, rows, pos + j))
+            tokens, _ = _greedy_head(params, cfg, h)
             props.append(tokens)
-            cache = view.cache
         return cache, jnp.stack(props, axis=1)
 
     return _jit_decode(propose, donate, n_replicated=1)

@@ -115,6 +115,20 @@ class TransformerDecoder:
     defaults to the dense equivalent (every slot can hold a full
     lane); set it lower to serve more slots at the same HBM — the
     scheduler's :class:`PagePool` admission keeps the pool honest.
+    The pool's leaves are in ``cfg.dtype`` (float32 or bfloat16), one
+    ``[n_loops * n_pages, page_size, H, Dh]`` array a layer for K and
+    one for V: a looped stack (``cfg.recipe.n_loops`` passes over the
+    same layers) keeps a row a (pass, layer, position), so its pool is
+    ``n_loops`` times a single pass's while a page id still names ONE
+    position's rows (in every pass and layer) and ``n_pages``,
+    :meth:`rows_at`, :meth:`pages_for` and the scheduler's
+    :class:`PagePool` count a position once
+    (:attr:`kv_bytes_per_position` says what it costs). What differs
+    between softmax configurations is what the program builders read
+    from ``cfg`` (the recipe, the dtype), never the decoder class.
+    ``prompt_buckets`` names the prefill ladder (ascending; each one
+    page or less, or whole pages) where the traffic's prompts do not
+    want the powers of two up to ``max_len``.
     Callers without a scheduler (direct API,
     ``testing/decode_load``) may omit page tables: an identity table
     (slot ``s`` -> pages ``[1 + s*pps, 1 + (s+1)*pps)``) stands in,
@@ -138,9 +152,17 @@ class TransformerDecoder:
                  attn_impl: str = "auto",
                  verify_ce_impl: Optional[str] = None,
                  prefix_cache: bool = True,
-                 quantized_ffn: bool = False):
+                 quantized_ffn: bool = False,
+                 prompt_buckets: Optional[List[int]] = None):
         from mmlspark_tpu.models import transformer as T
         self.cfg = cfg
+        #: passes a token makes over the layers (1: an unlooped stack)
+        self.n_loops = int(cfg.recipe.n_loops)
+        if cfg.recipe.looped and draft_params is not None:
+            raise ValueError(
+                "a looped stack has no speculation: its draft would be "
+                "a model of fewer passes, which is not built (ROADMAP "
+                "B12)")
         self.n_slots = int(n_slots)
         self.max_len = int(max_len)
         self.eos_id = eos_id
@@ -191,6 +213,20 @@ class TransformerDecoder:
                 f"max_len={self.max_len}")
         self.page_size = page_size
         self.pages_per_slot = self.max_len // self.page_size
+        self._ladder = (bucket_ladder(self.max_len)
+                        if prompt_buckets is None
+                        else sorted(int(b) for b in prompt_buckets))
+        if any(b > page_size and b % page_size for b in self._ladder) \
+                or not 0 < self._ladder[0] <= self._ladder[-1] \
+                <= self.max_len:
+            raise ValueError(
+                f"prompt_buckets={self._ladder}: each one page "
+                f"({page_size}) or less or whole pages, none past "
+                f"max_len={self.max_len}")
+        #: the longest prompt a prefill takes: the ladder's top bucket,
+        #: with a row left to generate into (the scheduler refuses a
+        #: longer one as a 400 and states this in ``/decode/stats``)
+        self.max_prompt = min(self._ladder[-1], self.max_len - 1)
         # default pool = the dense equivalent + the scratch page:
         # identical HBM and admission behavior until the operator
         # shrinks it (or raises n_slots at the same pool)
@@ -242,6 +278,12 @@ class TransformerDecoder:
             if prefix_cache else None)
         self.cache = T.init_paged_kv_cache(cfg, self.n_pages,
                                            self.page_size)
+        #: bytes one position's K and V rows take in the pool: every
+        #: layer's and, in a looped stack, every pass's
+        self.kv_bytes_per_position = self.n_loops * sum(
+            x.dtype.itemsize * int(np.prod(x.shape[2:]))
+            for x in self.cache["k"] + self.cache["v"])
+        self._split_fetched = T.split_fetched
         if 1 + self.n_slots * self.pages_per_slot <= self.n_pages:
             self._identity_tables = (
                 1 + np.arange(self.n_slots * self.pages_per_slot,
@@ -320,9 +362,14 @@ class TransformerDecoder:
 
     def prefill_facts(self, prompt_len: int) -> Dict[str, int]:
         """What a ``decode.prefill`` span carries beyond the
-        scheduler's own attributes: nothing, for this kind."""
-        return {}
+        scheduler's own attributes: the prompt's real tokens and the
+        passes each makes."""
+        return {"prompt_tokens": int(prompt_len), "loops": self.n_loops}
 
+    #: the last prefill's / step's expected exit passes (a looped
+    #: stack: None / an array a slot)
+    prefill_exit_pass: Optional[float] = None
+    step_exit_pass: Optional[np.ndarray] = None
     #: windows turned into summary rows: none, for this kind
     n_compactions = 0
     #: a slot holds nothing but its rows (a decoder whose slots also
@@ -362,12 +409,21 @@ class TransformerDecoder:
     def prompt_buckets(self) -> List[int]:
         """The prefill shape ladder: pow2 buckets clamped at
         ``max_len`` (same policy as the frame plane's batch buckets —
-        one ladder idiom framework-wide, derived in O(log max_len)
-        instead of the old O(max_len) bucket_target scan)."""
-        return bucket_ladder(self.max_len)
+        one ladder idiom framework-wide), or the ladder the
+        constructor was given."""
+        return list(self._ladder)
+
+    def bucket_of(self, n: int) -> int:
+        """The ladder's bucket a prompt (or suffix) of ``n`` tokens is
+        padded to."""
+        for b in self._ladder:
+            if b >= n:
+                return b
+        raise ValueError(f"a prompt of {n} tokens is past the prefill "
+                         f"ladder {self._ladder}")
 
     def pad_prompt(self, prompt: np.ndarray) -> np.ndarray:
-        bucket = bucket_target(len(prompt), self.max_len)
+        bucket = self.bucket_of(len(prompt))
         out = np.zeros(bucket, np.int32)
         out[:len(prompt)] = prompt
         return out
@@ -405,7 +461,17 @@ class TransformerDecoder:
                 self.draft_params, self.draft_cache,
                 jnp.asarray(padded), np.int32(slot),
                 np.int32(len(prompt)))
-        return int(nxt), logits
+        return self._first_token(nxt), logits
+
+    def _first_token(self, nxt) -> int:
+        """A prefill's one fetch: the first generated token, and from
+        a looped stack the last prompt position's expected exit pass
+        with it (kept as :attr:`prefill_exit_pass`)."""
+        if not self.cfg.recipe.looped:
+            return int(nxt)
+        tokens, exits = self._split_fetched(nxt)
+        self.prefill_exit_pass = float(exits[0])
+        return int(tokens[0])
 
     def prefill(self, slot: int, prompt: np.ndarray,
                 page_table=None) -> int:
@@ -444,7 +510,7 @@ class TransformerDecoder:
                 self.draft_params, self.draft_cache,
                 jnp.asarray(self.pad_prompt(prompt)), np.int32(slot),
                 np.int32(len(prompt)))
-        return int(nxt), logits
+        return self._first_token(nxt), logits
 
     def step_logits(self, tokens: np.ndarray, pos: np.ndarray,
                     page_tables=None) -> "tuple[np.ndarray, Any]":
@@ -467,8 +533,15 @@ class TransformerDecoder:
                 self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(pos),
                 jnp.asarray(np.asarray(page_tables, np.int32)))
-        with span("decode.fetch"):
+        with span("decode.fetch") as sp:
             out = np.asarray(nxt)
+            if self.cfg.recipe.looped:
+                # one copy back: [next tokens | expected exit passes]
+                out, exits = self._split_fetched(out)
+                live = np.asarray(pos) > 0
+                self.step_exit_pass = exits
+                sp.attrs = {"exit_pass_mean": float(
+                    exits[live].mean() if live.any() else exits.mean())}
         return out, logits
 
     def step(self, tokens: np.ndarray, pos: np.ndarray,
@@ -547,14 +620,14 @@ class TransformerDecoder:
         zero_tables = np.zeros((self.n_slots, self.pages_per_slot),
                                np.int32)
         self.step(zeros_t, zeros_t.copy(), zero_tables)
-        for bucket in self.prompt_buckets():
+        for bucket in self._ladder:
             self.prefill(0, np.zeros(min(bucket, self.max_len - 1),
                                      np.int32), zero_tables[0])
         if self._prefix_prefill is not None:
             # the offset prefill compiles per SUFFIX bucket — the same
             # pow2 ladder (hit depth is a traced scalar, not a shape)
             import jax.numpy as jnp
-            for bucket in self.prompt_buckets():
+            for bucket in self._ladder:
                 self.cache, _, _ = self._prefix_prefill(
                     self.params, self.cache,
                     jnp.asarray(np.zeros(bucket, np.int32)),
@@ -1233,6 +1306,12 @@ class DecodeScheduler:
                  prefix_cache_pages: Optional[int] = None):
         from mmlspark_tpu.serving.policy import SpeculationPolicy
         self.decoder = decoder
+        #: the longest prompt admitted: a decoder that pads a prompt
+        #: to ONE bucket of a ladder states its top (a shorter ladder
+        #: than max_len is a smaller limit); one that walks a prompt in
+        #: tiles or windows takes any that leaves a row to generate
+        self.max_prompt = getattr(decoder, "max_prompt",
+                                  decoder.max_len - 1)
         # acceptance-gated speculation (serving/policy.py): "auto"
         # installs the default policy when a draft exists, None runs
         # speculation unconditionally, or pass a configured
@@ -1550,6 +1629,15 @@ class DecodeScheduler:
         cold or stale (caller falls back to the constant)."""
         return self.release_ewma.retry_after(len(self._waiting))
 
+    def _bucket_of(self, n: int) -> int:
+        """The shape a prefill of ``n`` tokens runs in, for the span
+        and the histogram's label: the decoder's ladder is its one
+        owner; a decoder with no ladder over whole prompts (it walks
+        them in tiles or windows) is labelled by the power of two."""
+        of = getattr(self.decoder, "bucket_of", None)
+        return (of(n) if of is not None
+                else bucket_target(n, self.decoder.max_len))
+
     def parse(self, payload: Any
               ) -> "tuple[np.ndarray, int, Optional[Sampler], Optional[bool]]":
         """Payload -> (prompt tokens, max_new, sampler, speculative).
@@ -1570,10 +1658,11 @@ class DecodeScheduler:
             raise ValueError(
                 f"prompt token out of range (vocab "
                 f"{self.decoder.cfg.vocab})")
-        if len(prompt) >= self.decoder.max_len:
+        if len(prompt) > self.max_prompt:
             raise ValueError(
-                f"prompt length {len(prompt)} >= max_len "
-                f"{self.decoder.max_len} (no room to generate)")
+                f"prompt length {len(prompt)} > {self.max_prompt}, the "
+                f"longest this decoder prefills (max_len "
+                f"{self.decoder.max_len}, a row left to generate)")
         max_new = payload.get("max_new_tokens",
                               self.max_new_tokens_default)
         if not isinstance(max_new, int) or isinstance(max_new, bool) \
@@ -2072,15 +2161,19 @@ class DecodeScheduler:
             self._tables[slot] = self.decoder.lane(pages[:n_sum],
                                                    pages[n_sum:])
             table = self._tables[slot]
-            bucket = bucket_target(len(req.prompt) - hit_len,
-                                   self.decoder.max_len)
-            sp = span("decode.prefill", bucket=bucket,
+            sp = span("decode.prefill",
                       prompt_len=len(req.prompt), prefix_hit=hit_len,
                       slot=slot, others_active=len(self._active),
                       trace=getattr(p, "trace", None))
-            sp.attrs.update(self.decoder.prefill_facts(len(req.prompt)))
             try:
                 with sp:
+                    # what the decoder says of this prefill is its own
+                    # code: a refusal there fails this request, below,
+                    # and not the loop
+                    bucket = self._bucket_of(len(req.prompt) - hit_len)
+                    sp.attrs.update(
+                        self.decoder.prefill_facts(len(req.prompt)),
+                        bucket=bucket)
                     sp.attrs["queue_wait_ms"] = (
                         sp.t0 * 1e-9 - req.t_submit) * 1e3
                     if self.fault_plan is not None:
@@ -2126,8 +2219,7 @@ class DecodeScheduler:
             self.prefill_s += t1 - t0
             if self._m_prefill is not None:
                 self._m_prefill.labels(
-                    bucket_target(len(req.prompt),
-                                  self.decoder.max_len)).observe(
+                    self._bucket_of(len(req.prompt))).observe(
                     (t1 - t0) * 1000.0)
             # prefill runs ONE request: its whole wall time is that
             # request's tenant's device time
@@ -2343,8 +2435,10 @@ class DecodeScheduler:
                 # state a slot, beside the rows a position)
                 "state_slots": (len(self._active)
                                 if self.decoder.has_slot_state else 0),
-                # the rows this step reads, by kind
+                # the rows this step reads, by kind: positions (a
+                # looped stack reads each in ``loops`` passes)
                 "window_rows": win_rows, "summary_rows": sum_rows,
+                "loops": getattr(self.decoder, "n_loops", 1),
                 # the page tables' entries, and those of them that name
                 # a live row: what the attention kernel fetches
                 "table_entries": int(self._tables.size),
@@ -2635,6 +2729,15 @@ class DecodeScheduler:
                 "slots_free": self.pool.n_free,
                 "slots_high_water": self.slots_high_water,
                 "max_len": self.decoder.max_len,
+                # the longest prompt admitted (a prefill ladder that
+                # stops short of max_len is the smaller limit)
+                "max_prompt": self.max_prompt,
+                # passes a token makes over the layers, and what one
+                # position's K/V rows cost in the pool (every layer's
+                # and pass's; None: a decoder that does not say)
+                "n_loops": getattr(self.decoder, "n_loops", 1),
+                "kv_bytes_per_position": getattr(
+                    self.decoder, "kv_bytes_per_position", None),
                 # the decode-step gather engine: "pallas" = the fused
                 # block-table kernel, "dense" = the materialized-lane
                 # gather (CPU/mesh fallback)

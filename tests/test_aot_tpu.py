@@ -531,3 +531,90 @@ class TestGraniteHybridPrograms:
         assert len(_flash_call_names(c)) == 1
         assert text.count("ragged-dot-metadata") >= 1
         assert len(re.findall(r"%ragged-dot\S* = ", text)) >= 2 * cfg.n_layers
+
+
+# ---------------------------------------------------------------------------
+# a looped softmax stack at the shapes of the cell ouro-2.6b.reason-closed
+# (benchmark/configs/ouro-2.6b.json): the five programs the decoder
+# compiles, against abstract bfloat16 weights
+
+
+class TestOuroPrograms:
+    """48 layers run four times with one set of weights, a K/V row a
+    (pass, layer, position): the passes are ONE loop in the program (a
+    layer's kernel appears once, not four times), the pool of 8.08 GB is
+    updated where it lies, and weights and pool together leave the chip
+    room for the programs' temporaries."""
+
+    HBM = 15.75e9
+
+    @pytest.fixture(scope="class")
+    def shapes(self, topo):
+        import json
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs",
+                               "ouro-2.6b.json")) as f:
+            hf = json.load(f)
+        sv = hf["serve"]
+        cfg = T.TransformerConfig.from_hf(hf, dtype=sv["dtype"])
+        one = NamedSharding(_mesh(topo, {"x": 1}), P())
+        shapes = jax.eval_shape(lambda: T.init_params(cfg, 0))
+        # the weights as the driver hands them over: matrices in the
+        # configuration's dtype, norm gains and the gate float32
+        params = _abstract(jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, jnp.bfloat16 if x.size > cfg.d_model
+                else x.dtype), shapes), one)
+        pps = sv["max_len"] // sv["page_size"]
+        n_pages = 1 + sv["n_slots"] * pps
+        cache = _abstract(jax.eval_shape(
+            lambda: T.init_paged_kv_cache(cfg, n_pages, sv["page_size"])),
+            one)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+        return cfg, sv, pps, params, cache, i32
+
+    @staticmethod
+    def _nbytes(tree) -> int:
+        return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                   for x in jax.tree.leaves(tree))
+
+    def _check(self, c, cfg, params, cache, temp_limit):
+        mem = c.memory_analysis()
+        # every pool byte aliased: the loop's carry is updated in place
+        assert mem.alias_size_in_bytes == self._nbytes(cache)
+        assert mem.temp_size_in_bytes < temp_limit
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < self.HBM
+        layer_pool = int(np.prod(cache["k"][0].shape))
+        assert not _pool_sized_moves(c.as_text(), layer_pool)
+        return mem
+
+    def test_step(self, shapes):
+        cfg, sv, pps, params, cache, i32 = shapes
+        # 2.668 B parameters in bfloat16, a pool four times a pass's
+        assert 5.33e9 < self._nbytes(params) < 5.35e9
+        assert 8.07e9 < self._nbytes(cache) < 8.09e9
+        assert cache["k"][0].shape == (4 * 321, 16, 16, 128)
+        n = sv["n_slots"]
+        c = T.build_paged_decode_step(
+            cfg, n, sv["page_size"], pps, attn_impl="pallas").lower(
+            params, cache, i32(n), i32(n), i32(n, pps)).compile()
+        self._check(c, cfg, params, cache, 256 * 2**20)
+        # one loop body: a layer's kernel once in the text, run 4 times
+        assert _n_mosaic(c) == cfg.n_layers
+        assert c.as_text().count("paged_decode_attention") >= cfg.n_layers
+        assert "looped_step" in c.as_text()
+
+    @pytest.mark.parametrize("bucket", [32, 64, 128, 256])
+    def test_prefill(self, shapes, bucket):
+        cfg, sv, pps, params, cache, i32 = shapes
+        assert bucket in sv["prompt_buckets"]
+        c = T.build_paged_prefill(
+            cfg, sv["page_size"], pps, attn_impl="pallas").lower(
+            params, cache, i32(bucket), i32(pps), i32()).compile()
+        self._check(c, cfg, params, cache, 512 * 2**20)
+        assert len(_flash_call_names(c)) == cfg.n_layers
+        assert "looped_prefill" in c.as_text()

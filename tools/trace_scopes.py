@@ -24,11 +24,17 @@ SCOPES = ("embed", "norm", "attn.qkv", "attn.core", "attn.out", "ffn",
           "moe", "head", "ce", "optimizer", "kv.write", "kv.gather",
           # the Granite-hybrid programs' parts (models/granite_hybrid.py)
           "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.gate_norm", "ssm.out",
-          "moe.route", "moe.experts", "moe.shared")
+          "moe.route", "moe.experts", "moe.shared",
+          # a looped stack's programs (jit_looped_step / _prefill): the
+          # norm between passes and the exit gate; the layers' parts
+          # keep the block's names under while/body
+          "loop.norm", "loop.gate")
 
 
 def scope_of(tf_op: str) -> str:
-    """``jit(step)/kv.write/scatter`` -> ``kv.write``."""
+    """``jit(step)/kv.write/scatter`` -> ``kv.write``;
+    ``jit(looped_step)/while/body/closed_call/loop.norm/norm/mul`` ->
+    ``loop.norm`` (the outermost known scope)."""
     parts = [p for p in tf_op.split("/") if p and not p.startswith("jit(")]
     return next((p for p in parts if p in SCOPES),
                 parts[0] if parts else "(none)")
