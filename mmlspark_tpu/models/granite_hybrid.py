@@ -619,9 +619,11 @@ def build_hybrid_prefill(cfg: GraniteHybridConfig, page_size: int,
 def build_hybrid_step(cfg: GraniteHybridConfig, page_size: int,
                       donate: bool = True, attn_impl: str = "dense"):
     """Jitted ``hybrid_step(params, cache, tokens, pos, page_tables) ->
-    (cache, fetched, logits)``: one token for every slot. ``fetched``
-    is everything the host reads back a step, as ONE int32 vector:
-    ``[next_tokens (n) | routings (len(experts_held)) | touched]``. Every slot's state is advanced once where it lies (the
+    (cache, fetched, logits, next_tokens)``: one token for every slot.
+    ``fetched`` is everything the host reads back a step, as ONE int32
+    vector: ``[next_tokens (n) | routings (len(experts_held)) |
+    touched]``; ``next_tokens`` alone is what a step dispatched before
+    this one is fetched takes, as it lies on the device. Every slot's state is advanced once where it lies (the
     cache is donated); the attention layers' new K/V row goes to row
     ``pos`` of the slot's lane. Free slots ride along at position 0
     with an all-scratch table; their routings are not counted (a live
@@ -672,7 +674,8 @@ def build_hybrid_step(cfg: GraniteHybridConfig, page_size: int,
             routings, touched = routings + r, touched + tch
         logits = _head(params, x, cfg)
         nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        return c, jnp.concatenate([nxt, routings, touched[None]]), logits
+        return (c, jnp.concatenate([nxt, routings, touched[None]]), logits,
+                nxt)
 
     return jax.jit(hybrid_step, donate_argnums=(1,) if donate else ())
 

@@ -2482,7 +2482,10 @@ def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
                             attn_impl: str = "dense"):
     """Jitted ``step(params, cache, tokens, pos, page_tables) ->
     (cache, next_tokens, logits)`` — one token for every slot through
-    the block-table layout (the paged :func:`build_decode_step`).
+    the block-table layout (the paged :func:`build_decode_step`). A
+    looped stack's is ``(cache, fetched, logits, next_tokens)``:
+    ``fetched`` packs the exit passes behind the tokens
+    (:func:`split_fetched`).
 
     Each slot writes its new K/V row at page
     ``page_tables[slot, pos // page_size]``, row ``pos % page_size``,
@@ -2517,9 +2520,17 @@ def build_paged_decode_step(cfg: TransformerConfig, n_slots: int,
             params, cfg, tokens, pos, cache,
             lambda c, shift: _slot_pages(c, _shifted(page_tables, shift),
                                          pos, _shifted(pg, shift), kernel))
-        return (cache,) + _greedy_head(params, cfg, h, lam)
+        fetched, logits = _greedy_head(params, cfg, h, lam)
+        if lam is None:
+            return cache, fetched, logits
+        # a looped stack's one fetch packs the exit passes behind the
+        # tokens: the tokens are an output of their own as well, which
+        # a step dispatched before this one is fetched takes as it lies
+        # on the device (nobody copies it back)
+        return cache, fetched, logits, fetched[:rows.shape[0]]
 
     return _jit_decode(step, donate, cache_sharding,
+                       n_replicated=3 if cfg.recipe.looped else 2,
                        looped=cfg.recipe.looped)
 
 

@@ -92,6 +92,27 @@ from mmlspark_tpu.serving.tenancy import (
 logger = get_logger("serving.decode")
 
 
+class StepInFlight:
+    """A step that was dispatched and is not fetched yet: what a
+    decoder's ``dispatch_step`` hands back and its ``fetch_step``, or
+    the next ``dispatch_step`` in place of host tokens, takes.
+    ``tokens`` is the step's greedy token a slot as it lies on the
+    device, ``fetched`` the one array the host copies back (the same
+    array where the program packs nothing beside its tokens),
+    ``logits`` the device's, ``pos`` the host's positions the step ran
+    at and ``seq`` its number among the steps its decoder dispatched."""
+
+    __slots__ = ("seq", "tokens", "fetched", "logits", "pos")
+
+    def __init__(self, seq: int, tokens, fetched, logits,
+                 pos: np.ndarray):
+        self.seq = seq
+        self.tokens = tokens
+        self.fetched = fetched
+        self.logits = logits
+        self.pos = pos
+
+
 class DecodeOverloaded(RuntimeError):
     """The waiting queue is full: new decode work must shed (429)."""
 
@@ -284,6 +305,9 @@ class TransformerDecoder:
             x.dtype.itemsize * int(np.prod(x.shape[2:]))
             for x in self.cache["k"] + self.cache["v"])
         self._split_fetched = T.split_fetched
+        #: which output of ``_step`` behind the cache is the tokens
+        #: alone (a looped stack's first is its packed fetch)
+        self._tokens_out = 2 if cfg.recipe.looped else 0
         if 1 + self.n_slots * self.pages_per_slot <= self.n_pages:
             self._identity_tables = (
                 1 + np.arange(self.n_slots * self.pages_per_slot,
@@ -512,37 +536,84 @@ class TransformerDecoder:
                 np.int32(len(prompt)))
         return self._first_token(nxt), logits
 
-    def step_logits(self, tokens: np.ndarray, pos: np.ndarray,
-                    page_tables=None) -> "tuple[np.ndarray, Any]":
-        """One token for every slot: ``tokens``/``pos`` are the full
-        fixed ``[n_slots]`` arrays (free slots ride along at token 0 /
-        pos 0 with an all-scratch table row).
-        Returns greedy next tokens plus the full per-slot logits
-        (device array; fetched only when a sampler needs it)."""
+    #: steps dispatched so far: a step's sequence number, on its
+    #: ``decode.dispatch`` span (``seq``) and on the ``decode.fetch``
+    #: span that waited for it (``fetched``)
+    n_dispatched = 0
+
+    def dispatch_step(self, tokens, pos: np.ndarray,
+                      page_tables=None) -> StepInFlight:
+        """One token for every slot, as far as the host's part goes:
+        the host-to-device copies and the call of the step program
+        until it returns (the ``decode.dispatch`` span, in the pass the
+        scheduler has open on this thread). ``tokens``/``pos`` are the
+        full fixed ``[n_slots]`` arrays (free slots ride along at token
+        0 / pos 0 with an all-scratch table row), or ``tokens`` is the
+        :class:`StepInFlight` of the step before, not fetched yet:
+        this step then takes that one's tokens as they lie on the
+        device and queues behind it (``ahead`` on the span). Shared by
+        every decoder class: ``_step`` returns ``(cache, fetched,
+        logits, ...)`` and ``_tokens_out`` names the output that is
+        the tokens."""
         import jax.numpy as jnp
         if page_tables is None:
             if self._identity_tables is None:
                 raise ValueError("undersized paged pool needs "
                                  "scheduler page tables")
             page_tables = self._identity_tables
-        # the host-to-device copies and the call until it returns, then
-        # the wait for the device and the copy back: two spans that land
-        # in the pass the scheduler has open on this thread
-        with span("decode.dispatch"):
-            self.cache, nxt, logits = self._step(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos),
+        ahead = isinstance(tokens, StepInFlight)
+        self.n_dispatched += 1
+        with span("decode.dispatch", seq=self.n_dispatched, ahead=ahead):
+            if ahead:
+                tokens = tokens.tokens
+            elif self.mesh is None:
+                tokens = jnp.asarray(tokens)
+            else:
+                # as a step's token output lies on the mesh (replicated:
+                # _jit_decode), so that both forms run ONE executable
+                import jax
+                from jax.sharding import NamedSharding, PartitionSpec
+                tokens = jax.device_put(
+                    np.asarray(tokens),
+                    NamedSharding(self.mesh, PartitionSpec()))
+            self.cache, *outs = self._step(
+                self.params, self.cache, tokens, jnp.asarray(pos),
                 jnp.asarray(np.asarray(page_tables, np.int32)))
-        with span("decode.fetch") as sp:
-            out = np.asarray(nxt)
-            if self.cfg.recipe.looped:
-                # one copy back: [next tokens | expected exit passes]
-                out, exits = self._split_fetched(out)
-                live = np.asarray(pos) > 0
-                self.step_exit_pass = exits
-                sp.attrs = {"exit_pass_mean": float(
-                    exits[live].mean() if live.any() else exits.mean())}
-        return out, logits
+        return StepInFlight(self.n_dispatched, outs[self._tokens_out],
+                            outs[0], outs[1], np.asarray(pos))
+
+    def fetch_step(self, step: StepInFlight
+                   ) -> "tuple[np.ndarray, Any]":
+        """What is left of ``step`` on the device and the one copy back
+        (the ``decode.fetch`` span): greedy next tokens plus the full
+        per-slot logits (device array; fetched only when a sampler
+        needs it). Shared by every decoder class: ``_read_fetched``
+        says what a program packs beside its tokens."""
+        with span("decode.fetch", fetched=step.seq) as sp:
+            out = self._read_fetched(np.asarray(step.fetched), step.pos,
+                                     sp.attrs)
+        return out, step.logits
+
+    def _read_fetched(self, fetched: np.ndarray, pos: np.ndarray,
+                      attrs: Dict[str, Any]) -> np.ndarray:
+        """A step's one copy back -> its tokens; what else it holds
+        goes onto the span's ``attrs`` (a looped stack: ``[next tokens
+        | expected exit passes]``)."""
+        if not self.cfg.recipe.looped:
+            return fetched
+        out, exits = self._split_fetched(fetched)
+        live = pos > 0
+        self.step_exit_pass = exits
+        attrs["exit_pass_mean"] = float(
+            exits[live].mean() if live.any() else exits.mean())
+        return out
+
+    def step_logits(self, tokens: np.ndarray, pos: np.ndarray,
+                    page_tables=None) -> "tuple[np.ndarray, Any]":
+        """:meth:`dispatch_step` and :meth:`fetch_step` in a row: a
+        step nobody runs ahead of."""
+        return self.fetch_step(self.dispatch_step(tokens, pos,
+                                                  page_tables))
 
     def step(self, tokens: np.ndarray, pos: np.ndarray,
              page_tables=None) -> np.ndarray:
@@ -596,6 +667,19 @@ class TransformerDecoder:
         with span("decode.fetch"):
             return np.asarray(toks), logits, np.asarray(scores)
 
+    def _warm_step(self, tables: np.ndarray) -> None:
+        """The step in both forms the scheduler's loop runs it: from
+        host tokens, and ahead of a step that is not fetched yet, from
+        that one's tokens on the device (one executable on jax 0.9:
+        both are uncommitted int32 arrays of one shape, and under a
+        mesh :meth:`dispatch_step` places the host's tokens as a
+        step's output lies; were it two, both are compiled here and
+        counted). Shared by every decoder class."""
+        zeros = np.zeros(self.n_slots, np.int32)
+        first = self.dispatch_step(zeros, zeros.copy(), tables)
+        self.fetch_step(self.dispatch_step(first, zeros.copy(), tables))
+        self.fetch_step(first)
+
     def n_compiles(self) -> int:
         """Compiled-executable count across every jitted entry point
         (prefill buckets, the step, and the draft/propose/verify
@@ -619,7 +703,7 @@ class TransformerDecoder:
         zeros_t = np.zeros(self.n_slots, np.int32)
         zero_tables = np.zeros((self.n_slots, self.pages_per_slot),
                                np.int32)
-        self.step(zeros_t, zeros_t.copy(), zero_tables)
+        self._warm_step(zero_tables)
         for bucket in self._ladder:
             self.prefill(0, np.zeros(min(bucket, self.max_len - 1),
                                      np.int32), zero_tables[0])
@@ -1211,8 +1295,12 @@ def pass_view(phases) -> Dict[str, Any]:
     as ``{"phases_ms": {phase: milliseconds}, "prefills": [one entry a
     ``decode.prefill``: its attributes, ``start_ms`` into the pass and
     ``ms``], **the other phases' attributes}`` (``admitted``,
-    ``active``, ``pages_in_use``, ``n_pages``, ``emitted``).
-    Read-side: the loop stores the spans as they are."""
+    ``active``, ``pages_in_use``, ``n_pages``, ``emitted``; of the
+    step the pass dispatched ``seq`` and ``ahead``, whether a step was
+    in flight as it went out, and ``fetched``, the sequence number of
+    the step the pass's fetch waited for: ``seq`` less one in a pass
+    that ran ahead). Read-side: the loop stores the spans as they
+    are."""
     t0 = min(p[1] for p in phases)
     view: Dict[str, Any] = {"phases_ms": {}, "prefills": []}
     for name, a, b, attrs in phases:
@@ -1370,6 +1458,15 @@ class DecodeScheduler:
         self.n_prompt_tokens = 0
         self.prefill_s = 0.0
         self.n_step_faults = 0
+        # the step in flight: dispatched in a pass that is over and not
+        # fetched yet, ``(decoder's StepInFlight, the requests it
+        # carries by slot, its dispatch's start in seconds)``; steps
+        # dispatched behind one; tokens of lanes whose request had left
+        # by the time their step was fetched; the last fetch's end
+        self._flight: Optional[tuple] = None
+        self.n_steps_ahead = 0
+        self.n_tokens_discarded = 0
+        self._t_fetched = 0.0
         self.slots_high_water = 0
         self.n_page_preempts = 0
         # speculative ledger: acceptance_rate = accepted / proposed
@@ -1445,9 +1542,17 @@ class DecodeScheduler:
             ("serving_decode_steps_total",
              "Single-token decode steps executed (each covers every "
              "live slot).", lambda: self.n_steps),
+            ("serving_decode_steps_ahead_total",
+             "Decode steps dispatched behind a step that was not "
+             "fetched yet (the host's turn ran beside the device).",
+             lambda: self.n_steps_ahead),
             ("serving_decode_tokens_total",
              "Tokens emitted to live requests.",
              lambda: self.n_tokens),
+            ("serving_decode_tokens_discarded_total",
+             "Tokens of a step in flight whose request had left by "
+             "the time it was fetched (never emitted).",
+             lambda: self.n_tokens_discarded),
             ("serving_decode_prefills_total",
              "Prompt prefills (slot claims).",
              lambda: self.n_prefills),
@@ -1863,6 +1968,10 @@ class DecodeScheduler:
                            finish_reason=reason)
             req.slot = None
         if req.pages or req.sum_pages:
+            # a step in flight may still write this request's next row
+            # into one of these pages: whoever claims them next is
+            # dispatched behind that step (:meth:`_emit_step`), and a
+            # published page is a full prompt page, which no step writes
             self._release_pages(req, publish=reason != "error")
         with self._lock:
             self._by_rid.pop(req.pending.rid, None)
@@ -1965,7 +2074,10 @@ class DecodeScheduler:
         """Every pass is a chain of ``decode.*`` spans (admit [with a
         prefill child per request admitted], then prepare, dispatch,
         fetch, emit, or idle), owned here and recorded once, as one
-        ``decode.pass`` span, when the pass ends."""
+        ``decode.pass`` span, when the pass ends. Which step the
+        pass's fetch waits for (the one it dispatched, or the one a
+        pass before did, with its own left in flight) is
+        :meth:`_run_step`'s to say, from what the slots hold."""
         with collect() as self._pass:
             while not self._stop.is_set():
                 with span("decode.admit") as sp:
@@ -1977,7 +2089,7 @@ class DecodeScheduler:
                     # frontend's request_timeout
                     self._reap_waiting()
                     sp.attrs = {"admitted": self._admit_waiting()}
-                if self._active:
+                if self._active or self._flight is not None:
                     self._run_step()
                 else:
                     # fully idle (nothing waiting either) -> block
@@ -1991,14 +2103,17 @@ class DecodeScheduler:
                     self._work.clear()
                 self._record_pass(self._pass[:])
                 self._pass.clear()
+        self._flight = None     # a stopped loop leaves nothing queued
 
     def _record_pass(self, phases: list) -> None:
         """One finished pass: its phases into the cumulative ``loop``
         counters, and the pass itself ONCE into the tracer's ring as a
         root ``decode.pass`` span under a trace id of its own: start,
         end, the step's sequence number and ``phases``, the spans as
-        they closed (:func:`pass_view` reads them). This runs between
-        two steps, while the device waits: it does the least it can. A
+        they closed (:func:`pass_view` reads them); the sequence number
+        counts the steps dispatched so far, the one in flight with
+        them. This runs between two steps, and with no step in flight
+        the device waits for it: it does the least it can. A
         pass that ran a step or a prefill and lasted over
         ``SLOW_PASS_MULTIPLE`` times the running median of the passes
         that ran a step is retained under route ``decode.loop`` like
@@ -2018,8 +2133,8 @@ class DecodeScheduler:
                 riders = attrs["traces"]
             elif name == "decode.dispatch":
                 stepped = True
-            elif name == "decode.prefill":
-                worked = True
+            elif name in ("decode.prefill", "decode.fetch"):
+                worked = True    # a pass that only fetched waited too
             t1 = b
         if self.tracer is None:
             return
@@ -2036,7 +2151,9 @@ class DecodeScheduler:
                                         or ns >= self._slow_ns)
         self.tracer.add(
             "decode.pass", t0 * 1e-9, t1 * 1e-9, None, capture=slow,
-            route=LOOP_ROUTE, step=self.n_steps if stepped else None,
+            route=LOOP_ROUTE, step=(
+                self.n_steps + (self._flight is not None)
+                if stepped else None),
             traces=riders, phases=phases,
             **(pass_view(phases) if slow else {}))
 
@@ -2319,16 +2436,21 @@ class DecodeScheduler:
         self._tables[req.slot] = self.decoder.lane(req.sum_pages, req.pages)
         return True
 
-    def _compact_filled_windows(self) -> None:
-        """After a step: a slot whose step wrote its window's last row
-        turns that window into summary rows (``decoder.compact``: the
-        second kind of row, in pages claimed here and kept until the
-        request leaves) and gives the window's pages back. A pool that
-        cannot hold the summaries ends the request like any other
-        growth (``pages_exhausted``); a compaction that raises ends it
-        like a failed step."""
+    def _compact_filled_windows(self, slots) -> None:
+        """After a step: a slot of ``slots`` (those the step gave a
+        token and that are still taken) whose step wrote its window's
+        last row turns that window into summary rows
+        (``decoder.compact``: the second kind of row, in pages claimed
+        here and kept until the request leaves) and gives the window's
+        pages back. A slot that took no part in the step is left alone:
+        a request admitted behind a step in flight whose prompt ends on
+        a window's edge has that window compacted by its prefill. A
+        pool that cannot hold the summaries ends the request like any
+        other growth (``pages_exhausted``); a compaction that raises
+        ends it like a failed step."""
         window = self.decoder.window
-        for slot, req in list(self._active.items()):
+        for slot in slots:
+            req = self._active[slot]
             if int(self._pos[slot]) % window:
                 continue
             with span("decode.compact", slot=slot,
@@ -2398,9 +2520,10 @@ class DecodeScheduler:
                 spec[slot] = req
         return spec
 
-    def _live_rows(self) -> "tuple[int, int, int]":
+    def _live_rows(self, ahead: int = 0) -> "tuple[int, int, int]":
         """``(summary_rows, window_rows, table_entries)`` over the live
-        slots, as the decoder counts them at each slot's position: the
+        slots, as the decoder counts them at each slot's position
+        (``ahead`` rows on, for the step behind one in flight): the
         rows a step reads by kind, and the entries of the slots' page
         tables that name them (a slot's rows lie in the first
         ``cdiv(rows, page_size)`` entries of its row, and the decode
@@ -2408,7 +2531,7 @@ class DecodeScheduler:
         live = list(self._active)
         if not live:
             return 0, 0, 0
-        n_sum, n_win = self.decoder.rows_at(self._pos[live])
+        n_sum, n_win = self.decoder.rows_at(self._pos[live] + ahead)
         entries = -(-(n_sum + n_win) // self.decoder.page_size)
         return int(np.sum(n_sum)), int(np.sum(n_win)), int(np.sum(entries))
 
@@ -2423,10 +2546,88 @@ class DecodeScheduler:
             return now, now
         return done[0][1] * 1e-9, done[-1][2] * 1e-9
 
+    def _may_run_ahead(self, riders) -> bool:
+        """Whether the step this pass dispatches may still be in flight
+        when the pass ends, so that the next pass queues another behind
+        it before it fetches (``riders``: the requests by slot of the
+        step in flight now, whose tokens the host has not seen, or None
+        with nothing in flight). Decided from what the
+        host sees before the fetch, and by nothing else:
+
+        * every slot is taken, by the requests the step in flight
+          carries: a free slot's next request would wait for every
+          queued step, not for what is left of one;
+        * every request is greedy and none may speculate: a sampled
+          token is drawn on the host from the fetched logits, and a
+          speculative round (or the draft's catch-up step) is another
+          program between two steps;
+        * no slot is known to end with the token in flight (its
+          ``max_new``-th, the lane's last row, a cancel, a closed
+          stream or a deadline already seen), so the pass that frees a
+          slot finds nothing queued behind it;
+        * the step in flight does not fill a window (a decoder with
+          two kinds of row): its compaction runs between that step and
+          the next, so that pass keeps the order fetch, compact,
+          dispatch (one pass in a window's length);
+        * every lane can grow to the row the step behind the one in
+          flight writes (grown HERE, a row on from
+          :meth:`_prepare_round`'s): a slot the pool cannot serve ends
+          for want of pages after its token is out, in the next pass's
+          upkeep, with nothing queued behind it.
+
+        An ``eos_id`` token cannot be seen before the fetch: that
+        request retires at its emit and the step queued behind carries
+        one lane more (:meth:`_emit_step` discards it)."""
+        if self.pool.n_free or not self._active:
+            return False
+        if riders is None:
+            return not any(r.sampler is not None or self._spec_capable(r)
+                           for r in self._active.values())
+        if riders != self._active:
+            return False
+        # the riders were greedy as their step went out, and stay so
+        last_row = self.decoder.max_len - 1
+        window = self.decoder.window
+        for slot, req in riders.items():
+            pos = int(self._pos[slot]) + 1
+            s, d = req.stream, req.pending.deadline
+            if len(req.produced) + 1 >= req.max_new \
+                    or pos >= last_row or (window and pos % window == 0) \
+                    or req.cancelled or (s is not None and s.closed) \
+                    or (d is not None and d.expired):
+                return False
+        return all(self._ensure_pages(req, int(self._pos[slot]) + 1)
+                   for slot, req in riders.items())
+
     def _run_step(self) -> None:
+        """The pass's step work, ordered by what the slots hold
+        (:meth:`_may_run_ahead`), in one of three ways:
+
+        * nothing in flight and the rule false: today's order, prepare,
+          dispatch, fetch, emit through ``decoder.step_logits`` (or a
+          speculative round);
+        * the rule holds: a step is dispatched and LEFT in flight. The
+          first such pass ends there; every later one queues its step
+          behind the one in flight, on that one's tokens as they lie on
+          the device, and only then fetches that one and emits its
+          tokens: prepare, dispatch, fetch, emit again, but the fetch
+          waits for a step dispatched a pass ago, and the host's turn
+          runs beside the device;
+        * the rule stops holding with a step in flight: the pass only
+          fetches and emits (its ``decode.prepare`` prepares nothing
+          and says what the fetched step read)."""
+        flight, self._flight = self._flight, None
+        in_flight, riders, t_dispatch = flight or (None, None, 0.0)
         with span("decode.prepare") as sp:
-            spec = self._prepare_round()
-            sum_rows, win_rows, live_entries = self._live_rows()
+            # with a step in flight nobody is reaped or preempted
+            # before its token is out: a dead slot retires at its emit
+            spec = self._prepare_round() if flight is None else {}
+            ahead = self._may_run_ahead(riders)
+            # the rows of the step this pass dispatches: one on, behind
+            # a step in flight; a pass that only fetches stamps what
+            # the step it fetches read
+            n_ahead = 1 if ahead and flight is not None else 0
+            sum_rows, win_rows, live_entries = self._live_rows(n_ahead)
             sp.attrs = {
                 "active": len(self._active),
                 "pages_in_use": self._pages_in_use(),
@@ -2445,34 +2646,91 @@ class DecodeScheduler:
                 "table_entries_live": live_entries,
                 "traces": [getattr(r.pending, "trace", None)
                            for r in self._active.values()]}
-        if not self._active:
-            return
         if spec:
             self._run_spec_round(spec)
             return
         i0 = len(self._pass)
-        try:
-            if self.fault_plan is not None:
-                self.fault_plan.raise_at("decode_step",
-                                         clock=self.clock)
-            out, step_logits = self.decoder.step_logits(
-                self._tokens, self._pos, self._tables)
-        except Exception as e:  # noqa: BLE001 — injected or real
-            # a failed step loses the affected requests (500, never
-            # journaled — clients may retry) but NEVER a slot or page
-            self.n_step_faults += 1
-            logger.warning("decode step failed; failing %d in-slot "
-                           "requests", len(self._active), exc_info=True)
-            for req in list(self._active.values()):
-                self._finish(req, "error", status=500,
-                             error=f"decode step failed: {e}")
+        if flight is None and not ahead:
+            # today's order (``step_logits`` is dispatch and fetch in a
+            # row: tests and the benchmark's planted faults wrap it)
+            if not self._active:
+                return
+            reqs = dict(self._active)
+            try:
+                if self.fault_plan is not None:
+                    self.fault_plan.raise_at("decode_step",
+                                             clock=self.clock)
+                out, logits = self.decoder.step_logits(
+                    self._tokens, self._pos, self._tables)
+            except Exception as e:  # noqa: BLE001 — injected or real
+                self._fail_step(e)
+                return
+            self._emit_step(out, logits, reqs, *self._device_interval(i0))
             return
-        t0, t1 = self._device_interval(i0)
+        queued = None
+        if ahead:
+            try:
+                if self.fault_plan is not None:
+                    self.fault_plan.raise_at("decode_step",
+                                             clock=self.clock)
+                # copies of the host's arrays: they change under a
+                # step that is still in flight
+                step = self.decoder.dispatch_step(
+                    self._tokens.copy() if flight is None else in_flight,
+                    self._pos + n_ahead, self._tables.copy())
+            except Exception as e:  # noqa: BLE001 — injected or real
+                self._fail_step(e)
+                return
+            queued = (step, dict(self._active), self._device_interval(i0)[0])
+            self.n_steps_ahead += n_ahead
+        if flight is not None:
+            i0 = len(self._pass)
+            try:
+                out, logits = self.decoder.fetch_step(in_flight)
+            except Exception as e:  # noqa: BLE001 — surfaces at its fetch
+                # the step queued behind ran on this one's cache: both
+                # are lost, and counted as the one fault they are
+                self._fail_step(e)
+                return
+            # a step queued behind another started when that one ended
+            self._emit_step(out, logits, riders,
+                            max(t_dispatch, self._t_fetched),
+                            self._device_interval(i0)[1])
+        self._flight = queued
+
+    def _fail_step(self, e: Exception) -> None:
+        """A failed step loses the affected requests (500, never
+        journaled — clients may retry) but NEVER a slot or page; a
+        step still in flight is dropped with it."""
+        self.n_step_faults += 1
+        logger.warning("decode step failed; failing %d in-slot "
+                       "requests", len(self._active), exc_info=True)
+        for req in list(self._active.values()):
+            self._finish(req, "error", status=500,
+                         error=f"decode step failed: {e}")
+
+    def _emit_step(self, out: np.ndarray, step_logits,
+                   reqs: Dict[int, _DecodeRequest],
+                   t0: float, t1: float) -> None:
+        """A fetched step's tokens to the requests that rode it
+        (``reqs``, as they stood at its dispatch), its wall time
+        ``t0``-``t1`` (the end of the fetch before, or its own dispatch
+        if that came later, to the end of its fetch) to the step
+        histogram and the tenants. A request that left while the step
+        was in flight (an ``eos_id`` token, a cancel, a closed stream,
+        a deadline: nothing the host saw before it queued the step)
+        has a lane in it all the same: that token is DISCARDED, never
+        streamed, never in ``produced`` or ``n_tokens``. Its row went
+        to a page, and with a state a slot its state to a slot, that
+        were released when the request left: whatever takes them over
+        (a prefill, another slot's growth, a first tile's reset) is
+        dispatched behind the step in flight, and so runs behind it on
+        the device."""
+        self._t_fetched = t1
         self.n_steps += 1
         if self._m_step is not None:
             self._m_step.labels().observe((t1 - t0) * 1000.0)
-        self._charge_device_ms((t1 - t0) * 1000.0,
-                               self._active.values())
+        self._charge_device_ms((t1 - t0) * 1000.0, reqs.values())
         if self.decoder.has_draft and any(
                 self._spec_capable(r) for r in self._active.values()):
             # draft-cache catch-up: a spec-capable slot stepping
@@ -2493,23 +2751,30 @@ class DecodeScheduler:
             # step, paid ONLY while a sampling request is in a slot —
             # pure-greedy batches keep the token-only transfer
             logits_np = None
-            if any(r.sampler is not None
-                   for r in self._active.values()):
+            if any(r.sampler is not None for r in reqs.values()):
                 logits_np = np.asarray(step_logits)
-            live = list(self._active.items())
-            for slot, req in live:
+            emitted = 0
+            for slot, req in reqs.items():
+                if self._active.get(slot) is not req:
+                    # (the slot may hold its next request by now, which
+                    # took no part in this step)
+                    self.n_tokens_discarded += 1
+                    continue
                 tok = (int(out[slot]) if req.sampler is None
                        else req.sampler.sample(logits_np[slot]))
                 req.produced.append(tok)
                 self.n_tokens += 1
+                emitted += 1
                 req.t_last = t1      # one store/token: the TPOT stamp
                 self._pos[slot] += 1
                 self._tokens[slot] = tok
                 self._emit_stream(req, [tok])
                 self._retire_if_done(req, tok)
-            sp.attrs = {"emitted": len(live)}
+            sp.attrs = {"emitted": emitted}
         if self.decoder.window:
-            self._compact_filled_windows()
+            self._compact_filled_windows(
+                [slot for slot, req in reqs.items()
+                 if self._active.get(slot) is req])
 
     def _run_spec_round(self, spec: Dict[int, _DecodeRequest]) -> None:
         """One speculative round: draft proposes ``spec_k`` tokens per
@@ -2763,6 +3028,12 @@ class DecodeScheduler:
                 "max_waiting": self.max_waiting,
                 "n_requests": self.n_requests,
                 "n_steps": self.n_steps,
+                # of them, steps dispatched behind a step that was not
+                # fetched yet (the host's turn ran beside the device),
+                # and tokens of lanes whose request had left when their
+                # step was fetched (never emitted, not in n_tokens)
+                "n_steps_ahead": self.n_steps_ahead,
+                "n_tokens_discarded": self.n_tokens_discarded,
                 "n_tokens": self.n_tokens,
                 # goodput: tokens from requests that resolved cleanly
                 # (eos/length) vs everything emitted — cancelled/

@@ -27,7 +27,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from mmlspark_tpu.core.profiling import span
+from mmlspark_tpu.serving.decode import TransformerDecoder
 
 
 class EvaByteDecoder:
@@ -222,28 +222,20 @@ class EvaByteDecoder:
                 page_table=None) -> int:
         return self.prefill_logits(slot, prompt, page_table)[0]
 
-    def step_logits(self, tokens: np.ndarray, pos: np.ndarray,
-                    page_tables=None) -> "tuple[np.ndarray, Any]":
-        """One byte for every slot (free slots ride along at byte 0 /
-        position 0 with an all-scratch table row)."""
-        import jax.numpy as jnp
-        if page_tables is None:
-            if self._identity_tables is None:
-                raise ValueError("undersized pool needs scheduler page "
-                                 "tables")
-            page_tables = self._identity_tables
-        with span("decode.dispatch"):
-            self.cache, nxt, logits, _ = self._step(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos),
-                jnp.asarray(np.asarray(page_tables, np.int32)))
-        with span("decode.fetch"):
-            out = np.asarray(nxt)
-        return out, logits
+    # one step: the softmax decoder's own dispatch and fetch, by call
+    # (``eva_step`` returns ``(cache, next_tokens, logits, further)``:
+    # the tokens are the one fetch, and nothing is packed beside them)
+    _tokens_out = 0
+    n_dispatched = 0
+    dispatch_step = TransformerDecoder.dispatch_step
+    fetch_step = TransformerDecoder.fetch_step
+    step_logits = TransformerDecoder.step_logits
+    step = TransformerDecoder.step
+    _warm_step = TransformerDecoder._warm_step
 
-    def step(self, tokens: np.ndarray, pos: np.ndarray,
-             page_tables=None) -> np.ndarray:
-        return self.step_logits(tokens, pos, page_tables)[0]
+    def _read_fetched(self, fetched: np.ndarray, pos, attrs
+                      ) -> np.ndarray:
+        return fetched
 
     def compact(self, window_pages, summary_pages) -> None:
         """A finished window's rows (in ``window_pages``, all
@@ -263,9 +255,8 @@ class EvaByteDecoder:
         """Compile the step, the compaction and every tile bucket (what
         they write lands on the scratch page). Returns the compile
         count."""
-        zeros_t = np.zeros(self.n_slots, np.int32)
-        self.step(zeros_t, zeros_t.copy(),
-                  np.zeros((self.n_slots, self.pages_per_slot), np.int32))
+        self._warm_step(
+            np.zeros((self.n_slots, self.pages_per_slot), np.int32))
         scratch = np.zeros(self.pages_per_slot, np.int32)
         for bucket in self.prompt_buckets():
             # the full window's bucket also runs the compaction

@@ -32,7 +32,6 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from mmlspark_tpu.core.profiling import span
 from mmlspark_tpu.serving.decode import TransformerDecoder
 
 
@@ -118,7 +117,15 @@ class HybridDecoder:
     _table_for = TransformerDecoder._table_for
     placement = TransformerDecoder.placement
     prefill = TransformerDecoder.prefill
+    # one step: the shared dispatch and fetch (``hybrid_step`` returns
+    # ``(cache, fetched, logits, next_tokens)``)
+    _tokens_out = 2
+    n_dispatched = 0
+    dispatch_step = TransformerDecoder.dispatch_step
+    fetch_step = TransformerDecoder.fetch_step
+    step_logits = TransformerDecoder.step_logits
     step = TransformerDecoder.step
+    _warm_step = TransformerDecoder._warm_step
 
     def prefill_facts(self, prompt_len: int) -> Dict[str, int]:
         """What a ``decode.prefill`` span carries for this kind."""
@@ -158,34 +165,20 @@ class HybridDecoder:
         self.n_state_resets += 1
         return int(nxt), logits
 
-    def step_logits(self, tokens: np.ndarray, pos: np.ndarray,
-                    page_tables=None) -> "tuple[np.ndarray, Any]":
-        """One token for every slot (free slots ride along at token 0 /
-        position 0 with an all-scratch table row). The ``decode.fetch``
-        span carries what the experts received: ``expert_routings`` (a
-        held expert's routings, the layers summed), ``expert_load_max``
-        and ``experts_touched`` ((layer, held expert) pairs that
-        received any)."""
-        import jax.numpy as jnp
-        if page_tables is None:
-            if self._identity_tables is None:
-                raise ValueError("undersized pool needs scheduler page "
-                                 "tables")
-            page_tables = self._identity_tables
-        with span("decode.dispatch"):
-            self.cache, fetched, logits = self._step(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(pos),
-                jnp.asarray(np.asarray(page_tables, np.int32)))
-        with span("decode.fetch") as sp:
-            # one copy back: [next tokens | routings | touched]
-            fetched = np.asarray(fetched)
-            out, routings = np.split(fetched[:-1], [self.n_slots])
-            self.expert_routings += routings
-            sp.attrs = {"expert_routings": routings.tolist(),
-                        "expert_load_max": int(routings.max()),
-                        "experts_touched": int(fetched[-1])}
-        return out, logits
+    def _read_fetched(self, fetched: np.ndarray, pos, attrs
+                      ) -> np.ndarray:
+        """A step's one copy back, ``[next tokens | routings |
+        touched]`` -> the tokens; the ``decode.fetch`` span carries
+        what the experts received: ``expert_routings`` (a held expert's
+        routings, the layers summed), ``expert_load_max`` and
+        ``experts_touched`` ((layer, held expert) pairs that received
+        any)."""
+        out, routings = np.split(fetched[:-1], [self.n_slots])
+        self.expert_routings += routings
+        attrs.update(expert_routings=routings.tolist(),
+                     expert_load_max=int(routings.max()),
+                     experts_touched=int(fetched[-1]))
+        return out
 
     def n_compiles(self) -> int:
         return int(self._prefill._cache_size() + self._step._cache_size())
@@ -194,9 +187,8 @@ class HybridDecoder:
         """Compile the step and the tile (what they write lands on the
         scratch page and in slot 0's state, which the slot's first
         request resets). Returns the compile count: two."""
-        zeros_t = np.zeros(self.n_slots, np.int32)
         scratch = np.zeros((self.n_slots, self.pages_per_slot), np.int32)
-        self.step(zeros_t, zeros_t.copy(), scratch)
+        self._warm_step(scratch)
         self.prefill(0, np.zeros(1, np.int32), scratch[0])
         self.n_state_resets = 0
         self.expert_routings[:] = 0

@@ -504,9 +504,16 @@ class TestGraniteHybridPrograms:
         temporary the size of a state), and the grouped-query paged
         kernel is the one Mosaic call."""
         GH, cfg, params, cache, i32 = shapes
-        c = GH.build_hybrid_step(cfg, PAGE, attn_impl="pallas").lower(
+        low = GH.build_hybrid_step(cfg, PAGE, attn_impl="pallas").lower(
             params, cache, i32(self.N_SLOTS), i32(self.N_SLOTS),
-            i32(self.N_SLOTS, self.MAX_LEN // PAGE)).compile()
+            i32(self.N_SLOTS, self.MAX_LEN // PAGE))
+        # the one fetch packs counters behind the tokens; the tokens
+        # alone are an output too, which the loop hands to the next
+        # step as it lies on the device (ISSUE 36)
+        _, fetched, _, tokens = low.out_info
+        assert fetched.shape == (self.N_SLOTS + len(cfg.experts_held) + 1,)
+        assert (tokens.shape, tokens.dtype) == ((self.N_SLOTS,), jnp.int32)
+        c = low.compile()
         mem = c.memory_analysis()
         assert mem.alias_size_in_bytes == self._nbytes(cache)
         assert mem.temp_size_in_bytes < 64 * 2**20
@@ -599,9 +606,15 @@ class TestOuroPrograms:
         assert 8.07e9 < self._nbytes(cache) < 8.09e9
         assert cache["k"][0].shape == (4 * 321, 16, 16, 128)
         n = sv["n_slots"]
-        c = T.build_paged_decode_step(
+        low = T.build_paged_decode_step(
             cfg, n, sv["page_size"], pps, attn_impl="pallas").lower(
-            params, cache, i32(n), i32(n), i32(n, pps)).compile()
+            params, cache, i32(n), i32(n), i32(n, pps))
+        # [tokens | exit passes] is the one fetch; the tokens alone are
+        # an output too, the next step's input as it lies on the device
+        _, fetched, _, tokens = low.out_info
+        assert fetched.shape == (2 * n,)
+        assert (tokens.shape, tokens.dtype) == ((n,), jnp.int32)
+        c = low.compile()
         self._check(c, cfg, params, cache, 256 * 2**20)
         # one loop body: a layer's kernel once in the text, run 4 times
         assert _n_mosaic(c) == cfg.n_layers

@@ -87,7 +87,8 @@ def run():
 
 
 @pytest.mark.parametrize("what", ["phases_present", "chain", "prefills",
-                                  "stats_loop", "pool"])
+                                  "stats_loop", "pool", "ahead",
+                                  "in_turn"])
 def test_scheduler_run_leaves_whole_passes(run, what):
     passes = run["passes"]
     stepped = [sp for sp in passes if sp.attrs["step"] is not None]
@@ -99,8 +100,13 @@ def test_scheduler_run_leaves_whole_passes(run, what):
         # cache row (tests/test_serving_decode.py TestTwoRowKinds): the
         # softmax block never opens it
         assert seen == set(LOOP_PHASES) - {"compact"}
-        for sp in stepped:
-            assert set(STEP_PHASES) <= set(sp.view["phases_ms"])
+        # a pass that dispatched a step fetched and emitted one too, or
+        # left its step in flight for the next pass to queue behind
+        for sp, nxt in zip(stepped, stepped[1:] + [None]):
+            assert set(STEP_PHASES[:3]) <= set(sp.view["phases_ms"])
+            if not set(STEP_PHASES) <= set(sp.view["phases_ms"]):
+                assert not sp.view["ahead"] and nxt.view["ahead"]
+                assert nxt.view["fetched"] == sp.view["seq"]
         # every pass under a trace id of its own, steps in sequence
         assert len({sp.trace_id for sp in passes}) == len(passes)
         steps = [sp.attrs["step"] for sp in stepped]
@@ -139,7 +145,12 @@ def test_scheduler_run_leaves_whole_passes(run, what):
         n = {k: sum(1 for sp in passes if k in sp.view["phases_ms"])
              for k in LOOP_PHASES if k != "prefill"}
         assert {k: loop[k]["n"] for k in n} == n
-        assert loop["dispatch"]["n"] == run["stats"]["n_steps"]
+        # every step dispatched was fetched, in a later pass or its own
+        assert loop["dispatch"]["n"] == loop["fetch"]["n"] \
+            == run["stats"]["n_steps"]
+        assert run["stats"]["n_steps_ahead"] == sum(
+            sp.view["ahead"] for sp in stepped)
+        assert run["stats"]["n_tokens_discarded"] == 0
         assert loop["prefill"]["n"] == run["stats"]["n_prefills"] == 6
         # prefill_s and the prefill phase are the same clock reads
         assert loop["prefill"]["s"] == pytest.approx(
@@ -147,10 +158,36 @@ def test_scheduler_run_leaves_whole_passes(run, what):
         for k in LOOP_PHASES:
             ms = sum(sp.view["phases_ms"].get(k, 0.0) for sp in passes)
             assert loop[k]["s"] == pytest.approx(ms * 1e-3, abs=1e-5)
+    elif what == "ahead":
+        # four slots taken by greedy requests: the loop queues a step
+        # behind the one in flight, and fetches that one after
+        ahead = [sp for sp in stepped if sp.view["ahead"]]
+        assert ahead and len(ahead) == run["stats"]["n_steps_ahead"]
+        for sp in ahead:
+            by_name = {name[7:]: (a, b)
+                       for name, a, b, _ in sp.attrs["phases"]}
+            assert by_name["dispatch"][1] <= by_name["fetch"][0]
+            assert sp.view["fetched"] == sp.view["seq"] - 1
+            assert sp.view["active"] == sp.view["emitted"] == 4
+    elif what == "in_turn":
+        # with a free slot, or a request whose token in flight is its
+        # last, a pass fetches the step it dispatched, or only fetches
+        turn = [sp for sp in stepped if not sp.view["ahead"]
+                and "fetch" in sp.view["phases_ms"]]
+        assert turn and all(sp.view["fetched"] == sp.view["seq"]
+                            for sp in turn)
+        assert any(sp.view["active"] < 4 for sp in turn)
+        only = [sp for sp in passes if sp.attrs["step"] is None
+                and "fetch" in sp.view["phases_ms"]]
+        assert only and all(
+            set(sp.view["phases_ms"]) == {"admit", "prepare", "fetch",
+                                          "emit"}
+            for sp in only)
     else:
         for sp in stepped:
             at = sp.view
-            assert 1 <= at["active"] <= 4 and at["emitted"] == at["active"]
+            assert 1 <= at["active"] <= 4
+            assert at.get("emitted", at["active"]) == at["active"]
             assert at["n_pages"] == run["stats"]["pages"]["n_pages"]
             assert at["active"] <= at["pages_in_use"] <= at["n_pages"]
 

@@ -191,6 +191,50 @@ def test_prefill_then_decode_matches_reference(served, prompt_len):
     assert sched.pages.n_free == dec.n_pages - 1
 
 
+def test_a_window_filled_with_a_step_queued_is_compacted_in_between(
+        params):
+    """Both slots taken and greedy: the loop queues a step behind the
+    one in flight, except where that one fills a slot's window. That
+    pass only fetches, emits and compacts, the next dispatches alone,
+    and every served byte is the reference's best at its position
+    (ONE full forward over prompt + served bytes)."""
+    from mmlspark_tpu.core.tracing import Tracer
+    from mmlspark_tpu.serving.decode import pass_view
+    dec = decoder_for(params, CFG, n_slots=2, max_len=128, page_size=4,
+                      attn_impl="dense")
+    tracer = Tracer()
+    sched = DecodeScheduler(dec, tracer=tracer).start()
+    prompts = [TOKENS[:20], TOKENS[40:50]]
+    try:
+        outs = _generate(sched, prompts, [30, 30])
+        stats = sched.stats()
+    finally:
+        sched.stop()
+    for prompt, out in zip(prompts, outs):
+        seq = np.concatenate([prompt, np.asarray(out["tokens"], np.int32)])
+        want = RE.logits(M, SEED, seq[:-1])[len(prompt) - 1:, :320]
+        served = want[np.arange(30), out["tokens"]]
+        assert (want.max(axis=-1) - served).max() < TOL
+    views = [pass_view(sp.attrs["phases"])
+             for sp in tracer.recorder.scan("decode.pass")]
+    # positions 31 are written by steps 12 and 22: two compactions by
+    # the loop, each in a pass that dispatched nothing, each followed
+    # by a step that ran behind no other
+    compacting = [i for i, v in enumerate(views)
+                  if "compact" in v["phases_ms"]]
+    assert len(compacting) == 2 and stats["n_compactions"] == 2
+    for i in compacting:
+        assert sorted(views[i]["phases_ms"]) == [
+            "admit", "compact", "emit", "fetch", "prepare"]
+        assert views[i + 1]["ahead"] is False
+        assert "fetch" not in views[i + 1]["phases_ms"]
+        assert views[i + 2]["ahead"] is True
+    # behind no other: the first step and the one after each compaction
+    assert stats["n_steps"] == 29 and stats["n_steps_ahead"] == 29 - 3
+    assert stats["n_tokens_discarded"] == 0
+    assert sched.pages.n_free == dec.n_pages - 1
+
+
 def test_three_requests_together_give_what_each_gives_alone(served):
     dec, sched, _ = served
     prompts = [TOKENS[:7], TOKENS[10:55], TOKENS[20:110]]

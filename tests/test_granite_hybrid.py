@@ -206,6 +206,33 @@ def test_padded_positions_leave_state_and_tail_bit_identical(params):
             assert np.array_equal(np.asarray(x), np.asarray(y))
 
 
+def test_a_step_takes_the_tokens_of_the_step_before_on_the_device(
+        params):
+    """The step's one fetch packs counters behind its tokens; the
+    tokens are an output of their own too, and a step dispatched on
+    them before they are fetched gives what a step on the fetched
+    tokens gives (two decoders, the same prompt in slot 1)."""
+    a, b = decoder(params), decoder(params)
+    tok = np.zeros(N_SLOTS, np.int32)
+    at = np.zeros(N_SLOTS, np.int32)
+    for dec in (a, b):
+        tok[1] = dec.prefill(1, TOKENS[:9])
+    at[1] = 9
+    first = a.dispatch_step(tok, at)
+    second = a.dispatch_step(first, at + (at > 0))      # not fetched yet
+    assert (np.asarray(first.tokens)
+            == np.asarray(first.fetched)[:N_SLOTS]).all()
+    out1, _ = a.fetch_step(first)
+    out2, logits2 = a.fetch_step(second)
+    want1, _ = b.step_logits(tok, at)
+    want2, want_logits2 = b.step_logits(want1, at + (at > 0))
+    assert (out1 == want1).all() and (out2 == want2).all()
+    assert np.abs(np.asarray(logits2) - np.asarray(want_logits2)).max() \
+        == 0.0
+    assert (a.expert_routings == b.expert_routings).all()
+    assert (first.seq, second.seq) == (1, 2) and a.n_compiles() == 2
+
+
 def test_a_reused_slot_starts_from_a_zero_state(params, reference):
     """A request's first tile resets whatever the slot's last request
     left: the second request in the slot reads as if it were alone."""

@@ -197,6 +197,33 @@ def test_a_threshold_under_one_refuses(build):
 # built refuses by name
 
 
+def test_a_step_takes_the_tokens_of_the_step_before_on_the_device(
+        params):
+    """A looped step's one fetch packs the exit passes behind its
+    tokens; the tokens are an output of their own too, and a step
+    dispatched on them before they are fetched gives what a step on
+    the fetched tokens gives."""
+    a, b = decoder(params), decoder(params)
+    tok = np.zeros(N_SLOTS, np.int32)
+    at = np.zeros(N_SLOTS, np.int32)
+    for dec in (a, b):
+        tok[1] = dec.prefill(1, TOKENS[:9])
+    at[1] = 9
+    first = a.dispatch_step(tok, at)
+    second = a.dispatch_step(first, at + (at > 0))      # not fetched yet
+    assert (np.asarray(first.tokens)
+            == np.asarray(first.fetched)[:N_SLOTS]).all()
+    out1, _ = a.fetch_step(first)
+    out2, logits2 = a.fetch_step(second)
+    want1, _ = b.step_logits(tok, at)
+    want2, want_logits2 = b.step_logits(want1, at + (at > 0))
+    assert (out1 == want1).all() and (out2 == want2).all()
+    assert np.abs(np.asarray(logits2) - np.asarray(want_logits2)).max() \
+        == 0.0
+    assert (a.step_exit_pass == b.step_exit_pass).all()
+    assert a._step._cache_size() == 1
+
+
 def test_one_loop_with_the_default_recipe_is_the_unlooped_program():
     cfg = T.TransformerConfig(vocab=64, d_model=16, n_heads=2, d_head=8,
                               d_ff=32, n_stages=1, layers_per_stage=1)

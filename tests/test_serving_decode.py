@@ -2016,3 +2016,311 @@ def test_prepare_counts_the_table_entries(make, prompt_len, n_new, want):
     # the kernel's own count at the same rows: the last row's index
     rows = [v["summary_rows"] + v["window_rows"] for v in steps]
     assert [int(paged_walk(r - 1, dec.page_size)) for r in rows] == want
+
+
+# ---------------------------------------------------------------------------
+# a step in flight (ISSUE 36): with every slot taken and greedy the loop
+# queues step N+1 on step N's tokens as they lie on the device, and only
+# then fetches step N. The yardstick is the same requests served in
+# today's order (``step_logits``: dispatch and fetch in a row) by the
+# same decoder.
+
+
+class _InTurn(DecodeScheduler):
+    """Today's order in every pass."""
+
+    def _may_run_ahead(self, flight):
+        return False
+
+
+class _Stream:
+    """The slice of the frontend's token stream the scheduler touches."""
+
+    closed = False
+    t_first = 0.0
+
+    def __init__(self):
+        self.tokens, self.final = [], None
+
+    def emit(self, event: bytes) -> None:
+        self.tokens.append(json.loads(event[6:])["token"])
+
+    def finish(self, event: bytes) -> None:
+        self.final = json.loads(event[6:])
+
+
+def _tiny_ouro():
+    from mmlspark_tpu.serving.decode import decoder_for
+    cfg = T.TransformerConfig.from_hf(
+        {"model_type": "ouro", "vocab_size": 64, "hidden_size": 16,
+         "num_attention_heads": 2, "num_key_value_heads": 2,
+         "head_dim": 8, "intermediate_size": 24, "num_hidden_layers": 2,
+         "total_ut_steps": 3, "early_exit_threshold": 1,
+         "rope_theta": 1000000, "rms_norm_eps": 1e-6,
+         "hidden_act": "silu", "rope_scaling": None,
+         "sliding_window": None, "tie_word_embeddings": False},
+        dtype="float32")
+    return decoder_for(T.init_params(cfg, seed=5), cfg, n_slots=2,
+                       max_len=64, page_size=4, attn_impl="dense")
+
+
+KINDS = {
+    "tiny": lambda: _decoder(n_slots=2, max_len=64, page_size=4),
+    "tiny-evabyte": lambda: TestTwoRowKinds._decoder(),
+    "tiny-granite": lambda: TestStateASlot._decoder(n_slots=2),
+    "tiny-ouro": _tiny_ouro,
+}
+_KIND_DECODERS = {}
+
+
+def _kind(kind: str):
+    """One two-slot decoder a block kind, compiled once for the cases
+    below (every case brings its own scheduler, pool and tables)."""
+    if kind not in _KIND_DECODERS:
+        _KIND_DECODERS[kind] = KINDS[kind]()
+    return _KIND_DECODERS[kind]
+
+
+@pytest.fixture(params=list(KINDS))
+def kind_decoder(request):
+    return _kind(request.param)
+
+
+def _serve_all(sched, payloads, streams=(), timeout=60):
+    """``payloads`` through ``sched`` from start to stop: the requests
+    (``streams`` names those that stream) and the final stats."""
+    ps = [_Pending(p, f"f{i}") for i, p in enumerate(payloads)]
+    for i in streams:
+        ps[i].stream = _Stream()
+    sched.start()
+    try:
+        for p in ps:
+            sched.submit(p)
+        for p in ps:
+            assert p.event.wait(timeout), "stranded"
+    finally:
+        sched.stop()
+    return ps, sched.stats()
+
+
+def _mix(rng, shapes):
+    return [{"prompt": _prompt(rng, n), "max_new_tokens": m}
+            for n, m in shapes]
+
+
+def _nothing_left(sched, stats) -> bool:
+    return (stats["slots_free"] == stats["n_slots"] and _pages_idle(sched)
+            and sched._flight is None)
+
+
+class TestStepInFlight:
+
+    def test_ahead_serves_the_tokens_of_todays_order(self, kind_decoder):
+        """Two slots, five greedy requests whose ends and admissions
+        fall among the steps (and, with a window of 16, whose lanes
+        cross it while a step is queued): every request's tokens are
+        those of the same requests served in today's order."""
+        payloads = _mix(np.random.default_rng(7),
+                        ((10, 14), (5, 9), (3, 12), (12, 6), (7, 11)))
+        want, in_turn = _serve_all(_InTurn(kind_decoder), payloads)
+        sched = DecodeScheduler(kind_decoder)
+        got, stats = _serve_all(sched, payloads)
+        for a, b in zip(got, want):
+            assert a.status == b.status == 200
+            assert json.loads(a.reply) == json.loads(b.reply)
+        assert in_turn["n_steps_ahead"] == 0
+        assert 0 < stats["n_steps_ahead"] < stats["n_steps"]
+        assert stats["n_tokens_discarded"] == 0
+        assert stats["n_tokens"] == in_turn["n_tokens"] == sum(
+            p["max_new_tokens"] for p in payloads)
+        assert _nothing_left(sched, stats)
+
+    @pytest.mark.parametrize("third", [9, 16])
+    def test_an_eos_with_a_step_in_flight_costs_one_lane(
+            self, kind_decoder, third):
+        """An ``eos_id`` token is seen at the emit, with the next step
+        queued: that step's lane for the request is discarded (not
+        streamed, not in the final event, not in ``n_tokens``), slot
+        and pages come back, and the slot's next request (a state a
+        slot: reset by its first tile) reads as in today's order. That
+        request is admitted with the queued step not yet fetched and
+        takes no part in it: a prompt of exactly one window (``third``
+        16) leaves it at a window's edge with nothing to compact."""
+        dec = kind_decoder
+        # A and B start together; step j's emit hands A its token j.
+        # Windows of 16 fill at steps 6 (A) and 10 (B), whose passes
+        # only fetch: the EOS is looked for at steps that run ahead
+        # (tiny models repeat themselves: prompts are drawn until one
+        # of A's tokens there is nobody else's and not A's before)
+        for seed in range(11, 27):
+            payloads = _mix(np.random.default_rng(seed),
+                            ((10, 16), (6, 30), (third, 7)))
+            probe, _ = _serve_all(_InTurn(dec), payloads)
+            toks = [json.loads(p.reply)["tokens"] for p in probe]
+            k = next((k for k in (8, 9, 12, 13, 14) if toks[0][k] not in
+                      toks[0][:k] + toks[1] + toks[2]), None)
+            if k is not None:
+                break
+        assert k is not None
+        dec.eos_id = toks[0][k]
+        try:
+            # (the decoder counts its compactions over every scheduler)
+            n0 = dec.n_compactions
+            want, in_turn = _serve_all(_InTurn(dec), payloads)
+            n1 = in_turn["n_compactions"]
+            sched = DecodeScheduler(dec)
+            got, stats = _serve_all(sched, payloads, streams=(0, 2))
+        finally:
+            dec.eos_id = None
+        first = json.loads(got[0].reply)
+        assert first["finish_reason"] == "eos"
+        assert first["tokens"] == toks[0][:k + 1]
+        for a, b in zip(got, want):
+            assert json.loads(a.reply) == json.loads(b.reply)
+        for p in (got[0], got[2]):
+            out = json.loads(p.reply)
+            assert p.stream.tokens == p.stream.final["tokens"] \
+                == out["tokens"]
+        assert stats["n_tokens_discarded"] == 1
+        assert stats["n_tokens"] == sum(
+            json.loads(p.reply)["n_tokens"] for p in got)
+        assert stats["n_steps_ahead"] > k - 4
+        assert stats["n_compactions"] - n1 == n1 - n0
+        assert stats["n_step_faults"] == 0
+        assert _nothing_left(sched, stats)
+
+    @pytest.mark.parametrize("how", ["cancelled", "disconnected"])
+    def test_a_request_that_leaves_with_a_step_in_flight(self, how):
+        """A cancel or a closed stream that lands between a step's
+        dispatch and the fetch before it: the request retires at that
+        emit with the token it was owed, and the step already queued
+        carries one lane more, discarded."""
+        from mmlspark_tpu.serving.decode import StepInFlight
+        dec = _kind("tiny")
+        payloads = _mix(np.random.default_rng(13), ((8, 20), (5, 20)))
+        want, _ = _serve_all(_InTurn(dec), payloads)
+        sched = DecodeScheduler(dec)
+        inner, n_ahead = dec.dispatch_step, [0]
+
+        def dispatch_step(tokens, pos, tables=None):
+            step = inner(tokens, pos, tables)
+            n_ahead[0] += isinstance(tokens, StepInFlight)
+            if n_ahead[0] == 5 and isinstance(tokens, StepInFlight):
+                if how == "cancelled":
+                    sched.cancel("f0")
+                else:
+                    sched._by_rid["f0"].stream.closed = True
+            return step
+
+        dec.dispatch_step = dispatch_step
+        try:
+            got, stats = _serve_all(sched, payloads, streams=(0, 1))
+        finally:
+            del dec.dispatch_step
+        gone, stays = (json.loads(p.reply) for p in got)
+        assert gone["finish_reason"] == how
+        assert got[0].status == (200 if how == "cancelled" else 500)
+        # the fifth step dispatched ahead is step 6, behind step 5: the
+        # prefill's token and one from each of steps 1..5
+        assert gone["tokens"] == json.loads(want[0].reply)["tokens"][:6]
+        # (a closed stream takes no event: the token of the emit that
+        # found it closed is in the reply alone, as in today's order)
+        assert got[0].stream.tokens == gone["tokens"][
+            :6 if how == "cancelled" else 5]
+        assert stays == json.loads(want[1].reply)
+        assert got[1].stream.tokens == stays["tokens"]
+        assert stats["n_tokens_discarded"] == 1
+        assert stats["n_tokens"] == gone["n_tokens"] + stays["n_tokens"]
+        assert _nothing_left(sched, stats)
+
+    @pytest.mark.parametrize("kind", ["tiny", "tiny-granite"])
+    def test_a_step_that_fails_at_its_fetch_with_another_queued(
+            self, kind):
+        """Both steps are lost as the one fault they are: the slots'
+        requests get 500s, no slot or page is lost, and the loop
+        serves the next request as today's order does."""
+        dec = _kind(kind)
+        payloads = _mix(np.random.default_rng(17),
+                        ((8, 20), (5, 20), (6, 9)))
+        want, _ = _serve_all(_InTurn(dec), payloads[2:])
+        sched = DecodeScheduler(dec)
+        inner, queued = dec.fetch_step, []
+
+        def fetch_step(step):
+            if dec.n_dispatched > step.seq:
+                queued.append(step.seq)
+                if len(queued) == 4:
+                    raise RuntimeError("scripted fetch fault")
+            return inner(step)
+
+        dec.fetch_step = fetch_step
+        try:
+            got, stats = _serve_all(sched, payloads)
+        finally:
+            del dec.fetch_step
+        for p in got[:2]:
+            out = json.loads(p.reply)
+            assert p.status == 500 and out["finish_reason"] == "error"
+            assert "scripted fetch fault" in out["error"]
+            assert out["n_tokens"] == 4      # the prefill, steps 1..3
+        assert got[2].status == 200
+        assert json.loads(got[2].reply) == json.loads(want[0].reply)
+        assert stats["n_step_faults"] == 1
+        assert stats["releases"] == {"error": 2, "length": 1}
+        assert _nothing_left(sched, stats)
+
+    @pytest.mark.parametrize("why", ["free_slot", "sampler",
+                                     "speculative", "last_token"])
+    def test_the_rule_keeps_todays_order(self, why):
+        """A free slot, a sampler in a slot, a speculative cohort and
+        a slot whose token in flight is its last each give passes in
+        today's order: no step is dispatched behind another."""
+        from mmlspark_tpu.core.tracing import Tracer
+        from mmlspark_tpu.serving.decode import pass_view
+        rng = np.random.default_rng(19)
+        if why == "speculative":
+            dec = _spec_setup(n_slots=2)[2]
+        else:
+            dec = _kind("tiny")
+        payloads = _mix(rng, ((6, 8), (4, 8)))
+        if why == "free_slot":
+            payloads = payloads[:1]
+        elif why == "sampler":
+            payloads[1].update(temperature=0.8, seed=3)
+        elif why == "last_token":
+            # the prefill's token and one step's: that step is left in
+            # flight by the pass that dispatched it and fetched alone
+            payloads = _mix(rng, ((6, 2), (4, 2)))
+        tracer = Tracer()
+        sched = DecodeScheduler(dec, tracer=tracer)
+        got, stats = _serve_all(sched, payloads)
+        assert all(p.status == 200 for p in got)
+        assert stats["n_steps_ahead"] == 0
+        assert stats["n_tokens_discarded"] == 0
+        views = [pass_view(sp.attrs["phases"])
+                 for sp in tracer.recorder.scan("decode.pass")]
+        assert not any(v.get("ahead") for v in views)
+        if why == "speculative":
+            assert stats["speculative"]["rounds"] > 0
+        elif why == "last_token":
+            assert stats["n_steps"] == 1
+            assert [sorted(v["phases_ms"]) for v in views
+                    if "fetch" in v["phases_ms"]] \
+                == [["admit", "emit", "fetch", "prepare"]]
+        else:
+            assert stats["n_steps"] == 7
+            assert all(v["fetched"] == v["seq"] for v in views
+                       if "dispatch" in v["phases_ms"])
+        assert _nothing_left(sched, stats)
+
+    def test_a_budget_of_m_tokens_runs_m_less_two_steps_ahead(self):
+        """Two slots, two requests of five tokens: the prefill's, then
+        four steps, of which the first is left in flight, the next
+        three queue behind one, and the last is fetched alone."""
+        dec = _kind("tiny")
+        sched = DecodeScheduler(dec)
+        _, stats = _serve_all(sched, _mix(np.random.default_rng(23),
+                                          ((6, 5), (4, 5))))
+        assert (stats["n_steps"], stats["n_steps_ahead"]) == (4, 3)
+        assert stats["loop"]["dispatch"]["n"] == 4
+        assert stats["loop"]["fetch"]["n"] == 4

@@ -7,7 +7,9 @@ Modules``), the three largest programs' op time by named scope (an
 op's scope is the stat ``tf_op`` of its event METADATA, which
 ``jax.profiler.ProfileData`` does not show, hence ``xplane_pb2``) and
 by op kind, the heaviest ``copy`` / ``copy-done`` instructions, and
-the mean of every ``decode.*`` host span::
+the mean of every ``decode.*`` host span, and the gaps the device
+leaves between two calls of its largest program with the ``decode.*``
+host span that was open in the middle of each::
 
     python tools/trace_scopes.py .bench_trace/<cell>/**/*.xplane.pb
 
@@ -49,6 +51,8 @@ def main(paths):
     kind_scope = collections.defaultdict(Counter)
     heaviest = collections.defaultdict(Counter)
     host_t, host_n = Counter(), Counter()
+    gaps = collections.defaultdict(list)    # program -> (ps, midpoint)
+    spans = []                              # host (start, end, name)
     for path in paths:
         space = xplane_pb2.XSpace()
         with open(path, "rb") as f:
@@ -60,10 +64,11 @@ def main(paths):
                 for ev in line.events] for line in plane.lines}
             if not re.match(r"^/device:TPU:\d+$", plane.name):
                 for evs in events.values():
-                    for _, dur, md in evs:
+                    for start, dur, md in evs:
                         if md.name.startswith("decode."):
                             host_t[md.name] += dur
                             host_n[md.name] += 1
+                            spans.append((start, start + dur, md.name))
                 continue
             tf_op_id = next((k for k, v in plane.stat_metadata.items()
                              if v.name == "tf_op"), None)
@@ -73,6 +78,9 @@ def main(paths):
             for _, dur, name in mods:
                 mod_t[name] += dur
                 mod_n[name] += 1
+            for (s0, d0, n0), (s1, _, n1) in zip(mods, mods[1:]):
+                if n0 == n1:     # nothing else ran between the two calls
+                    gaps[n0].append((s1 - s0 - d0, (s0 + d0 + s1) // 2))
             for start, dur, md in events.get("XLA Ops", []):
                 i = bisect.bisect_right(starts, start) - 1
                 inside = i >= 0 and start < mods[i][0] + mods[i][1]
@@ -111,6 +119,21 @@ def main(paths):
         for kind in ("copy", "copy-done"):
             for name, t in heaviest[mod, kind].most_common(2):
                 print(f"  heaviest {kind} {t / 1e12:.4f} s: {name}")
+    for mod, _ in mod_t.most_common(1):
+        g = sorted(gaps[mod])
+        if g:
+            # the host span open at a gap's midpoint, the shortest if
+            # several are (one clock: both planes are the profiler's)
+            cover = Counter()
+            for _, mid in g:
+                over = [(e - b, n) for b, e, n in spans if b <= mid < e]
+                cover[min(over)[1] if over else "(no decode.* span)"] += 1
+            ms = [x[0] / 1e9 for x in g]
+            print(f"{mod}: {len(ms)} gaps between two calls in a row, ms: "
+                  f"median {ms[len(ms) // 2]:.3f} mean "
+                  f"{sum(ms) / len(ms):.3f} p90 {ms[len(ms) * 9 // 10]:.3f} "
+                  f"max {ms[-1]:.3f}; host span open in the middle: "
+                  + ", ".join(f"{n} {c}" for n, c in cover.most_common(5)))
     print("host spans: calls, mean ms")
     for name, t in host_t.most_common():
         print(f"  {name:20s} {host_n[name]:6d} {t / host_n[name] / 1e9:8.3f}")
