@@ -270,12 +270,13 @@ class FlightRecorder:
     at capture time, not kept).
 
     The default of 16,384 spans holds a whole benchmark run of the
-    decode loop with room to spare: some 3,500 ``decode.pass`` spans
-    (each under a trace id of its own, so they spread over the stripes)
-    beside the spans of some 75 requests, about 270 a stripe of 1,024.
-    A pass span with its phases is about 3.5 KB, a request's child
-    span about 0.9 KB: such a run leaves some 13 MB of host memory in
-    the ring, and a ring full of passes would hold under 60 MB."""
+    decode loop: some 7,000 ``decode.pass`` spans where a pass is a
+    step of 8.5 ms (each under a trace id of its own, so they spread
+    over the stripes) beside the spans of some 110 requests, about 470
+    a stripe of 1,024. A pass span with its phases is about 3.5 KB, a
+    request's child span about 0.9 KB: such a run leaves some 26 MB of
+    host memory in the ring, and a ring full of passes would hold under
+    60 MB."""
 
     def __init__(self, capacity: int = 16384, stripes: int = 16):
         self.stripes = max(int(stripes), 1)

@@ -1282,6 +1282,23 @@ _MAX_TIMELINE_SPANS = 128
 #: span's ``phases_ms``
 LOOP_PHASES = ("admit", "prefill", "prepare", "dispatch", "fetch",
                "emit", "compact", "idle")
+#: the order a pass ran in, ``order`` on its ``decode.prepare``
+#: (:meth:`DecodeScheduler._run_step`): its step went out behind a step
+#: in flight; was left in flight with nothing before it; today's order
+#: (prepare, dispatch, fetch, emit); the pass only fetched the step in
+#: flight; a speculative round
+PASS_ORDERS = ("ahead", "start", "in_turn", "fetch_only", "spec_round")
+#: what held a pass that is not ``ahead`` / ``start`` to today's order,
+#: ``held_by`` on its ``decode.prepare`` and the keys of
+#: ``/decode/stats`` -> ``held_by``: the first condition of
+#: :meth:`DecodeScheduler._may_run_ahead` that said no, in its order
+HELD_BY = ("free_slot", "sampler", "speculative", "riders_changed",
+           "last_token", "lane_full", "window_fill", "cancelled",
+           "stream_closed", "deadline", "no_pages")
+#: the phases of a pass in which the device can be left with nothing of
+#: the loop's queued (:meth:`DecodeScheduler._record_pass`): the keys of
+#: a pass's ``starved_ms`` and of ``/decode/stats`` -> ``loop.starved``
+STARVED_PHASES = ("admit", "prepare", "dispatch", "emit")
 #: the route ``decode.pass`` spans are captured under, and how many
 #: times the running median of the passes that ran a step a pass must
 #: last to be retained as slow (``GET /traces``)
@@ -1295,7 +1312,9 @@ def pass_view(phases) -> Dict[str, Any]:
     as ``{"phases_ms": {phase: milliseconds}, "prefills": [one entry a
     ``decode.prefill``: its attributes, ``start_ms`` into the pass and
     ``ms``], **the other phases' attributes}`` (``admitted``,
-    ``active``, ``pages_in_use``, ``n_pages``, ``emitted``; of the
+    ``active``, ``pages_in_use``, ``n_pages``, ``emitted``; ``order``,
+    the order the pass ran in (``PASS_ORDERS``), and in a pass held to
+    today's order ``held_by``, the rule's word (``HELD_BY``); of the
     step the pass dispatched ``seq`` and ``ahead``, whether a step was
     in flight as it went out, and ``fetched``, the sequence number of
     the step the pass's fetch waited for: ``seq`` less one in a pass
@@ -1314,6 +1333,19 @@ def pass_view(phases) -> Dict[str, Any]:
             view.update(attrs)
     view.pop("traces", None)       # the pass carries them itself
     return view
+
+
+def stall_word(ns: int, cpu_ns: int, proc_ns: int) -> str:
+    """Who ran in a pass of ``ns`` nanoseconds in which the loop's
+    thread had ``cpu_ns`` of CPU time and the whole process
+    ``proc_ns``: ``on_cpu`` (the thread computed for over half of it),
+    ``contended`` (it did not, and the process's threads together did:
+    the frontend, a client, a compile ran while the loop waited for
+    them or beside them), ``blocked`` (neither: nothing of this
+    process ran; the runtime's wait, or the machine stood still)."""
+    if 2 * cpu_ns > ns:
+        return "on_cpu"
+    return "contended" if 2 * proc_ns > ns else "blocked"
 
 
 class _DecodeRequest:
@@ -1489,6 +1521,16 @@ class DecodeScheduler:
         self.loop = {f"decode.{name}": [0, 0] for name in LOOP_PHASES}
         self._pass_ns: deque = deque(maxlen=64)
         self._slow_ns: Optional[float] = None
+        # what _record_pass carries from pass to pass: whether a step
+        # of this loop is in flight and the last one's sequence number,
+        # the nanoseconds the device was left without work by phase,
+        # the passes each word of the rule held, and the loop thread's
+        # and the process's CPU clocks as the pass before ended
+        self._in_flight = False
+        self._seq_out = 0
+        self.starved_ns = dict.fromkeys(STARVED_PHASES, 0)
+        self.held_by = dict.fromkeys(HELD_BY, 0)
+        self._cpu_ns = (time.thread_time_ns(), time.process_time_ns())
         # tenancy hooks (wired by bind() against the server's
         # registry): slot-release EWMA feeds honest decode-429
         # Retry-After; the fair cycle orders slot claims per tenant
@@ -1575,6 +1617,15 @@ class DecodeScheduler:
              "accepted / proposed).", lambda: self.n_spec_accepted),
         ):
             m.counter(name, help_).set_function(fn)
+        starved = m.counter(
+            "serving_decode_device_starved_seconds_total",
+            "Seconds the decode loop left the device with nothing of "
+            "its own queued, by the phase it spent them in: the rate "
+            "is the share of wall time in which the host is the "
+            "ceiling.", labels=("phase",))
+        for phase in STARVED_PHASES:
+            starved.labels(phase).set_function(
+                lambda phase=phase: self.starved_ns[phase] / 1e9)
         m.gauge("serving_decode_pages_free",
                 "Free KV-cache pages in the shared pool."
                 ).set_function(lambda: self.pages.n_free)
@@ -2079,6 +2130,8 @@ class DecodeScheduler:
         pass before did, with its own left in flight) is
         :meth:`_run_step`'s to say, from what the slots hold."""
         with collect() as self._pass:
+            # (this thread's CPU clock, not the constructor's)
+            self._cpu_ns = (time.thread_time_ns(), time.process_time_ns())
             while not self._stop.is_set():
                 with span("decode.admit") as sp:
                     # dead waiters resolve EVERY pass, slots full or
@@ -2118,26 +2171,92 @@ class DecodeScheduler:
         ``SLOW_PASS_MULTIPLE`` times the running median of the passes
         that ran a step is retained under route ``decode.loop`` like
         any slow request, with its view spelled out beside the
-        phases; a pass that only waited for work is no stall."""
+        phases; a pass that only waited for work is no stall.
+
+        **The starved account**: the time the loop left the device
+        with nothing of its own queued, by the phase it spent it in
+        (``STARVED_PHASES``), on the pass as ``starved_ms`` and summed
+        in ``starved_ns``. One bit is carried from pass to pass, "a
+        step of this loop is in flight": a ``decode.dispatch`` sets it
+        at its end, the ``decode.fetch`` of step k clears it unless a
+        step after k went out, and so does a ``decode.prefill`` (it
+        waits for its token, behind whatever was queued) and a
+        ``decode.idle``. Walking the phases as they closed, every
+        ``admit`` (less its ``prefill`` children), ``prepare``,
+        ``dispatch`` and ``emit`` that ran with the bit clear is
+        starved time. A ``prefill``, a ``compact`` and a speculative
+        round are device work of another program and count as busy;
+        ``idle`` (no live slot) is no starvation. This is the host's
+        account, not the device's: it leaves out the copy back at the
+        tail of a ``decode.fetch`` (the device is idle there if nothing
+        is queued) and counts the tail of a ``decode.dispatch`` after
+        the program was queued, as well as the turn after a
+        ``decode.compact``, which only queues its program; it cannot
+        see the device's gaps INSIDE a prefill walk.
+
+        **Who ran**: ``cpu_ms`` and ``proc_cpu_ms`` are the loop
+        thread's and the process's CPU time since the pass before was
+        recorded (two clock reads a pass); a retained pass says from
+        them whether it was ``on_cpu``, ``contended`` or ``blocked``
+        (:func:`stall_word`)."""
         loop = self.loop
         t0 = t1 = 0
-        worked = stepped = False
+        worked = stepped = other_program = False
         riders = ()
+        # the bit as the pass found it (an admit closes after its
+        # prefills) and as the walk carries it
+        found = in_flight = self._in_flight
+        seq_out = self._seq_out
+        pf_ns = pf_end = 0
+        starved: Dict[str, int] = {}
         for name, a, b, attrs in phases:
             acc = loop[name]
             acc[0] += 1
             acc[1] += b - a
             if name == "decode.admit":
                 t0 = a           # the first span opened, whatever
-            elif name == "decode.prepare":        # closed before it
+                if not found:                     # closed before it
+                    starved["admit"] = b - a - pf_ns
+                elif pf_end:
+                    starved["admit"] = b - pf_end
+            elif name == "decode.prepare":
                 riders = attrs["traces"]
+                other_program = attrs.get("order") == "spec_round"
+                if not in_flight:
+                    starved["prepare"] = b - a
             elif name == "decode.dispatch":
                 stepped = True
-            elif name in ("decode.prefill", "decode.fetch"):
+                if not (in_flight or other_program):
+                    starved["dispatch"] = \
+                        starved.get("dispatch", 0) + b - a
+                in_flight = True
+                seq_out = attrs["seq"] if attrs else 0
+            elif name == "decode.fetch":
                 worked = True    # a pass that only fetched waited too
+                in_flight = seq_out > attrs["fetched"] if attrs else False
+            elif name == "decode.emit":
+                if not (in_flight or other_program):
+                    starved["emit"] = starved.get("emit", 0) + b - a
+            elif name == "decode.prefill":
+                worked = True
+                pf_ns += b - a
+                pf_end = b
+                in_flight = False
+            elif name == "decode.idle":
+                in_flight = False
             t1 = b
+        self._in_flight = in_flight
+        self._seq_out = seq_out
+        if starved:
+            total = self.starved_ns
+            for phase, ns in starved.items():
+                total[phase] += ns
+                starved[phase] = ns * 1e-6
         if self.tracer is None:
             return
+        cpu, proc = time.thread_time_ns(), time.process_time_ns()
+        cpu0, proc0 = self._cpu_ns
+        self._cpu_ns = cpu, proc
         ns = t1 - t0
         if stepped:
             self._pass_ns.append(ns)
@@ -2155,7 +2274,11 @@ class DecodeScheduler:
                 self.n_steps + (self._flight is not None)
                 if stepped else None),
             traces=riders, phases=phases,
-            **(pass_view(phases) if slow else {}))
+            starved_ms=starved, cpu_ms=(cpu - cpu0) * 1e-6,
+            proc_cpu_ms=(proc - proc0) * 1e-6,
+            **(dict(pass_view(phases),
+                    stall=stall_word(ns, cpu - cpu0, proc - proc0))
+               if slow else {}))
 
     def _reap_waiting(self) -> None:
         with self._lock:
@@ -2520,20 +2643,16 @@ class DecodeScheduler:
                 spec[slot] = req
         return spec
 
-    def _live_rows(self, ahead: int = 0) -> "tuple[int, int, int]":
-        """``(summary_rows, window_rows, table_entries)`` over the live
-        slots, as the decoder counts them at each slot's position
-        (``ahead`` rows on, for the step behind one in flight): the
-        rows a step reads by kind, and the entries of the slots' page
-        tables that name them (a slot's rows lie in the first
-        ``cdiv(rows, page_size)`` entries of its row, and the decode
-        attention kernel walks those and no others)."""
+    def _live_rows(self, ahead: int = 0) -> "tuple[int, int]":
+        """``(summary_rows, window_rows)`` over the live slots, as the
+        decoder counts them at each slot's position (``ahead`` rows on,
+        for the step behind one in flight): the rows a step reads by
+        kind."""
         live = list(self._active)
         if not live:
-            return 0, 0, 0
+            return 0, 0
         n_sum, n_win = self.decoder.rows_at(self._pos[live] + ahead)
-        entries = -(-(n_sum + n_win) // self.decoder.page_size)
-        return int(np.sum(n_sum)), int(np.sum(n_win)), int(np.sum(entries))
+        return int(np.sum(n_sum)), int(np.sum(n_win))
 
     def _device_interval(self, i0: int) -> "tuple[float, float]":
         """Seconds (the tracer's clock) from the start of the first to
@@ -2546,31 +2665,38 @@ class DecodeScheduler:
             return now, now
         return done[0][1] * 1e-9, done[-1][2] * 1e-9
 
-    def _may_run_ahead(self, riders) -> bool:
-        """Whether the step this pass dispatches may still be in flight
-        when the pass ends, so that the next pass queues another behind
-        it before it fetches (``riders``: the requests by slot of the
-        step in flight now, whose tokens the host has not seen, or None
-        with nothing in flight). Decided from what the
-        host sees before the fetch, and by nothing else:
+    def _may_run_ahead(self, riders) -> Optional[str]:
+        """What holds the step this pass dispatches to today's order
+        (a word of :data:`HELD_BY`), or None: the step may still be in
+        flight when the pass ends, so that the next pass queues another
+        behind it before it fetches (``riders``: the requests by slot
+        of the step in flight now, whose tokens the host has not seen,
+        or None with nothing in flight). Decided from what the host
+        sees before the fetch, and by nothing else; the FIRST condition
+        that says no names the pass (``held_by`` on its
+        ``decode.prepare``):
 
-        * every slot is taken, by the requests the step in flight
-          carries: a free slot's next request would wait for every
-          queued step, not for what is left of one;
-        * every request is greedy and none may speculate: a sampled
-          token is drawn on the host from the fetched logits, and a
-          speculative round (or the draft's catch-up step) is another
-          program between two steps;
-        * no slot is known to end with the token in flight (its
-          ``max_new``-th, the lane's last row, a cancel, a closed
-          stream or a deadline already seen), so the pass that frees a
-          slot finds nothing queued behind it;
-        * the step in flight does not fill a window (a decoder with
-          two kinds of row): its compaction runs between that step and
-          the next, so that pass keeps the order fetch, compact,
+        * ``free_slot``: not every slot is taken: a free slot's next
+          request would wait for every queued step, not for what is
+          left of one;
+        * ``sampler`` / ``speculative``: a sampled token is drawn on
+          the host from the fetched logits, and a speculative round (or
+          the draft's catch-up step) is another program between two
+          steps (the riders of a step in flight were greedy as it went
+          out, and stay so);
+        * ``riders_changed``: the slots no longer hold the requests
+          the step in flight carries (one left at the last emit);
+        * ``last_token`` / ``lane_full`` / ``cancelled`` /
+          ``stream_closed`` / ``deadline``: a slot is known to end with
+          the token in flight (its ``max_new``-th, the lane's last row,
+          a cancel, a closed stream or a deadline already seen), so
+          the pass that frees a slot finds nothing queued behind it;
+        * ``window_fill``: the step in flight fills a window (a decoder
+          with two kinds of row): its compaction runs between that step
+          and the next, so that pass keeps the order fetch, compact,
           dispatch (one pass in a window's length);
-        * every lane can grow to the row the step behind the one in
-          flight writes (grown HERE, a row on from
+        * ``no_pages``: a lane cannot grow to the row the step behind
+          the one in flight writes (grown HERE, a row on from
           :meth:`_prepare_round`'s): a slot the pool cannot serve ends
           for want of pages after its token is out, in the next pass's
           upkeep, with nothing queued behind it.
@@ -2579,25 +2705,37 @@ class DecodeScheduler:
         request retires at its emit and the step queued behind carries
         one lane more (:meth:`_emit_step` discards it)."""
         if self.pool.n_free or not self._active:
-            return False
+            return "free_slot"
         if riders is None:
-            return not any(r.sampler is not None or self._spec_capable(r)
-                           for r in self._active.values())
+            for req in self._active.values():
+                if req.sampler is not None:
+                    return "sampler"
+                if self._spec_capable(req):
+                    return "speculative"
+            return None
         if riders != self._active:
-            return False
-        # the riders were greedy as their step went out, and stay so
+            return "riders_changed"
         last_row = self.decoder.max_len - 1
         window = self.decoder.window
         for slot, req in riders.items():
             pos = int(self._pos[slot]) + 1
             s, d = req.stream, req.pending.deadline
-            if len(req.produced) + 1 >= req.max_new \
-                    or pos >= last_row or (window and pos % window == 0) \
-                    or req.cancelled or (s is not None and s.closed) \
-                    or (d is not None and d.expired):
-                return False
-        return all(self._ensure_pages(req, int(self._pos[slot]) + 1)
-                   for slot, req in riders.items())
+            if len(req.produced) + 1 >= req.max_new:
+                return "last_token"
+            if pos >= last_row:
+                return "lane_full"
+            if window and pos % window == 0:
+                return "window_fill"
+            if req.cancelled:
+                return "cancelled"
+            if s is not None and s.closed:
+                return "stream_closed"
+            if d is not None and d.expired:
+                return "deadline"
+        for slot, req in riders.items():
+            if not self._ensure_pages(req, int(self._pos[slot]) + 1):
+                return "no_pages"
+        return None
 
     def _run_step(self) -> None:
         """The pass's step work, ordered by what the slots hold
@@ -2622,12 +2760,19 @@ class DecodeScheduler:
             # with a step in flight nobody is reaped or preempted
             # before its token is out: a dead slot retires at its emit
             spec = self._prepare_round() if flight is None else {}
-            ahead = self._may_run_ahead(riders)
+            held = self._may_run_ahead(riders)
+            ahead = held is None
             # the rows of the step this pass dispatches: one on, behind
             # a step in flight; a pass that only fetches stamps what
             # the step it fetches read
             n_ahead = 1 if ahead and flight is not None else 0
-            sum_rows, win_rows, live_entries = self._live_rows(n_ahead)
+            if spec:
+                order = "spec_round"
+            elif ahead:
+                order = "ahead" if n_ahead else "start"
+            else:
+                order = "in_turn" if flight is None else "fetch_only"
+            sum_rows, win_rows = self._live_rows(n_ahead)
             sp.attrs = {
                 "active": len(self._active),
                 "pages_in_use": self._pages_in_use(),
@@ -2640,12 +2785,14 @@ class DecodeScheduler:
                 # looped stack reads each in ``loops`` passes)
                 "window_rows": win_rows, "summary_rows": sum_rows,
                 "loops": getattr(self.decoder, "n_loops", 1),
-                # the page tables' entries, and those of them that name
-                # a live row: what the attention kernel fetches
-                "table_entries": int(self._tables.size),
-                "table_entries_live": live_entries,
+                # the order this pass runs in (PASS_ORDERS); where the
+                # rule held it to today's, ``held_by`` says which
+                "order": order,
                 "traces": [getattr(r.pending, "trace", None)
                            for r in self._active.values()]}
+            if held is not None:
+                sp.attrs["held_by"] = held
+                self.held_by[held] += 1
         if spec:
             self._run_spec_round(spec)
             return
@@ -3033,6 +3180,9 @@ class DecodeScheduler:
                 # and tokens of lanes whose request had left when their
                 # step was fetched (never emitted, not in n_tokens)
                 "n_steps_ahead": self.n_steps_ahead,
+                # passes held to today's order, by the first condition
+                # of _may_run_ahead that said no (HELD_BY)
+                "held_by": dict(self.held_by),
                 "n_tokens_discarded": self.n_tokens_discarded,
                 "n_tokens": self.n_tokens,
                 # goodput: tokens from requests that resolved cleanly
@@ -3056,8 +3206,13 @@ class DecodeScheduler:
                 # the loop's passes by phase (LOOP_PHASES): how many
                 # decode.<phase> spans closed and their seconds, from
                 # the same clock reads as the spans and as prefill_s
-                "loop": {k[7:]: {"n": n, "s": round(ns * 1e-9, 6)}
-                         for k, (n, ns) in self.loop.items()},
+                "loop": {**{k[7:]: {"n": n, "s": round(ns * 1e-9, 6)}
+                            for k, (n, ns) in self.loop.items()},
+                         # the seconds the loop left the device with
+                         # nothing of its own queued, by the phase it
+                         # spent them in (_record_pass)
+                         "starved": {k: round(ns * 1e-9, 6) for k, ns
+                                     in self.starved_ns.items()}},
                 "n_step_faults": self.n_step_faults,
                 # the two kinds of cache row (docs/serving.md "Two
                 # kinds of row"): windows turned into summaries so far

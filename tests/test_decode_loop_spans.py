@@ -23,7 +23,8 @@ from mmlspark_tpu.models import transformer as T
 from mmlspark_tpu.parallel import MeshSpec, build_mesh
 from mmlspark_tpu.serving import DecodeScheduler, TransformerDecoder
 from mmlspark_tpu.serving.decode import (
-    LOOP_PHASES, LOOP_ROUTE, SLOW_PASS_MULTIPLE, pass_view,
+    HELD_BY, LOOP_PHASES, LOOP_ROUTE, PASS_ORDERS, SLOW_PASS_MULTIPLE,
+    STARVED_PHASES, pass_view, stall_word,
 )
 
 CFG = T.TransformerConfig(vocab=64, d_model=16, n_heads=2, d_head=8,
@@ -47,6 +48,18 @@ def _decoder(**kw) -> TransformerDecoder:
     return TransformerDecoder(PARAMS, CFG, n_slots=4, max_len=32, **kw)
 
 
+_WARM = []
+
+
+def _warm_decoder() -> TransformerDecoder:
+    """One decoder with its programs compiled, for the cases that run
+    a scheduler over it one after another."""
+    if not _WARM:
+        _WARM.append(_decoder())
+        _WARM[0].warmup()
+    return _WARM[0]
+
+
 def _drive(sched, n=6, max_new=6):
     rng = np.random.default_rng(3)
     reqs = [_Pending({"prompt": [int(t) for t in rng.integers(
@@ -65,8 +78,7 @@ def _drive(sched, n=6, max_new=6):
 
 @pytest.fixture(scope="module")
 def run():
-    dec = _decoder()
-    dec.warmup()
+    dec = _warm_decoder()
     t0 = time.monotonic()
     sched = DecodeScheduler(dec, tracer=TRACER).start()
     try:
@@ -88,7 +100,7 @@ def run():
 
 @pytest.mark.parametrize("what", ["phases_present", "chain", "prefills",
                                   "stats_loop", "pool", "ahead",
-                                  "in_turn"])
+                                  "in_turn", "order", "starved"])
 def test_scheduler_run_leaves_whole_passes(run, what):
     passes = run["passes"]
     stepped = [sp for sp in passes if sp.attrs["step"] is not None]
@@ -140,7 +152,8 @@ def test_scheduler_run_leaves_whole_passes(run, what):
         rider = run["reqs"][0].trace
         assert [sp for sp in stepped if rider in sp.attrs["traces"]]
     elif what == "stats_loop":
-        loop = run["stats"]["loop"]
+        loop = dict(run["stats"]["loop"])
+        assert set(loop.pop("starved")) == set(STARVED_PHASES)
         assert set(loop) == set(LOOP_PHASES)
         n = {k: sum(1 for sp in passes if k in sp.view["phases_ms"])
              for k in LOOP_PHASES if k != "prefill"}
@@ -183,6 +196,47 @@ def test_scheduler_run_leaves_whole_passes(run, what):
             set(sp.view["phases_ms"]) == {"admit", "prepare", "fetch",
                                           "emit"}
             for sp in only)
+    elif what == "order":
+        # every pass that reached its prepare says in which order it
+        # ran, a held one which rule held it, and the dispatch's own
+        # ``ahead`` agrees; the share of steps ahead is the counters'
+        said = [sp for sp in passes if "prepare" in sp.view["phases_ms"]]
+        assert all(sp.view["order"] in PASS_ORDERS for sp in said)
+        assert not any("order" in sp.view for sp in passes
+                       if sp not in said)
+        for sp in said:
+            held = sp.view["order"] not in ("ahead", "start")
+            assert ("held_by" in sp.view) == held
+            assert not held or sp.view["held_by"] in HELD_BY
+        assert all(sp.view["ahead"] == (sp.view["order"] == "ahead")
+                   for sp in stepped)
+        n_ahead = sum(sp.view["order"] == "ahead" for sp in stepped)
+        assert n_ahead / len(stepped) == pytest.approx(
+            run["stats"]["n_steps_ahead"] / run["stats"]["n_steps"],
+            abs=0.01)
+        held = run["stats"]["held_by"]
+        assert sum(held.values()) == sum("held_by" in sp.view
+                                         for sp in said)
+        assert held["free_slot"] > 0 and held["last_token"] > 0
+    elif what == "starved":
+        # /decode/stats' account is the sum of the passes', and a pass
+        # says how much of it its thread and its process computed
+        total = dict.fromkeys(STARVED_PHASES, 0.0)
+        for sp in passes:
+            for k, ms in sp.attrs["starved_ms"].items():
+                assert 0 < ms <= sp.view["phases_ms"][k] + 1e-9
+                total[k] += ms
+            if sp.view.get("order") == "ahead":
+                assert sp.attrs["starved_ms"] == {}
+            assert 0 <= sp.attrs["cpu_ms"] <= sp.attrs["proc_cpu_ms"] + 0.5
+        assert run["stats"]["loop"]["starved"] == pytest.approx(
+            {k: ms * 1e-3 for k, ms in total.items()}, abs=1e-5)
+        assert all(ms > 0 for ms in total.values())
+        # an idle wait is no starvation: the account is far under the
+        # seconds the loop spent in ``idle`` and in everything else
+        assert sum(total.values()) < sum(
+            sp.duration_ms - sp.view["phases_ms"].get("idle", 0.0)
+            for sp in passes)
     else:
         for sp in stepped:
             at = sp.view
@@ -347,18 +401,39 @@ def test_span_under_budget_with_no_session(how):
 # (e) a slow pass is retained under route decode.loop, with its phases
 
 
-def _phases(ms: float, t0_ns: int, idle: bool = False):
+def _phases(ms: float, t0_ns: int, idle: bool = False, order=None,
+            seq=None, fetched=None, prefill_ns: int = 0):
+    """One pass of ``ms`` milliseconds as the loop hands it to
+    ``_record_pass``: admit, prepare, dispatch, fetch and emit of 1 us
+    each but the fetch, which takes the rest. ``order`` goes onto the
+    prepare and leaves out the dispatch (``fetch_only``) or the fetch
+    and emit (``start``); ``seq`` / ``fetched`` onto dispatch and
+    fetch; ``prefill_ns`` is a prefill inside an admit that much
+    longer."""
     a = t0_ns
     if idle:
         return [("decode.admit", a, a + 1000, {"admitted": 0}),
                 ("decode.idle", a + 1000, a + int(ms * 1e6), None)]
     b = a + int(ms * 1e6)
-    return [("decode.admit", a, a + 1000, {"admitted": 0}),
-            ("decode.prepare", a + 1000, a + 2000,
-             {"active": 1, "traces": ["t-1"]}),
-            ("decode.dispatch", a + 2000, a + 3000, None),
-            ("decode.fetch", a + 3000, b - 1000, None),
-            ("decode.emit", b - 1000, b, {"emitted": 1})]
+    out = []
+    if prefill_ns:
+        out.append(("decode.prefill", a, a + prefill_ns, {"slot": 0}))
+    at = a + 1000 + prefill_ns
+    out.append(("decode.admit", a, at, {"admitted": bool(prefill_ns)}))
+    attrs = {"active": 1, "traces": ["t-1"]}
+    if order is not None:
+        attrs["order"] = order
+    out.append(("decode.prepare", at, at + 1000, attrs))
+    at += 1000
+    if order != "fetch_only":
+        out.append(("decode.dispatch", at, at + 1000,
+                    None if seq is None else {"seq": seq}))
+        at += 1000
+    if order != "start":
+        out += [("decode.fetch", at, b - 1000,
+                 None if fetched is None else {"fetched": fetched}),
+                ("decode.emit", b - 1000, b, {"emitted": 1})]
+    return out
 
 
 @pytest.mark.parametrize("ms, idle, kept", [
@@ -406,3 +481,197 @@ def test_slow_requests_do_not_churn_out_a_retained_stall():
     kept = tracer.traces()
     assert stall["trace_id"] in {t["trace_id"] for t in kept}
     assert sum(t["route"] == "/generate" for t in kept) <= 33
+
+
+# ---------------------------------------------------------------------------
+# (f) the loop's own account of the time it left the device without work
+
+
+def _starved_us(sched, passes):
+    """The passes through ``_record_pass`` -> each one's ``starved_ms``
+    in whole microseconds (the helper's phases are 1 us long)."""
+    for phases in passes:
+        sched._record_pass(phases)
+    return [{k: round(ms * 1e3) for k, ms in sp.attrs["starved_ms"].items()}
+            for sp in sched.tracer.recorder.scan("decode.pass")]
+
+
+_T = 1_000_000_000
+_WHOLE_TURN = {"admit": 1, "prepare": 1, "dispatch": 1, "emit": 1}
+
+
+@pytest.mark.parametrize("case, passes, want", [
+    # today's order: the whole host's turn, and not the fetch
+    ("in_turn", [_phases(9.0, _T, order="in_turn", seq=1, fetched=1)],
+     [_WHOLE_TURN]),
+    # the helper as the older cases use it, with no attribute at all
+    ("no_attrs", [_phases(9.0, _T)], [_WHOLE_TURN]),
+    # a step left in flight, then one queued behind it: the pass that
+    # starts pays its turn, the pass that runs ahead nothing
+    ("ahead", [_phases(1.0, _T, order="start", seq=1),
+               _phases(9.0, 2 * _T, order="ahead", seq=2, fetched=1),
+               _phases(9.0, 3 * _T, order="ahead", seq=3, fetched=2)],
+     [{"admit": 1, "prepare": 1, "dispatch": 1}, {}, {}]),
+    # the pass that only fetches is starved from its fetch on; the one
+    # behind it keeps today's order and pays the whole turn
+    ("fetch_only_then_in_turn",
+     [_phases(1.0, _T, order="start", seq=1),
+      _phases(9.0, 2 * _T, order="fetch_only", fetched=1),
+      _phases(9.0, 3 * _T, order="in_turn", seq=2, fetched=2)],
+     [{"admit": 1, "prepare": 1, "dispatch": 1}, {"emit": 1},
+      _WHOLE_TURN]),
+    # a prefill is device work of another program: the admit around it
+    # counts less its child
+    ("prefill_child",
+     [_phases(9.0, _T, order="in_turn", seq=1, fetched=1,
+              prefill_ns=5_000_000)], [_WHOLE_TURN]),
+    # a prefill behind a step in flight waits for both: what follows it
+    # finds the device with nothing queued (admit: the 1 us behind it)
+    ("prefill_behind_a_step",
+     [_phases(1.0, _T, order="start", seq=1),
+      _phases(9.0, 2 * _T, order="fetch_only", fetched=1,
+              prefill_ns=5_000_000)],
+     [{"admit": 1, "prepare": 1, "dispatch": 1},
+      {"admit": 1, "prepare": 1, "emit": 1}]),
+    # waiting for work is no starvation, and leaves nothing in flight
+    ("idle", [_phases(50.0, _T, idle=True),
+              _phases(9.0, 2 * _T, order="in_turn", seq=1, fetched=1)],
+     [{"admit": 1}, _WHOLE_TURN]),
+    # a speculative round is another program's device work: the turn
+    # before it counts, its dispatches and its emit do not
+    ("spec_round", [_phases(9.0, _T, order="spec_round")],
+     [{"admit": 1, "prepare": 1}]),
+])
+def test_record_pass_keeps_the_starved_account(case, passes, want):
+    sched = DecodeScheduler(_decoder(), tracer=Tracer())
+    got = _starved_us(sched, passes)
+    assert got == want
+    total = {k: sum(p.get(k, 0) for p in want) for k in STARVED_PHASES}
+    assert {k: round(ns / 1000) for k, ns in sched.starved_ns.items()} \
+        == total
+    assert sched.stats()["loop"]["starved"] == pytest.approx(
+        {k: us * 1e-6 for k, us in total.items()}, abs=1e-9)
+
+
+def test_the_account_is_exported_by_phase():
+    """``serving_decode_device_starved_seconds_total{phase}`` reads the
+    same sums when it is scraped, with nothing on the loop's path."""
+    from mmlspark_tpu.core.telemetry import MetricsRegistry
+    reg = MetricsRegistry()
+    sched = DecodeScheduler(_decoder(), tracer=Tracer(), registry=reg)
+    sched._record_pass(_phases(9.0, _T, order="in_turn", seq=1, fetched=1))
+    text = reg.render()
+    got = dict(re.findall(
+        r'^serving_decode_device_starved_seconds_total\{phase="(\w+)"\} '
+        r'(\S+)$', text, re.M))
+    assert {k: float(v) for k, v in got.items()} == pytest.approx(
+        dict.fromkeys(STARVED_PHASES, 1e-6))
+
+
+# ---------------------------------------------------------------------------
+# (g) a stall says whether anybody ran
+
+
+def _spin_cpu(seconds: float) -> None:
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("how, want", [
+    ("sleeps", "blocked"), ("spins", "on_cpu"), ("waits_for_a_thread",
+                                                 "contended")])
+def test_a_retained_stall_says_who_ran(how, want):
+    """A fetch that holds a pass for 0.2 s (in the decoder, before
+    its span opens: the pass's length and its clocks see it): asleep,
+    nothing of the process ran (``blocked``); spinning, the loop's own thread did
+    (``on_cpu``); waiting for another thread that spins, the process
+    did and the loop's thread did not (``contended``). The words are
+    decided by halves of the pass, so each case has a factor of two of
+    room under other tests' load."""
+    dec = _warm_decoder()
+    tracer = Tracer(default_slow_ms=150.0)
+    sched = DecodeScheduler(dec, tracer=tracer)
+    inner, n = dec.fetch_step, [0]
+
+    def fetch_step(step):
+        n[0] += 1
+        if n[0] == 3:
+            if how == "sleeps":
+                time.sleep(0.2)
+            elif how == "spins":
+                _spin_cpu(0.2)
+            else:
+                other = threading.Thread(target=_spin_cpu, args=(0.2,))
+                other.start()
+                other.join()
+        return inner(step)
+
+    dec.fetch_step = fetch_step
+    sched.start()
+    try:
+        _drive(sched, n=1, max_new=6)
+    finally:
+        sched.stop()
+        del dec.fetch_step
+    (kept,) = [t for t in tracer.traces() if t["route"] == LOOP_ROUTE
+               and t["duration_ms"] >= 190.0]
+    ms = kept["duration_ms"]
+    stall = tracer.get_trace(kept["trace_id"])["spans"][0]["attrs"]
+    assert stall["stall"] == want, stall
+    if want == "blocked":
+        assert stall["cpu_ms"] < ms / 2 and stall["proc_cpu_ms"] < ms / 2
+    elif want == "on_cpu":
+        assert stall["cpu_ms"] > ms / 2
+    else:
+        assert stall["cpu_ms"] < ms / 2 < stall["proc_cpu_ms"]
+    # only a retained pass is spelled out
+    assert all("stall" not in sp.attrs
+               for sp in tracer.recorder.scan("decode.pass")
+               if "phases_ms" not in sp.attrs)
+
+
+@pytest.mark.parametrize("ns, cpu, proc, want", [
+    (100, 51, 51, "on_cpu"), (100, 50, 51, "contended"),
+    (100, 10, 400, "contended"), (100, 50, 50, "blocked"),
+    (100, 0, 0, "blocked")])
+def test_stall_word(ns, cpu, proc, want):
+    assert stall_word(ns, cpu, proc) == want
+
+
+# ---------------------------------------------------------------------------
+# (h) what the account costs a pass
+
+
+@pytest.mark.perf
+def test_record_pass_costs_a_few_spans():
+    """``_record_pass`` runs between two steps. On a pass of seven
+    phases (a prefill inside its admit, prepare, dispatch, fetch,
+    emit, compact) it costs no more than 12 spans of the primitive
+    opened and closed under an owner, which is what the loop pays to
+    HAVE the seven: a ratio on one machine, not a wall-clock bound
+    (measured 4-5 spans' worth; PERF.md section 6, PR 37)."""
+    sched = DecodeScheduler(_decoder(), tracer=Tracer())
+    seven = _phases(9.0, _T, order="in_turn", seq=1, fetched=1,
+                    prefill_ns=5_000_000)
+    seven.append(("decode.compact", seven[-1][2], seven[-1][2] + 1000,
+                  {"slot": 0}))
+    assert len(seven) == 7
+
+    def best_of(fn, n=5000, rounds=5):
+        best = float("inf")
+        for _ in range(rounds):
+            t0 = time.perf_counter_ns()
+            for _ in range(n):
+                fn()
+            best = min(best, (time.perf_counter_ns() - t0) / n)
+        return best
+
+    def one_span():
+        with span("decode.emit") as sp:
+            sp.attrs = {"emitted": 1}
+
+    with collect():
+        span_ns = best_of(one_span)
+    pass_ns = best_of(lambda: sched._record_pass(seven))
+    assert pass_ns < 12 * span_ns, (pass_ns, span_ns)

@@ -1987,10 +1987,11 @@ class TestStateASlot:
     # a state a slot beside the rows (pages of 4): positions 19..21
     (lambda: TestStateASlot._decoder(), 19, 4, [5, 6, 6]),
 ], ids=["one_kind", "two_kinds", "state_a_slot"])
-def test_prepare_counts_the_table_entries(make, prompt_len, n_new, want):
-    """``decode.prepare`` stamps the page tables' entries and those of
-    them that name a live row, from the positions the scheduler holds:
-    ``cdiv(rows, page_size)`` a live slot, which is what
+def test_prepare_counts_the_rows_the_kernel_walks(make, prompt_len, n_new,
+                                                  want):
+    """``decode.prepare`` stamps the rows a step reads by kind, from
+    the positions the scheduler holds: ``cdiv(rows, page_size)`` of a
+    live slot's table entries name them, which is what
     ``paged_decode_attention`` walks (``parallel/pallas_attention.
     paged_walk``); the rest of a table is never looked at."""
     from mmlspark_tpu.core.tracing import Tracer
@@ -2010,11 +2011,9 @@ def test_prepare_counts_the_table_entries(make, prompt_len, n_new, want):
     steps = [v for v in (pass_view(sp.attrs["phases"])
                          for sp in tracer.recorder.scan("decode.pass"))
              if "dispatch" in v["phases_ms"]]
-    assert [v["table_entries_live"] for v in steps] == want
-    assert {v["table_entries"] for v in steps} \
-        == {dec.n_slots * dec.pages_per_slot}
-    # the kernel's own count at the same rows: the last row's index
     rows = [v["summary_rows"] + v["window_rows"] for v in steps]
+    assert [-(-r // dec.page_size) for r in rows] == want
+    # the kernel's own count at the same rows: the last row's index
     assert [int(paged_walk(r - 1, dec.page_size)) for r in rows] == want
 
 
@@ -2030,7 +2029,7 @@ class _InTurn(DecodeScheduler):
     """Today's order in every pass."""
 
     def _may_run_ahead(self, flight):
-        return False
+        return "free_slot"
 
 
 class _Stream:
@@ -2300,10 +2299,22 @@ class TestStepInFlight:
         views = [pass_view(sp.attrs["phases"])
                  for sp in tracer.recorder.scan("decode.pass")]
         assert not any(v.get("ahead") for v in views)
+        # every stepping pass is held, and by this rule while the
+        # slots are as the case set them (a slot freed by the first
+        # request to end reads ``free_slot``)
+        held = [v["held_by"] for v in views
+                if v.get("order") not in (None, "start")]
+        assert held[0] == why and set(held) <= {why, "free_slot"}
+        assert stats["held_by"][why] == held.count(why)
+        assert sum(stats["held_by"].values()) == len(held)
         if why == "speculative":
             assert stats["speculative"]["rounds"] > 0
+            assert {v["order"] for v in views if "order" in v} \
+                <= {"spec_round", "in_turn"}
         elif why == "last_token":
             assert stats["n_steps"] == 1
+            assert [v["order"] for v in views if "order" in v] \
+                == ["start", "fetch_only"]
             assert [sorted(v["phases_ms"]) for v in views
                     if "fetch" in v["phases_ms"]] \
                 == [["admit", "emit", "fetch", "prepare"]]
@@ -2311,6 +2322,142 @@ class TestStepInFlight:
             assert stats["n_steps"] == 7
             assert all(v["fetched"] == v["seq"] for v in views
                        if "dispatch" in v["phases_ms"])
+        assert _nothing_left(sched, stats)
+
+    def test_every_pass_says_its_order(self, kind_decoder):
+        """Over a run whose ends and admissions fall among the steps:
+        every stepping pass carries an ``order`` of the vocabulary,
+        ``held_by`` exactly where the rule held it, ``order`` and the
+        ``ahead`` on its dispatch agree, the counters in
+        ``/decode/stats`` are the passes' sums, and a pass that ran
+        ahead left the device nothing to wait for."""
+        from collections import Counter
+        from mmlspark_tpu.core.tracing import Tracer
+        from mmlspark_tpu.serving.decode import (
+            HELD_BY, PASS_ORDERS, STARVED_PHASES, pass_view)
+        payloads = _mix(np.random.default_rng(7),
+                        ((10, 14), (5, 9), (3, 12), (12, 6), (7, 11)))
+        tracer = Tracer()
+        sched = DecodeScheduler(kind_decoder, tracer=tracer)
+        got, stats = _serve_all(sched, payloads)
+        assert all(p.status == 200 for p in got)
+        spans = tracer.recorder.scan("decode.pass")
+        views = [pass_view(sp.attrs["phases"]) for sp in spans]
+        stepping = [v for v in views if "prepare" in v["phases_ms"]]
+        assert stepping and {v["order"] for v in stepping} \
+            <= set(PASS_ORDERS) - {"spec_round"}
+        assert not any("order" in v for v in views if v not in stepping)
+        for v in stepping:
+            assert ("held_by" in v) == (v["order"] in ("in_turn",
+                                                       "fetch_only"))
+            assert v.get("held_by", "free_slot") in HELD_BY
+            if "dispatch" in v["phases_ms"]:
+                assert v["ahead"] == (v["order"] == "ahead")
+                assert v["order"] != "fetch_only"
+            else:
+                assert v["order"] in ("fetch_only", "in_turn")
+        orders = Counter(v["order"] for v in stepping)
+        assert orders["ahead"] == stats["n_steps_ahead"] > 0
+        assert orders["start"] > 0 and orders["fetch_only"] > 0
+        held = Counter(v["held_by"] for v in stepping if "held_by" in v)
+        assert {k: n for k, n in stats["held_by"].items() if n} == held
+        assert set(stats["held_by"]) == set(HELD_BY)
+        assert held["last_token"] > 0
+        if kind_decoder.window:
+            assert held["window_fill"] > 0
+        # the starved account: nothing in a pass that ran ahead, the
+        # whole host's turn in one in today's order, and the stats'
+        # sums are the passes'
+        total = dict.fromkeys(STARVED_PHASES, 0.0)
+        for sp, v in zip(spans, views):
+            starved = sp.attrs["starved_ms"]
+            assert set(starved) <= set(STARVED_PHASES)
+            for k, ms in starved.items():
+                assert 0 <= ms <= v["phases_ms"][k] + 1e-9
+                total[k] += ms
+            if v.get("order") == "ahead":
+                assert starved == {}
+            elif v.get("order") == "in_turn" and "emit" in v["phases_ms"]:
+                assert set(starved) == set(STARVED_PHASES)
+                assert starved["dispatch"] == pytest.approx(
+                    v["phases_ms"]["dispatch"])
+            assert sp.attrs["cpu_ms"] >= 0 and sp.attrs["proc_cpu_ms"] >= 0
+        assert stats["loop"]["starved"] == pytest.approx(
+            {k: ms * 1e-3 for k, ms in total.items()}, abs=1e-5)
+        assert sum(total.values()) > 0
+        assert _nothing_left(sched, stats)
+
+    @pytest.mark.parametrize("why", ["cancelled", "stream_closed",
+                                     "deadline", "riders_changed",
+                                     "window_fill"])
+    def test_a_held_pass_names_the_rule(self, why):
+        """With a step in flight, a cancel, a closed stream or a
+        deadline the host has seen, a request that left at the last
+        emit, and a step that fills a window, each hold the next pass
+        to a fetch alone under their word."""
+        from mmlspark_tpu.core.tracing import Tracer
+        from mmlspark_tpu.serving.decode import pass_view
+        dec = _kind("tiny-evabyte" if why == "window_fill" else "tiny")
+        clock = ManualClock()
+        # (a request that left is missed by the rule only once its
+        # slot is taken again: a third request waits for it)
+        payloads = _mix(np.random.default_rng(29),
+                        ((8, 20), (5, 20), (6, 3))[
+                            :3 if why == "riders_changed" else 2])
+        tracer = Tracer()
+        sched = DecodeScheduler(dec, tracer=tracer, clock=clock)
+        inner, n_tokens = sched._retire_if_done, [0]
+
+        def retire_if_done(req, tok):
+            # f0's fifth token, of a step fetched with another queued:
+            # the cancel lands before the emit looks (the request
+            # leaves there: ``riders_changed``) or just after it
+            n_tokens[0] += req.pending.rid == "f0"
+            mine = req.pending.rid == "f0" and n_tokens[0] == 4
+            if mine and why == "riders_changed":
+                sched.cancel("f0")
+            done = inner(req, tok)
+            if mine and why == "cancelled":
+                sched.cancel("f0")
+            elif mine and why == "stream_closed":
+                req.stream.closed = True
+            elif mine and why == "deadline":
+                clock.advance(5.0)
+            return done
+
+        sched._retire_if_done = retire_if_done
+        ps = [_Pending(p, f"f{i}") for i, p in enumerate(payloads)]
+        ps[0].stream = _Stream()
+        if why == "deadline":
+            ps[0].deadline = Deadline(1.0, clock=clock)
+        sched.start()
+        try:
+            for p in ps:
+                sched.submit(p)
+            for p in ps:
+                assert p.event.wait(60), "stranded"
+        finally:
+            sched.stop()
+        stats = sched.stats()
+        views = [pass_view(sp.attrs["phases"])
+                 for sp in tracer.recorder.scan("decode.pass")]
+        held = [v for v in views if v.get("held_by") == why]
+        assert held and stats["held_by"][why] == len(held)
+        assert all(v["order"] == "fetch_only" for v in held)
+        if why == "window_fill":
+            # both lanes cross position 16, three steps apart: one
+            # pass each, and the lanes run on behind their compactions
+            assert len(held) == 2 and stats["n_compactions"] >= 2
+            assert stats["n_tokens_discarded"] == 0
+        else:
+            # a step was queued as the host saw it: the pass after only
+            # fetches, and the request leaves at that emit (or left at
+            # the one before, its lane in the queued step discarded)
+            assert len(held) == 1
+            assert stats["n_tokens_discarded"] == (why == "riders_changed")
+            assert json.loads(ps[0].reply)["finish_reason"] == {
+                "stream_closed": "disconnected", "deadline": "deadline"
+                }.get(why, "cancelled")
         assert _nothing_left(sched, stats)
 
     def test_a_budget_of_m_tokens_runs_m_less_two_steps_ahead(self):
