@@ -46,10 +46,13 @@ multiplier; the equations in full are at the head of
   chip that holds it adds it; on one chip the layer runs without that
   exchange and nothing stands in for it). No routing is dropped: a
   tile's routings are sorted by expert into one grouped product
-  (``jax.lax.ragged_dot``, no capacity); a decode step runs every held
-  expert over every slot, weighted by the router (zero where a slot
-  was not routed): at 16 slots x 10 routings some nine in ten held
-  experts are touched anyway, and the masked form is exact.
+  (``jax.lax.ragged_dot``, no capacity); a decode step weights each
+  held expert's product by the router (zero where a slot was not
+  routed), and under the Pallas kernels copies and multiplies only
+  the experts some live slot was routed to
+  (:func:`~mmlspark_tpu.parallel.pallas_moe.touched_experts`: 32 slots
+  at LFM2's top-4 of 64 touch some 60% of them); the dense path runs
+  every held expert over every slot (the masked form). Both are exact.
 
 **Precision** (``cfg.dtype``, bfloat16 as served): weights, K/V rows and
 matmul operands in ``dtype``; accumulation, the residual stream, the
@@ -549,7 +552,20 @@ def sigmoid_route(logits, bias, cfg: GraniteHybridConfig):
     return idx, w * cfg.routed_scaling
 
 
-def moe(bp, h, cfg: GraniteHybridConfig, grouped: bool, live=None):
+def masked_experts(u, weight, w_in_e, w_out_e):
+    """Every held expert over every token, each product weighted by the
+    router's ``weight`` [T, E] (zero where a token was not routed): the
+    masked form of a decode step's experts. ``u`` [T, D] in the weights'
+    dtype; float32 [T, D]."""
+    f = w_out_e.shape[1]
+    gv = _mm("td,edf->etf", u, w_in_e)
+    act = jax.nn.silu(gv[..., :f]) * gv[..., f:]
+    return _mm("etf,efd->td", (act * weight.T[:, :, None]).astype(u.dtype),
+               w_out_e)
+
+
+def moe(bp, h, cfg: GraniteHybridConfig, grouped: bool, live=None,
+        impl: str = "dense"):
     """The expert layer's share on this chip: ``h`` [T, D] float32 ->
     ``(routed + shared [T, D], routings int32[len(experts_held)],
     touched int32)``: the router over all ``n_experts``, ``top_k`` a
@@ -562,9 +578,13 @@ def moe(bp, h, cfg: GraniteHybridConfig, grouped: bool, live=None):
     how many of them received any.
 
     ``grouped`` sorts the routings by expert into one grouped product
-    (a prompt's tile); otherwise every held expert runs over every
-    token with the router's weight, zero where it was not routed (a
-    decode step's few tokens). Both are exact: no routing is dropped."""
+    (a prompt's tile). Otherwise (a decode step's few tokens) each
+    expert's product is weighted by the router, zero where it was not
+    routed: under ``impl`` ``"pallas"`` (``"pallas_interpret"`` on the
+    CPU) :func:`~mmlspark_tpu.parallel.pallas_moe.touched_experts` copies
+    and multiplies only the ``touched`` experts, listed first; under
+    ``"dense"`` every held expert runs over every token (the masked
+    form). All are exact: no routing is dropped."""
     dt_, f = cfg.compute_dtype, cfg.d_expert
     e = len(cfg.experts_held)
     u32 = _norm(h, bp["norm2"], cfg)
@@ -587,6 +607,7 @@ def moe(bp, h, cfg: GraniteHybridConfig, grouped: bool, live=None):
         w = jnp.where(local < e, w, 0.0)
         routings = jnp.zeros((e + 1,), jnp.int32).at[
             local.reshape(-1)].add(1)[:e]
+        touched = jnp.sum(routings > 0).astype(jnp.int32)
     if cfg.d_shared:
         with jax.named_scope("moe.shared"):
             shared = _gated(u, bp["w_in_s"], bp["w_out_s"], dt_)
@@ -606,14 +627,19 @@ def moe(bp, h, cfg: GraniteHybridConfig, grouped: bool, live=None):
         else:
             weight = jnp.zeros((h.shape[0], e + 1), jnp.float32).at[
                 jnp.arange(h.shape[0])[:, None], local].add(w)[:, :e]
-            gv = _mm("td,edf->etf", u, bp["w_in_e"])
-            act = jax.nn.silu(gv[..., :f]) * gv[..., f:]
-            routed = _mm("etf,efd->td",
-                         (act * weight.T[:, :, None]).astype(dt_),
-                         bp["w_out_e"])
+            if impl == "dense":
+                routed = masked_experts(u, weight, bp["w_in_e"],
+                                        bp["w_out_e"])
+            else:
+                from mmlspark_tpu.parallel.pallas_moe import touched_experts
+                # the touched experts first, in order of place
+                ids = jnp.argsort(routings == 0, stable=True)
+                routed = touched_experts(
+                    u, weight, bp["w_in_e"], bp["w_out_e"], ids, touched,
+                    interpret=impl == "pallas_interpret")
     if cfg.d_shared:
         routed = routed + shared
-    return routed, routings, jnp.sum(routings > 0).astype(jnp.int32)
+    return routed, routings, touched
 
 
 def attention_dense(q, k, v, q_pos, scale: float):
@@ -726,12 +752,12 @@ def forward_logits(params, tokens, cfg: GraniteHybridConfig):
 
 
 def _ffn(bp, layer: int, h, cfg: GraniteHybridConfig, grouped: bool,
-         live=None):
+         live=None, impl: str = "dense"):
     """Layer ``layer``'s FFN: :func:`dense_ffn` for a leading dense
     layer (no routings: ``None``), else :func:`moe`."""
     if layer < cfg.n_dense_layers:
         return dense_ffn(bp, h, cfg), None, None
-    return moe(bp, h, cfg, grouped=grouped, live=live)
+    return moe(bp, h, cfg, grouped=grouped, live=live, impl=impl)
 
 
 # ---------------------------------------------------------------------------
@@ -867,7 +893,9 @@ def build_hybrid_step(cfg: GraniteHybridConfig, page_size: int,
     slot's position is at least one). ``routings`` int32[len(
     experts_held)] are the routings each held expert received over the
     layers, ``touched`` how many (layer, held expert) pairs received
-    any: the expert weights the step had to read."""
+    any: the expert weights the step had to read, and under an
+    ``attn_impl`` other than ``"dense"`` the ones it copied (the same
+    setting picks the attention kernel and the expert walk, :func:`moe`)."""
     page_size = int(page_size)
     rm = cfg.residual_multiplier
 
@@ -914,7 +942,8 @@ def build_hybrid_step(cfg: GraniteHybridConfig, page_size: int,
                 mixed, c["conv"][i], c["ssm"][i] = mamba_step(
                     bp, u, c["conv"][i], c["ssm"][i], cfg)
             x = x + rm * mixed
-            out, r, tch = _ffn(bp, layer, x, cfg, grouped=False, live=live)
+            out, r, tch = _ffn(bp, layer, x, cfg, grouped=False, live=live,
+                               impl=attn_impl)
             x = x + rm * out
             if r is not None:
                 routings, touched = routings + r, touched + tch

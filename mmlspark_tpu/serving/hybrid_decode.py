@@ -107,6 +107,10 @@ class HybridDecoder:
         self.n_state_resets = 0
         #: routings each held expert received, over every step so far
         self.expert_routings = np.zeros(len(cfg.experts_held), np.int64)
+        #: (MoE layer, held expert) pairs: what a step of the masked
+        #: form reads
+        self._pairs_held = ((cfg.n_layers - cfg.n_dense_layers)
+                            * len(cfg.experts_held))
         self._identity_tables = None
         if 1 + self.n_slots * self.pages_per_slot <= self.n_pages:
             self._identity_tables = (
@@ -179,14 +183,19 @@ class HybridDecoder:
         """A step's one copy back, ``[next tokens | routings |
         touched]`` -> the tokens; the ``decode.fetch`` span carries
         what the experts received: ``expert_routings`` (a held expert's
-        routings, the layers summed), ``expert_load_max`` and
+        routings, the layers summed), ``expert_load_max``,
         ``experts_touched`` ((layer, held expert) pairs that received
-        any)."""
+        any) and ``experts_read`` (the pairs whose weights the step
+        copied: the touched ones under the Pallas kernels, every held
+        expert of every MoE layer under the dense path)."""
         out, routings = np.split(fetched[:-1], [self.n_slots])
         self.expert_routings += routings
+        touched = int(fetched[-1])
         attrs.update(expert_routings=routings.tolist(),
                      expert_load_max=int(routings.max()),
-                     experts_touched=int(fetched[-1]))
+                     experts_touched=touched,
+                     experts_read=(touched if self.attn_impl != "dense"
+                                   else self._pairs_held))
         return out
 
     def n_compiles(self) -> int:

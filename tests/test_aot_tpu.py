@@ -75,6 +75,26 @@ def _n_mosaic(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _expert_walks(compiled) -> int:
+    """Mosaic calls of ``touched_experts``, the decode step's walk over
+    the experts its slots routed to (XLA names a Mosaic call after the
+    jitted function it came from)."""
+    return len(re.findall(r"%touched_experts\S* = .* custom-call\(",
+                          compiled.as_text()))
+
+
+def _dense_expert_reads(compiled, w_in_e) -> list:
+    """Lines of the optimized HLO, other than the held experts' own
+    parameters and the walks that take them whole, that hold a shape of
+    ``w_in_e`` ([E, D, 2F]): a fused masked product over every held
+    expert (the ``edf`` operand of ``td,edf->etf``) would."""
+    shape = "bf16[" + ",".join(map(str, w_in_e.shape)) + "]"
+    return [line for line in compiled.as_text().splitlines()
+            if shape in line and " parameter(" not in line
+            and "touched_experts" not in line
+            and not line.startswith(("HloModule", "ENTRY"))]
+
+
 def _flash_call_names(compiled):
     """Names of the Mosaic calls that are the flash forward kernel: XLA
     names the instruction after the jitted ``_flash_call``, and the
@@ -159,6 +179,29 @@ class TestKernels:
         fetch = PA.paged_fetch_pages(pps, PAGE, h_kv, d, dtype)
         assert 4 * fetch * PA._paged_page_vmem_bytes(
             PAGE, h_kv, d, dtype) <= PA._PAGED_VMEM_BUDGET
+
+    # the decode step's expert walk at the two hybrid cells' layers:
+    # (slots, d_model, expert width, experts held)
+    @pytest.mark.parametrize("t,d,f,e", [
+        (32, 2048, 1536, 64),    # lfm2-24b-a2b.assist-closed
+        (16, 4096, 768, 36),     # granite-4.0-h-small.rag-closed
+    ])
+    def test_touched_experts(self, topo, t, d, f, e):
+        """ONE Mosaic call named after the jitted function, the experts
+        left in HBM (no temporary), two chunks' copies inside the budget
+        the rule states and the kernel inside the scoped VMEM it asks
+        for (or the compile fails)."""
+        from mmlspark_tpu.parallel import pallas_moe as PM
+        bf = jnp.bfloat16
+        c = _compile_on_one(
+            topo, PM.touched_experts, ((t, d), bf), ((t, e), jnp.float32),
+            ((e, d, 2 * f), bf), ((e, f, d), bf), ((e,), jnp.int32),
+            ((), jnp.int32))
+        assert _n_mosaic(c) == _expert_walks(c) == 1
+        assert c.memory_analysis().temp_size_in_bytes == 0
+        chunk = PM.expert_chunk(d, f, 2)
+        assert 2 * 3 * d * chunk * 2 <= PM._MOE_VMEM_BUDGET
+        assert d * chunk * 2 == 3 * 2**20          # copies of 3 MiB
 
     @pytest.mark.parametrize("s", [8, 128, FULL.max_len])
     def test_flash_prefill_attention(self, topo, s):
@@ -502,8 +545,11 @@ class TestGraniteHybridPrograms:
     def test_step(self, shapes):
         """One token for 16 slots: the recurrent state and the K/V
         pools are updated where they lie (every cache byte aliased, no
-        temporary the size of a state), and the grouped-query paged
-        kernel is the one Mosaic call."""
+        temporary the size of a state), the grouped-query paged kernel
+        is one Mosaic call, and each of the ten expert layers one more,
+        the walk over the touched experts, with no masked product over
+        the 36 held left beside it (10.81 GB of arguments, 16 MB of
+        temporaries)."""
         GH, cfg, params, cache, i32 = shapes
         low = GH.build_hybrid_step(cfg, PAGE, attn_impl="pallas").lower(
             params, cache, i32(self.N_SLOTS), i32(self.N_SLOTS),
@@ -520,7 +566,10 @@ class TestGraniteHybridPrograms:
         assert mem.temp_size_in_bytes < 64 * 2**20
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             < 12 * 2**30
-        assert _n_mosaic(c) == 1
+        assert mem.argument_size_in_bytes < 10.82e9
+        assert _n_mosaic(c) == 1 + cfg.n_layers
+        assert _expert_walks(c) == cfg.n_layers
+        assert _dense_expert_reads(c, params["blocks"][0]["w_in_e"]) == []
         assert "paged_decode_attention" in c.as_text()
         # 4.76 B parameters in bfloat16 on this chip
         assert 9.4e9 < self._nbytes(params) < 9.6e9
@@ -589,10 +638,11 @@ class TestLfm2Programs:
 
     def test_step(self, shapes):
         """One token for 32 slots: the conv tails and the padded K/V
-        pools updated where they lie (12.69 GB of arguments, 9 MB of
-        temporaries), and ONE Mosaic call an attention layer, the walk
+        pools updated where they lie (12.69 GB of arguments, 11 MB of
+        temporaries), ONE Mosaic call an attention layer, the walk
         over the live entries (a grid of one step a slot, not one a
-        table entry)."""
+        table entry), and one a MoE layer, the walk over the touched
+        experts, with no masked product over all 64 left beside it."""
         GH, cfg, sv, params, cache, i32 = shapes
         # 5.27 B parameters in bfloat16 (the norms, taps, routers and
         # biases float32), a pool of 2 x 2 x 537 MB
@@ -606,8 +656,12 @@ class TestLfm2Programs:
         _, fetched, _, tokens = low.out_info
         assert fetched.shape == (n + 64 + 1,)
         c = low.compile()
-        self._check(c, params, cache, 256 * 2**20)
-        assert _n_mosaic(c) == 2
+        mem = self._check(c, params, cache, 256 * 2**20)
+        assert mem.argument_size_in_bytes < 12.69e9
+        n_moe = cfg.n_layers - cfg.n_dense_layers
+        assert _n_mosaic(c) == 2 + n_moe
+        assert _expert_walks(c) == n_moe == 8
+        assert _dense_expert_reads(c, params["blocks"][2]["w_in_e"]) == []
         assert "paged_decode_attention" in c.as_text()
         # a pool of 128-lane heads is what the walk takes (its grid is
         # (slots,): the kernel copies its own pages)
