@@ -3,7 +3,9 @@ layers, a routed mixture of gated experts with a shared expert behind
 every mixer; and, as a configuration of the same block, LFM2's layer
 (``lfm2_moe``): gated short convolutions beside grouped-query attention
 with rotary and QK-norm, sigmoid-routed experts with no shared expert,
-leading dense FFN layers.
+leading dense FFN layers; and Kimi-K2's (``kimi_k2``, DeepSeek-V3's
+layer): multi-head latent attention in every layer over a paged latent
+cache, a leading dense FFN, sigmoid-routed experts beside a shared one.
 
 A third block kind beside ``models/transformer.py``'s softmax block and
 ``models/evabyte.py``'s EVA block (``GraniteHybridConfig.block_kind ==
@@ -33,6 +35,13 @@ multiplier; the equations in full are at the head of
   heads, softmax scale ``attention_multiplier``; no positional encoding
   unless ``rope_theta`` (half-split rotary over the whole head), and
   with ``qk_norm`` a per-head RMSNorm of q and k before it.
+* **Latent attention layer** (``"mla"``, Kimi-K2's): ``c_q =
+  norm(W_DQ u)``, ``[q_nope | q_pe] = W_UQ c_q`` a head; ``[c_kv | k_pe]
+  = W_DKV u``, the latent normed, ONE rotary key for all heads; YaRN
+  over the rotary lanes (:func:`yarn_rope`); keys and values ``W_UK
+  c_kv`` / ``W_UV c_kv`` a head. A tile expands the lane's latents so
+  (:func:`_mla_attend`); a step folds ``W_UK`` into the query and
+  applies ``W_UV`` after the weighted sum of latents (:func:`mla_step`).
 * **FFN**: the first ``n_dense_layers`` layers one dense gated product
   of width ``d_dense`` (:func:`dense_ffn`), every later layer the
   experts.
@@ -68,8 +77,9 @@ position*: per attention layer one K and one V page pool ``[n_pages,
 page_size, n_kv_heads, lanes]`` in ``dtype`` (page 0 the scratch page;
 ``lanes`` is ``d_head``, or ``d_head`` zero-padded to whole 128-lane
 registers where the decoder asks for it so that ``paged_decode_attention``
-walks the live entries). Every array is a buffer of its own, donated
-through both programs:
+walks the live entries); per ``"mla"`` layer one latent pool
+``[n_pages, page_size, lanes]``, a row ``[c_kv | k_pe]`` a position.
+Every array is a buffer of its own, donated through both programs:
 
 ``build_hybrid_prefill``  one tile of a prompt (``tile`` tokens at
     ``pos0``, ``length`` of them real): carries the slot's state and
@@ -84,6 +94,7 @@ through both programs:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, List, Sequence, Tuple
 
 import jax
@@ -143,6 +154,28 @@ class GraniteHybridConfig:
     rope_theta: float = 0.0
     #: a per-head RMSNorm of q and k (``q_norm``/``k_norm``) before it
     qk_norm: bool = False
+    #: an ``"mla"`` layer's widths (multi-head latent attention): the
+    #: query's low rank, the latent's, a head's query/key lanes without
+    #: and with the rotary, and its value lanes
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    d_nope: int = 0
+    d_rope: int = 0
+    d_v: int = 0
+    #: YaRN's stretch of an ``"mla"`` layer's rotary (:func:`yarn_inv_freq`;
+    #: ``rope_factor`` 1 is the plain rotary)
+    rope_factor: float = 1.0
+    rope_orig_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    #: ``"sigmoid"``'s normaliser: the chosen weights over their sum
+    #: plus this
+    route_eps: float = 1e-6
+    #: the head is the embedding (tied), or a matrix of its own
+    #: (``params["head"]`` [D, vocab])
+    tied_head: bool = True
 
     #: what the serving plane reads to pick the decoder
     block_kind = "granite_hybrid"
@@ -150,9 +183,14 @@ class GraniteHybridConfig:
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
-        bad = set(self.layer_types) - {"mamba", "attention", "conv"}
+        bad = set(self.layer_types) - {"mamba", "attention", "conv", "mla"}
         if bad:
             raise ValueError(f"unknown layer types {sorted(bad)}")
+        if "mla" in self.layer_types and min(
+                self.q_lora_rank, self.kv_lora_rank, self.d_nope,
+                self.d_rope, self.d_v) < 1:
+            raise ValueError("an mla layer needs q_lora_rank, kv_lora_rank,"
+                             " d_nope, d_rope and d_v")
         if self.router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}")
         if not 0 <= self.n_dense_layers <= self.n_layers or (
@@ -173,11 +211,13 @@ class GraniteHybridConfig:
     @classmethod
     def from_hf(cls, hf: Dict[str, Any], experts_held: Sequence[int] = None,
                 dtype: str = "bfloat16") -> "GraniteHybridConfig":
-        """From a ``granitemoehybrid`` or an ``lfm2_moe``
+        """From a ``granitemoehybrid``, an ``lfm2_moe`` or a ``kimi_k2``
         ``config.json`` (its HF keys); ``experts_held`` defaults to all
         the routed experts."""
         if hf.get("model_type") == "lfm2_moe":
             return cls._from_lfm2(hf, experts_held, dtype)
+        if hf.get("model_type") == "kimi_k2":
+            return cls._from_kimi(hf, experts_held, dtype)
         if int(hf.get("mamba_n_groups", 1)) != 1:
             raise ValueError("one group of B and C only")
         n = int(hf["num_hidden_layers"])
@@ -238,9 +278,73 @@ class GraniteHybridConfig:
             logits_scaling=1.0, norm_eps=float(hf["norm_eps"]),
             dtype=dtype)
 
+    @classmethod
+    def _from_kimi(cls, hf: Dict[str, Any], experts_held, dtype: str
+                   ) -> "GraniteHybridConfig":
+        """DeepSeek-V3's layer as Kimi-K2 publishes it: multi-head latent
+        attention in every layer (``"mla"``) with YaRN over the rotary
+        lanes and the softmax scale times ``yarn_mscale(factor,
+        mscale_all_dim) ** 2``; the first ``first_k_dense_replace`` FFNs
+        dense, then sigmoid scores chosen with their
+        ``e_score_correction_bias`` (``noaux_tc`` over one group: the
+        top ``num_experts_per_tok`` of score + bias) beside
+        ``n_shared_experts`` shared ones; no multipliers."""
+        if (int(hf.get("n_group", 1)), int(hf.get("topk_group", 1))) \
+                != (1, 1) or hf.get("topk_method") != "noaux_tc" \
+                or hf.get("scoring_func") != "sigmoid" \
+                or int(hf.get("moe_layer_freq", 1)) != 1:
+            raise ValueError("one group of sigmoid-scored experts chosen "
+                             "by noaux_tc, in every layer, only")
+        rs = hf.get("rope_scaling") or {}
+        if rs and rs.get("type", rs.get("rope_type")) != "yarn":
+            raise ValueError("a YaRN rope_scaling, or none")
+        n = int(hf["num_hidden_layers"])
+        d_nope, d_rope = int(hf["qk_nope_head_dim"]), int(
+            hf["qk_rope_head_dim"])
+        factor = float(rs.get("factor", 1.0))
+        m_all = float(rs.get("mscale_all_dim", 0.0))
+        m = yarn_mscale(factor, m_all)
+        n_experts = int(hf["n_routed_experts"])
+        return cls(
+            vocab=int(hf["vocab_size"]), d_model=int(hf["hidden_size"]),
+            n_heads=int(hf["num_attention_heads"]),
+            n_kv_heads=int(hf["num_attention_heads"]),
+            d_head=int(hf["v_head_dim"]), layer_types=("mla",) * n,
+            n_experts=n_experts, top_k=int(hf["num_experts_per_tok"]),
+            experts_held=tuple(range(n_experts) if experts_held is None
+                               else experts_held),
+            d_expert=int(hf["moe_intermediate_size"]),
+            d_shared=(int(hf["moe_intermediate_size"])
+                      * int(hf.get("n_shared_experts") or 0)),
+            n_dense_layers=min(int(hf["first_k_dense_replace"]), n),
+            d_dense=int(hf["intermediate_size"]), router="sigmoid",
+            expert_bias=True, norm_topk=bool(hf["norm_topk_prob"]),
+            routed_scaling=float(hf["routed_scaling_factor"]),
+            route_eps=1e-20, rope_theta=float(hf["rope_theta"]),
+            q_lora_rank=int(hf["q_lora_rank"]),
+            kv_lora_rank=int(hf["kv_lora_rank"]), d_nope=d_nope,
+            d_rope=d_rope, d_v=int(hf["v_head_dim"]), rope_factor=factor,
+            rope_orig_len=int(rs.get("original_max_position_embeddings",
+                                     0)),
+            beta_fast=float(rs.get("beta_fast", 32)),
+            beta_slow=float(rs.get("beta_slow", 1)),
+            yarn_mscale=float(rs.get("mscale", 1.0)),
+            yarn_mscale_all_dim=m_all,
+            tied_head=bool(hf.get("tie_word_embeddings", False)),
+            embedding_multiplier=1.0, residual_multiplier=1.0,
+            attention_multiplier=(d_nope + d_rope) ** -0.5 * m * m,
+            logits_scaling=1.0, norm_eps=float(hf["rms_norm_eps"]),
+            dtype=dtype)
+
     @property
     def n_layers(self) -> int:
         return len(self.layer_types)
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes of an ``"mla"`` layer's cache row: the normed latent,
+        then the turned rotary key all heads share."""
+        return self.kv_lora_rank + self.d_rope
 
     @property
     def d_inner(self) -> int:
@@ -304,10 +408,10 @@ def init_params(cfg: GraniteHybridConfig, seed: int = 0) -> Dict[str, Any]:
                 w_out_e=matrix(ks[2], (e, cfg.d_expert, d), cfg.d_expert))
             if cfg.expert_bias:
                 bp["expert_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
-        if cfg.d_shared:
-            bp.update(
-                w_in_s=matrix(ks[3], (d, 2 * cfg.d_shared), d),
-                w_out_s=matrix(ks[4], (cfg.d_shared, d), cfg.d_shared))
+            if cfg.d_shared:
+                bp.update(
+                    w_in_s=matrix(ks[3], (d, 2 * cfg.d_shared), d),
+                    w_out_s=matrix(ks[4], (cfg.d_shared, d), cfg.d_shared))
         if kind == "attention":
             hd = cfg.n_heads * cfg.d_head
             bp.update(
@@ -319,6 +423,17 @@ def init_params(cfg: GraniteHybridConfig, seed: int = 0) -> Dict[str, Any]:
             if cfg.qk_norm:
                 bp.update(q_norm=jnp.ones((cfg.d_head,), jnp.float32),
                           k_norm=jnp.ones((cfg.d_head,), jnp.float32))
+        elif kind == "mla":
+            h_, r, rq = cfg.n_heads, cfg.kv_lora_rank, cfg.q_lora_rank
+            bp.update(
+                wq_a=matrix(ks[5], (d, rq), d),
+                q_a_norm=jnp.ones((rq,), jnp.float32),
+                wq_b=matrix(ks[6], (rq, h_, cfg.d_nope + cfg.d_rope), rq),
+                wkv_a=matrix(ks[7], (d, cfg.latent_lanes), d),
+                kv_a_norm=jnp.ones((r,), jnp.float32),
+                w_uk=matrix(ks[9], (r, h_, cfg.d_nope), r),
+                w_uv=matrix(ks[10], (r, h_, cfg.d_v), r),
+                wo=matrix(ks[8], (h_, cfg.d_v, d), h_ * cfg.d_v))
         elif kind == "conv":
             bp.update(
                 w_in=matrix(ks[5], (d, 3 * d), d),
@@ -344,8 +459,12 @@ def init_params(cfg: GraniteHybridConfig, seed: int = 0) -> Dict[str, Any]:
         blocks.append(bp)
     embed = cfg.embed_std * jax.random.normal(
         jax.random.fold_in(root, 0), (cfg.vocab, d), jnp.float32)
-    return {"embed": embed.astype(dt_),
-            "final_norm": jnp.ones((d,), jnp.float32), "blocks": blocks}
+    params = {"embed": embed.astype(dt_),
+              "final_norm": jnp.ones((d,), jnp.float32), "blocks": blocks}
+    if not cfg.tied_head:
+        params["head"] = matrix(jax.random.fold_in(root, 1),
+                                (d, cfg.vocab), d)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -540,15 +659,16 @@ def dense_ffn(bp, h, cfg: GraniteHybridConfig):
 
 
 def sigmoid_route(logits, bias, cfg: GraniteHybridConfig):
-    """LFM2's router: ``s = sigmoid(logits)``; the ``top_k`` of ``s +
-    bias`` (``bias`` None: of ``s``) are chosen and weighted by their
-    UNBIASED ``s``, over their sum + 1e-6 where ``norm_topk``, times
+    """LFM2's and DeepSeek-V3's router: ``s = sigmoid(logits)``; the
+    ``top_k`` of ``s + bias`` (``bias`` None: of ``s``) are chosen and
+    weighted by their UNBIASED ``s``, over their sum + ``route_eps``
+    (LFM2's 1e-6, DeepSeek-V3's 1e-20) where ``norm_topk``, times
     ``routed_scaling``. Returns ``(idx, w)`` [T, top_k]."""
     s = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(s if bias is None else s + bias, cfg.top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if cfg.norm_topk:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.route_eps)
     return idx, w * cfg.routed_scaling
 
 
@@ -668,6 +788,169 @@ def rope(x, pos, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude: ``0.1 mscale ln(factor) + 1``, and 1 where the
+    rotary is not stretched."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: GraniteHybridConfig) -> np.ndarray:
+    """An ``"mla"`` layer's ``d_rope / 2`` rotary frequencies (float32,
+    a constant of the program): ``f_extra(i) = rope_theta ** (-2 i /
+    d_rope)`` for the pairs that turn fast, ``f_extra / rope_factor``
+    for those that turn slowly, and a linear ramp between pair ``low``
+    and pair ``high``, the pairs that turn ``beta_fast`` and
+    ``beta_slow`` times over ``rope_orig_len`` positions (floored and
+    ceiled, clamped to ``[0, d_rope - 1]``)."""
+    d, base = cfg.d_rope, cfg.rope_theta
+    extra = base ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if cfg.rope_factor <= 1:
+        return extra.astype(np.float32)
+
+    def pair(turns):
+        return d * math.log(cfg.rope_orig_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair(cfg.beta_fast)), 0)
+    high = min(math.ceil(pair(cfg.beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3),
+                   0.0, 1.0)
+    return (extra / cfg.rope_factor * ramp + extra * (1.0 - ramp)
+            ).astype(np.float32)
+
+
+def yarn_rope(x, pos, cfg: GraniteHybridConfig):
+    """``x`` [T, ..., d_rope] float32 at positions ``pos`` [T], turned as
+    DeepSeek-V3's published code turns it: the lanes de-interleaved
+    (pair ``(2 i, 2 i + 1)`` to ``(i, i + d_rope / 2)``), then the
+    half-split rotary at :func:`yarn_inv_freq`, cos and sin times
+    ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)``. The result stays de-interleaved, queries and keys
+    alike, so that every score is the published one."""
+    d = x.shape[-1]
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1)
+    ang = pos.astype(jnp.float32)[:, None] * jnp.asarray(yarn_inv_freq(cfg))
+    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + (d // 2,))
+    mag = yarn_mscale(cfg.rope_factor, cfg.yarn_mscale) / yarn_mscale(
+        cfg.rope_factor, cfg.yarn_mscale_all_dim)
+    cos, sin = mag * jnp.cos(ang), mag * jnp.sin(ang)
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def _mla_q(bp, u, pos, cfg: GraniteHybridConfig):
+    """``u`` [T, D] at ``pos`` [T] -> the queries, float32: ``q_nope``
+    [T, H, d_nope] and the turned ``q_pe`` [T, H, d_rope] (``c_q =
+    norm(W_DQ u)``, ``[q_nope | q_pe] = W_UQ c_q`` a head)."""
+    dt_ = cfg.compute_dtype
+    with jax.named_scope("mla.q"):
+        c_q = _norm(_mm("td,dr->tr", u.astype(dt_), bp["wq_a"]),
+                    bp["q_a_norm"], cfg)
+        q = _mm("tr,rhk->thk", c_q.astype(dt_), bp["wq_b"])
+        return q[..., :cfg.d_nope], yarn_rope(q[..., cfg.d_nope:], pos, cfg)
+
+
+def _mla_rows(bp, u, pos, cfg: GraniteHybridConfig):
+    """``u`` [T, D] at ``pos`` [T] -> its cache rows [T, latent_lanes]
+    in ``dtype``: ``[norm(c_kv) | rope(k_pe)]``, ``[c_kv | k_pe] =
+    W_DKV u``; one rotary key for all the heads."""
+    dt_, r = cfg.compute_dtype, cfg.kv_lora_rank
+    with jax.named_scope("mla.q"):
+        kv = _mm("td,dc->tc", u.astype(dt_), bp["wkv_a"])
+        return jnp.concatenate(
+            [_norm(kv[:, :r], bp["kv_a_norm"], cfg),
+             yarn_rope(kv[:, r:], pos, cfg)], -1).astype(dt_)
+
+
+def _mla_out(bp, a, cfg: GraniteHybridConfig):
+    with jax.named_scope("mla.out"):
+        return _mm("thv,hvd->td", a.astype(cfg.compute_dtype), bp["wo"])
+
+
+def _mla_chunk(bp, q_nope, q_pe, lat, q_pos, k0, carry,
+               cfg: GraniteHybridConfig):
+    """One chunk of cache rows ``lat`` [S, lanes] (positions
+    ``k0 + arange(S)``) into the online softmax ``carry = (m, l, acc)``
+    ([H, T], [H, T], [H, T, d_v] float32) of the queries ``q_nope`` /
+    ``q_pe`` [T, H, .] in ``dtype`` at ``q_pos`` [T], causally: the
+    chunk's latents up through ``W_UK`` / ``W_UV`` to per-head keys and
+    values first (the expanded form)."""
+    dt_, r = cfg.compute_dtype, cfg.kv_lora_rank
+    with jax.named_scope("mla.expand"):
+        k_nope = _mm("sc,chk->shk", lat[:, :r], bp["w_uk"]).astype(dt_)
+        v = _mm("sc,chv->shv", lat[:, :r], bp["w_uv"]).astype(dt_)
+    with jax.named_scope("mla.latent_attn"):
+        m, l, acc = carry
+        s = (_mm("thk,shk->hts", q_nope, k_nope)
+             + _mm("thk,sk->hts", q_pe, lat[:, r:r + cfg.d_rope])
+             ) * cfg.attention_multiplier
+        seen = q_pos[:, None] >= k0 + jnp.arange(lat.shape[0])[None, :]
+        s = jnp.where(seen[None], s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1),
+                acc * alpha[..., None]
+                + _mm("hts,shv->htv", p.astype(dt_), v))
+
+
+def _mla_attend(bp, u, q_pos, chunk_rows, n_chunks,
+                cfg: GraniteHybridConfig):
+    """The expanded latent attention of ``u`` [T, D] at ``q_pos`` over
+    the first ``n_chunks`` chunks of ``T`` cache rows, chunk ``j``'s
+    rows [T, lanes] (positions ``j T + arange(T)``) read by
+    ``chunk_rows(j)`` when the chunk is reached (``n_chunks`` a traced
+    count: the chunks past the last query's position are not looked
+    at) -> the layer's output [T, D]."""
+    t, h, dt_ = u.shape[0], cfg.n_heads, cfg.compute_dtype
+    q_nope, q_pe = _mla_q(bp, u, q_pos, cfg)
+    q_nope, q_pe = q_nope.astype(dt_), q_pe.astype(dt_)
+
+    def chunk(j, carry):
+        return _mla_chunk(bp, q_nope, q_pe, chunk_rows(j), q_pos, j * t,
+                          carry, cfg)
+
+    f32 = jnp.float32
+    _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, chunk, (jnp.full((h, t), _NEG_INF, f32),
+                             jnp.zeros((h, t), f32),
+                             jnp.zeros((h, t, cfg.d_v), f32)))
+    return _mla_out(bp, (acc / l[..., None]).transpose(1, 0, 2), cfg)
+
+
+def mla_step(bp, u, pos, pool, page, row, page_tables,
+             cfg: GraniteHybridConfig, page_size: int, impl: str):
+    """The latent attention for one token of every slot, absorbed:
+    ``W_UK`` folded into the query (``q_lat = W_UK^T q_nope``, [n, H,
+    kv_lora_rank]), the token's row written to row ``row`` of page
+    ``page``, the query ``[q_lat | q_pe]`` scored against the slot's
+    latent rows as they lie, and ``W_UV`` applied after the weighted sum
+    of latents. A pool of more lanes than ``latent_lanes`` holds the row
+    zero-padded, and the query alike. Returns ``(out [n, D], pool)``."""
+    dt_, r = cfg.compute_dtype, cfg.kv_lora_rank
+    q_nope, q_pe = _mla_q(bp, u, pos, cfg)
+    rows = _mla_rows(bp, u, pos, cfg)
+    lanes = pool.shape[-1]
+    with jax.named_scope("mla.q"):
+        q = _lanes(jnp.concatenate(
+            [_mm("thk,chk->thc", q_nope.astype(dt_), bp["w_uk"]), q_pe],
+            -1).astype(dt_), lanes)
+    with jax.named_scope("mla.latent_write"):
+        pool = pool.at[page, row].set(_lanes(rows, lanes))
+    from mmlspark_tpu.parallel import pallas_mla
+    with jax.named_scope("mla.latent_attn"):
+        if impl == "dense":
+            o = pallas_mla.latent_decode_attention_dense(
+                q, pool, page_tables, pos, cfg.attention_multiplier, r)
+        else:
+            o = pallas_mla.paged_latent_decode_attention(
+                q, pool, page_tables, pos, cfg.attention_multiplier,
+                page_size, r, interpret=impl == "pallas_interpret")
+    with jax.named_scope("mla.out"):
+        a = _mm("thc,chv->thv", o.astype(dt_), bp["w_uv"])
+    return _mla_out(bp, a, cfg), pool
+
+
 def _qkv(bp, u, cfg: GraniteHybridConfig, pos=None):
     """q, k, v [T, H(_kv), Dh] in ``dtype``; with ``qk_norm`` / a
     ``rope_theta``, q and k are normed / turned (at ``pos`` [T]) in
@@ -712,9 +995,12 @@ def _embed(params, tokens, cfg: GraniteHybridConfig):
 
 
 def _head(params, x, cfg: GraniteHybridConfig):
-    """``x`` [T, D] float32 -> float32 logits [T, vocab] (tied)."""
+    """``x`` [T, D] float32 -> float32 logits [T, vocab] (the embedding
+    where ``tied_head``, else ``params["head"]``)."""
     h = _norm(x, params["final_norm"], cfg).astype(cfg.compute_dtype)
     with jax.named_scope("head"):
+        if not cfg.tied_head:
+            return _mm("td,dv->tv", h, params["head"]) / cfg.logits_scaling
         return _mm("td,vd->tv", h, params["embed"]) / cfg.logits_scaling
 
 
@@ -725,7 +1011,8 @@ def _head(params, x, cfg: GraniteHybridConfig):
 def forward_logits(params, tokens, cfg: GraniteHybridConfig):
     """``tokens`` [S] -> float32 logits [S, vocab] of the whole
     sequence: every Mamba and conv layer from a zero state, the
-    attention dense over the sequence's own rows."""
+    attention dense over the sequence's own rows (an ``"mla"`` layer's
+    expanded, in one chunk)."""
     s_len = tokens.shape[0]
     rm = cfg.residual_multiplier
     x = _embed(params, tokens, cfg)
@@ -741,6 +1028,10 @@ def forward_logits(params, tokens, cfg: GraniteHybridConfig):
                 a = attention_dense(q, k, v, jnp.arange(s_len),
                                     cfg.attention_multiplier)
             mixed = _attn_out(bp, a, cfg)
+        elif kind == "mla":
+            rows = _mla_rows(bp, u, jnp.arange(s_len), cfg)
+            mixed = _mla_attend(bp, u, jnp.arange(s_len), lambda j: rows,
+                                1, cfg)
         elif kind == "conv":
             mixed, _ = conv_tile(bp, u, jnp.zeros(
                 (cfg.conv_width - 1, cfg.d_model), jnp.float32), s_len, cfg)
@@ -765,18 +1056,23 @@ def _ffn(bp, layer: int, h, cfg: GraniteHybridConfig, grouped: bool,
 
 
 def init_cache(cfg: GraniteHybridConfig, n_slots: int, n_pages: int,
-               page_size: int, head_lanes: int = None
-               ) -> Dict[str, List[jax.Array]]:
+               page_size: int, head_lanes: int = None,
+               latent_lanes: int = None) -> Dict[str, List[jax.Array]]:
     """``{"ssm", "conv"}``: one array a Mamba layer, a slot's recurrent
     state and the rows its convolution still needs; ``{"tail"}`` (where
     there are conv layers): one array a conv layer, the ``v`` rows its
     convolution still needs; ``{"k", "v"}``: one page pool an attention
     layer (page 0 the scratch page), ``head_lanes`` (default
-    ``d_head``) lanes a head, the lanes past ``d_head`` zeros. Lists, so
-    that every array is a buffer of its own."""
+    ``d_head``) lanes a head, the lanes past ``d_head`` zeros;
+    ``{"lat"}`` (where there are ``"mla"`` layers): one latent page pool
+    ``[n_pages, page_size, lanes]`` an ``"mla"`` layer, a row a
+    position (the normed latent, then the turned rotary key), ``lanes``
+    ``latent_lanes`` (default ``cfg.latent_lanes``), the lanes past it
+    zeros. Lists, so that every array is a buffer of its own."""
     n_mamba = sum(k == "mamba" for k in cfg.layer_types)
     n_conv = sum(k == "conv" for k in cfg.layer_types)
-    n_attn = cfg.n_layers - n_mamba - n_conv
+    n_mla = sum(k == "mla" for k in cfg.layer_types)
+    n_attn = sum(k == "attention" for k in cfg.layer_types)
     pool = (int(n_pages), int(page_size), cfg.n_kv_heads,
             int(head_lanes or cfg.d_head))
     cache = {
@@ -791,6 +1087,11 @@ def init_cache(cfg: GraniteHybridConfig, n_slots: int, n_pages: int,
         cache["tail"] = [jnp.zeros((n_slots, cfg.conv_width - 1,
                                     cfg.d_model), jnp.float32)
                          for _ in range(n_conv)]
+    if n_mla:
+        cache["lat"] = [jnp.zeros((int(n_pages), int(page_size),
+                                   int(latent_lanes or cfg.latent_lanes)),
+                                  cfg.compute_dtype)
+                        for _ in range(n_mla)]
     return cache
 
 
@@ -858,6 +1159,27 @@ def build_hybrid_prefill(cfg: GraniteHybridConfig, page_size: int,
                             interpret=attn_impl == "pallas_interpret",
                             q_offset=pos0)[0]
                 mixed = _attn_out(bp, a, cfg)
+            elif kind == "mla":
+                q_pos = pos0 + jnp.arange(t)
+                lanes = c["lat"][i].shape[-1]
+                rows = _lanes(_mla_rows(bp, u, q_pos, cfg), lanes)
+                with jax.named_scope("mla.latent_write"):
+                    pages = jax.lax.dynamic_slice_in_dim(
+                        page_table, pos0 // page_size, n_chunks)
+                    c["lat"][i] = c["lat"][i].at[pages].set(
+                        rows.reshape(n_chunks, page_size, lanes))
+                # the lane so far, a tile of rows a chunk, each chunk's
+                # pages gathered when it is reached: the chunks past
+                # this tile's are not looked at
+                pool = c["lat"][i]
+
+                def chunk_rows(j, pool=pool):
+                    return pool[jax.lax.dynamic_slice_in_dim(
+                        page_table, j * n_chunks, n_chunks)].reshape(
+                            t, lanes)
+
+                mixed = _mla_attend(bp, u, q_pos, chunk_rows,
+                                    pos0 // t + 1, cfg)
             elif kind == "conv":
                 tail = jnp.where(fresh, 0.0, c["tail"][i][slot])
                 mixed, tail = conv_tile(bp, u, tail, length, cfg)
@@ -888,8 +1210,9 @@ def build_hybrid_step(cfg: GraniteHybridConfig, page_size: int,
     touched]``; ``next_tokens`` alone is what a step dispatched before
     this one is fetched takes, as it lies on the device. Every slot's state is advanced once where it lies (the
     cache is donated); the attention layers' new K/V row goes to row
-    ``pos`` of the slot's lane. Free slots ride along at position 0
-    with an all-scratch table; their routings are not counted (a live
+    ``pos`` of the slot's lane, an ``"mla"`` layer's latent row
+    likewise (:func:`mla_step`, the absorbed form). Free slots ride
+    along at position 0 with an all-scratch table; their routings are not counted (a live
     slot's position is at least one). ``routings`` int32[len(
     experts_held)] are the routings each held expert received over the
     layers, ``touched`` how many (layer, held expert) pairs received
@@ -936,6 +1259,10 @@ def build_hybrid_step(cfg: GraniteHybridConfig, page_size: int,
                 if lanes != cfg.d_head:
                     a = a[..., :cfg.d_head]
                 mixed = _attn_out(bp, a, cfg)
+            elif kind == "mla":
+                mixed, c["lat"][i] = mla_step(
+                    bp, u, pos, c["lat"][i], page, row, page_tables, cfg,
+                    page_size, attn_impl)
             elif kind == "conv":
                 mixed, c["tail"][i] = conv_step(bp, u, c["tail"][i], cfg)
             else:

@@ -5,14 +5,18 @@
 cache of TWO parts.
 
 *Rows a position*: the attention layers' K/V rows, in one page pool a
-layer with ``n_kv_heads`` heads. These are what :meth:`rows_at`,
+layer with ``n_kv_heads`` heads, and the ``"mla"`` layers' latent rows
+(the normed latent and the rotary key all heads share, one pool a layer,
+the same pages). These are what :meth:`rows_at`,
 :meth:`pages_for` and :meth:`prefill_pages` count, and what the
 scheduler's ``PagePool`` claims and gives back. *A state a slot*: each
 Mamba layer's recurrent state and conv tail, each conv layer's tail of
 ``v`` rows, one fixed-size entry a slot, overwritten by every step and
 every tile. The scheduler counts nothing of it: it is the slot's, and a
 request's first prefill tile starts it from zeros whatever the slot's
-last request left (``n_state_resets``).
+last request left (``n_state_resets``). A configuration of ``"mla"``
+(and attention) layers alone has no such state (``has_slot_state`` is
+false).
 
 A prompt is walked tile by tile inside :meth:`prefill_logits`: ONE tile
 program (``prefill_tile`` tokens, the last tile padded), the state
@@ -24,7 +28,9 @@ whose ``block_kind`` is ``"granite_hybrid"``. No prefix cache and no
 speculation for this kind (``has_prefix_prefill`` / ``has_draft`` are
 false): a shared prefix would need the state as it stood at the
 prefix's end (a snapshot a cached prefix), a rejected draft the state
-before it (a rollback); neither is built (ROADMAP B8).
+before it (a rollback); neither is built (ROADMAP B8). A stack of
+latent layers alone holds no such state, but the prefix cache over
+latent pages is not built either (ROADMAP B3).
 """
 
 from __future__ import annotations
@@ -48,9 +54,6 @@ class HybridDecoder:
     #: this kind's rows are of one kind, kept for ever (no window)
     window: Optional[int] = None
     n_compactions = 0
-    #: a slot holds state that is not rows (``decode.prepare`` stamps
-    #: how many slots do)
-    has_slot_state = True
 
     def __init__(self, params, cfg, n_slots: int = 16,
                  max_len: int = 10240, eos_id: Optional[int] = None,
@@ -90,6 +93,11 @@ class HybridDecoder:
             attn_impl = "pallas" if paged_attention_available() else "dense"
         self.attn_impl = attn_impl
         self.params = params
+        #: a slot holds state that is not rows, where there are Mamba or
+        #: conv layers (``decode.prepare`` stamps how many slots do)
+        self.has_slot_state = any(k in ("mamba", "conv")
+                                  for k in cfg.layer_types)
+        self._n_mla = sum(k == "mla" for k in cfg.layer_types)
         # the kernel walks a slot's live entries only at whole 128-lane
         # heads: a narrower head (LFM2's 64) is kept zero-padded to them
         lanes = cfg.d_head
@@ -97,8 +105,14 @@ class HybridDecoder:
             from mmlspark_tpu.parallel.pallas_attention import (
                 paged_head_lanes)
             lanes = paged_head_lanes(cfg.d_head, cfg.n_kv_heads)
+        # the latent kernel's copies take a row of whole 128-lane
+        # registers: Kimi-K2's 576-lane row is kept zero-padded to 640
+        lat_lanes = None
+        if self._n_mla and attn_impl != "dense":
+            from mmlspark_tpu.parallel.pallas_mla import latent_pool_lanes
+            lat_lanes = latent_pool_lanes(cfg.latent_lanes)
         self.cache = GH.init_cache(cfg, self.n_slots, self.n_pages, ps,
-                                   head_lanes=lanes)
+                                   head_lanes=lanes, latent_lanes=lat_lanes)
         self._prefill = GH.build_hybrid_prefill(cfg, ps, donate=donate,
                                                 attn_impl=attn_impl)
         self._step = GH.build_hybrid_step(cfg, ps, donate=donate,
@@ -141,9 +155,17 @@ class HybridDecoder:
     _warm_step = TransformerDecoder._warm_step
 
     def prefill_facts(self, prompt_len: int) -> Dict[str, int]:
-        """What a ``decode.prefill`` span carries for this kind."""
-        return {"tiles": -(-int(prompt_len) // self.prefill_tile),
-                "prompt_tokens": int(prompt_len)}
+        """What a ``decode.prefill`` span carries for this kind; with
+        ``"mla"`` layers also ``latent_rows_expanded``, the lane rows
+        the walk pushes up through ``W_UK`` / ``W_UV`` in one such
+        layer (tile ``k`` expands the ``k + 1`` tiles of rows up to its
+        own end)."""
+        tiles = -(-int(prompt_len) // self.prefill_tile)
+        facts = {"tiles": tiles, "prompt_tokens": int(prompt_len)}
+        if self._n_mla:
+            facts["latent_rows_expanded"] = (
+                self.prefill_tile * tiles * (tiles + 1) // 2)
+        return facts
 
     # -- shapes ----------------------------------------------------------
 

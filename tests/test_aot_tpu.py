@@ -775,3 +775,117 @@ class TestOuroPrograms:
         self._check(c, cfg, params, cache, 512 * 2**20)
         assert len(_flash_call_names(c)) == cfg.n_layers
         assert "looped_prefill" in c.as_text()
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention at the shapes of the cell
+# kimi-k2.6.think-closed (benchmark/configs/kimi-k2.6.json): the latent
+# kernel alone, and the two programs the decoder compiles
+
+
+class TestKimiPrograms:
+    """Six latent-attention layers (one dense, five of 12 held experts of
+    384 and a shared one) over a latent pool of 64 slots x 12,288
+    positions: 8.37 GB of weights and 6.04 GB of pool, updated where it
+    lies, leave the chip room for the programs' temporaries; the step
+    holds one latent kernel a layer and one expert walk a MoE layer."""
+
+    HBM = 15.75e9
+
+    @pytest.fixture(scope="class")
+    def shapes(self, topo):
+        import json
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        sys.path.insert(0, os.path.join(root, "benchmark"))
+        import reference_kimi as RK
+        from mmlspark_tpu.models import granite_hybrid as GH
+        from mmlspark_tpu.parallel.pallas_mla import latent_pool_lanes
+        with open(os.path.join(root, "benchmark", "configs",
+                               "kimi-k2.6.json")) as f:
+            hf = json.load(f)
+        sv = hf["serve"]
+        m = RK.Model.from_config(hf)
+        cfg = GH.GraniteHybridConfig.from_hf(
+            RK.program_hf(hf), experts_held=m.experts_held,
+            dtype=sv["dtype"])
+        one = NamedSharding(_mesh(topo, {"x": 1}), P())
+        # the weights as the driver hands them over
+        params = _abstract(jax.eval_shape(
+            lambda: RK.make_params(m, 0, jnp.bfloat16)), one)
+        cache = _abstract(jax.eval_shape(lambda: GH.init_cache(
+            cfg, sv["n_slots"], sv["n_pages"], sv["page_size"],
+            latent_lanes=latent_pool_lanes(cfg.latent_lanes))), one)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one)
+
+        return GH, cfg, sv, params, cache, i32
+
+    @staticmethod
+    def _nbytes(tree) -> int:
+        return sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                   for x in jax.tree.leaves(tree))
+
+    def _check(self, c, cache, temp_limit):
+        mem = c.memory_analysis()
+        assert mem.alias_size_in_bytes == self._nbytes(cache)
+        assert mem.temp_size_in_bytes < temp_limit
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < self.HBM
+        assert not _pool_sized_moves(c.as_text(),
+                                     int(np.prod(cache["lat"][0].shape)))
+        return mem
+
+    def test_latent_kernel(self, topo):
+        """ONE Mosaic call named after the jitted function, the pool left
+        in HBM (no temporary), two fetches of 32 pages of 640 lanes
+        inside the budget; a 576-lane row is refused by Mosaic, which
+        is why the pool keeps 640."""
+        from mmlspark_tpu.parallel import pallas_mla as PM
+        n, pps, h = 64, 768, 64
+        lanes = PM.latent_pool_lanes(576)
+        c = _compile_on_one(
+            topo, functools.partial(PM.paged_latent_decode_attention,
+                                    scale=0.1447, page_size=PAGE, d_lat=512),
+            ((n, h, lanes), jnp.bfloat16),
+            ((1 + n * pps, PAGE, lanes), jnp.bfloat16),
+            ((n, pps), jnp.int32), ((n,), jnp.int32))
+        assert _n_mosaic(c) == 1 and lanes == 640
+        assert c.memory_analysis().temp_size_in_bytes == 0
+        assert len(re.findall(r"%(\S*paged_latent_decode_attention\S*) = "
+                              r".* custom-call\(", c.as_text())) == 1
+        fetch = PM.latent_fetch_pages(pps, PAGE, lanes, jnp.bfloat16)
+        assert fetch == 32
+        assert 2 * fetch * PAGE * lanes * 2 <= PM._LATENT_VMEM_BUDGET
+
+    def test_step(self, shapes):
+        """One token for 64 slots: 14.41 GB of arguments and some 13 MB of
+        temporaries, the six pools updated where they lie, six latent
+        kernels and five expert walks."""
+        GH, cfg, sv, params, cache, i32 = shapes
+        assert 8.36e9 < self._nbytes(params) < 8.38e9
+        assert cache["lat"][0].shape == (49153, 16, 640)
+        assert 6.03e9 < self._nbytes(cache) < 6.05e9
+        n, pps = sv["n_slots"], sv["max_len"] // sv["page_size"]
+        c = GH.build_hybrid_step(cfg, sv["page_size"],
+                                 attn_impl="pallas").lower(
+            params, cache, i32(n), i32(n), i32(n, pps)).compile()
+        self._check(c, cache, 64 * 2**20)
+        text = c.as_text()
+        assert len(re.findall(r"%paged_latent_decode_attention\S* = ",
+                              text)) == 6
+        assert _expert_walks(c) == 5
+
+    def test_prefill_tile(self, shapes):
+        """A 512-token tile: the lane expanded a tile of rows at a time
+        inside one loop a layer (no flash kernel), the experts as grouped
+        products, some 150 MB of temporaries."""
+        GH, cfg, sv, params, cache, i32 = shapes
+        pps = sv["max_len"] // sv["page_size"]
+        c = GH.build_hybrid_prefill(cfg, sv["page_size"],
+                                    attn_impl="pallas").lower(
+            params, cache, i32(sv["prefill_tile"]), i32(pps), i32(), i32(),
+            i32()).compile()
+        self._check(c, cache, 512 * 2**20)
+        assert not _flash_call_names(c)
+        assert "paged_latent_decode_attention" not in c.as_text()

@@ -31,6 +31,13 @@ SCOPES = ("embed", "norm", "attn.qkv", "attn.core", "attn.out", "ffn",
           # the leading dense FFN, and the two parts of attn.qkv
           "conv.in", "conv.core", "conv.out", "ffn.dense",
           "attn.qknorm", "attn.rope",
+          # multi-head latent attention (Kimi-K2's "mla" layers): the
+          # down/up projections and the absorption, the latent row's
+          # write, the attention over latent rows (the step's kernel or
+          # its twin, the tile's chunks), the tile's expansion of the
+          # lane through W_UK / W_UV, and W_UV / W_O after
+          "mla.q", "mla.latent_write", "mla.latent_attn", "mla.expand",
+          "mla.out",
           # a looped stack's programs (jit_looped_step / _prefill): the
           # norm between passes and the exit gate; the layers' parts
           # keep the block's names under while/body
