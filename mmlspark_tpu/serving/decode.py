@@ -390,6 +390,12 @@ class TransformerDecoder:
         passes each makes."""
         return {"prompt_tokens": int(prompt_len), "loops": self.n_loops}
 
+    def prepare_facts(self, pos) -> Dict[str, int]:
+        """What a ``decode.prepare`` span carries beyond the scheduler's
+        own attributes, for a step at the live slots' positions
+        ``pos``: nothing, for this kind."""
+        return {}
+
     #: the last prefill's / step's expected exit passes (a looped
     #: stack: None / an array a slot)
     prefill_exit_pass: Optional[float] = None
@@ -2790,6 +2796,8 @@ class DecodeScheduler:
                 "order": order,
                 "traces": [getattr(r.pending, "trace", None)
                            for r in self._active.values()]}
+            sp.attrs.update(self.decoder.prepare_facts(
+                self._pos[list(self._active)] + n_ahead))
             if held is not None:
                 sp.attrs["held_by"] = held
                 self.held_by[held] += 1

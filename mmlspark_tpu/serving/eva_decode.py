@@ -135,6 +135,8 @@ class EvaByteDecoder:
                 "summary_rows_written":
                     full * self.cfg.summaries_per_window}
 
+    prepare_facts = TransformerDecoder.prepare_facts
+
     def lane(self, sum_pages, win_pages) -> np.ndarray:
         """A slot's page-table row from the pages it holds: the summary
         pages first, then the window's, so that the live rows are a

@@ -113,6 +113,14 @@ class HybridDecoder:
             lat_lanes = latent_pool_lanes(cfg.latent_lanes)
         self.cache = GH.init_cache(cfg, self.n_slots, self.n_pages, ps,
                                    head_lanes=lanes, latent_lanes=lat_lanes)
+        #: table entries the latent kernel copies at a time (None: no
+        #: ``"mla"`` layer, or the dense twin in its place)
+        self._latent_fetch = None
+        if lat_lanes is not None:
+            from mmlspark_tpu.parallel.pallas_mla import latent_fetch_pages
+            self._latent_fetch = latent_fetch_pages(
+                self.pages_per_slot, ps, lat_lanes,
+                self.cache["lat"][0].dtype)
         self._prefill = GH.build_hybrid_prefill(cfg, ps, donate=donate,
                                                 attn_impl=attn_impl)
         self._step = GH.build_hybrid_step(cfg, ps, donate=donate,
@@ -166,6 +174,22 @@ class HybridDecoder:
             facts["latent_rows_expanded"] = (
                 self.prefill_tile * tiles * (tiles + 1) // 2)
         return facts
+
+    def prepare_facts(self, pos) -> Dict[str, int]:
+        """What a ``decode.prepare`` span carries for this kind; where
+        ``paged_latent_decode_attention`` walks the ``"mla"`` layers,
+        ``latent_fetches``, the fetches one layer's walk makes over the
+        live slots at positions ``pos`` (``pos // page_size + 1``
+        entries a slot, ``fetch`` at a time), and
+        ``latent_fetches_full``, those of ``fetch`` entries, whose
+        copies go out as straight-line code."""
+        if self._latent_fetch is None:
+            return {}
+        walk = np.minimum(np.asarray(pos) // self.page_size + 1,
+                          self.pages_per_slot)
+        fetch = self._latent_fetch
+        return {"latent_fetches": int(np.sum(-(-walk // fetch))),
+                "latent_fetches_full": int(np.sum(walk // fetch))}
 
     # -- shapes ----------------------------------------------------------
 

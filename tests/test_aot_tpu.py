@@ -877,9 +877,10 @@ class TestKimiPrograms:
         assert _expert_walks(c) == 5
 
     def test_prefill_tile(self, shapes):
-        """A 512-token tile: the lane expanded a tile of rows at a time
-        inside one loop a layer (no flash kernel), the experts as grouped
-        products, some 150 MB of temporaries."""
+        """A 512-token tile: the lane expanded and scored a tile of rows
+        at a time inside ONE Mosaic call a layer
+        (``latent_tile_attention``; no flash kernel, no XLA loop), the
+        experts as grouped products, some 265 MB of temporaries."""
         GH, cfg, sv, params, cache, i32 = shapes
         pps = sv["max_len"] // sv["page_size"]
         c = GH.build_hybrid_prefill(cfg, sv["page_size"],
@@ -887,5 +888,9 @@ class TestKimiPrograms:
             params, cache, i32(sv["prefill_tile"]), i32(pps), i32(), i32(),
             i32()).compile()
         self._check(c, cache, 512 * 2**20)
+        text = c.as_text()
         assert not _flash_call_names(c)
-        assert "paged_latent_decode_attention" not in c.as_text()
+        assert "paged_latent_decode_attention" not in text
+        assert len(re.findall(r"%latent_tile_attention\S* = .* custom-call\(",
+                              text)) == 6
+        assert " while(" not in text

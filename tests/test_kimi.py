@@ -229,6 +229,31 @@ def test_absorbed_equals_expanded(params):
     assert np.abs(np.asarray(expanded)).max() > 0.1
 
 
+@pytest.mark.parametrize("n_chunks", [1, 2, 4])
+def test_tile_kernel_equals_the_xla_chunks(params, n_chunks):
+    """A tile of 16 queries over its lane: ``latent_tile_attention``
+    (interpret mode) expanding and scoring the chunks in one call gives
+    the XLA chunk loop's output, for the tile at the lane's start, one
+    chunk in, and three; the rows past the tile's own chunk hold NaN
+    and are not read."""
+    rng = np.random.default_rng(6 + n_chunks)
+    bp = params["blocks"][1]
+    u = jnp.asarray(rng.normal(size=(TILE, 256)), jnp.float32)
+    q_pos = (n_chunks - 1) * TILE + jnp.arange(TILE)
+    lane = rng.normal(size=(MAX_LEN, CFG.latent_lanes)).astype(np.float32)
+    lane[n_chunks * TILE:] = np.nan
+    lane = jnp.asarray(lane)
+    xla = GH._mla_attend(
+        bp, u, q_pos,
+        lambda j: jax.lax.dynamic_slice_in_dim(lane, j * TILE, TILE),
+        n_chunks, CFG)
+    kernel = GH._mla_attend_kernel(bp, u, q_pos, lane, jnp.int32(n_chunks),
+                                   CFG, True)
+    assert np.isfinite(np.asarray(kernel)).all()
+    assert np.abs(np.asarray(kernel) - np.asarray(xla)).max() < TOL
+    assert np.abs(np.asarray(xla)).max() > 0.1
+
+
 # ---------------------------------------------------------------------------
 # (c) YaRN and the scale
 
@@ -365,22 +390,54 @@ def test_the_shares_add_up(grouped):
 # (f) the kernel against its dense twin
 
 
-@pytest.mark.parametrize("pos", [[0, 15, 16], [31, 32, 47], [63, 1, 48]],
-                         ids=["free_slot_and_a_page_edge", "a_fetch_edge",
-                              "the_last_row"])
+def _within(seconds, fn):
+    """``fn()`` on a thread of its own, failing after ``seconds``: the
+    TPU interpreter blocks for ever on a wait that no copy signals."""
+    out = []
+
+    def run():
+        try:
+            out.append((True, fn()))
+        except Exception as e:  # noqa: BLE001 — raised below
+            out.append((False, e))
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    assert out, f"no result in {seconds} s: a wait that no copy signals"
+    ok, value = out[0]
+    if not ok:
+        raise value
+    return value
+
+
+@pytest.mark.parametrize("pos", [
+    [0, 15, 16], [31, 32, 47], [63, 1, 48], [15, 31, 63], [16, 32, 48],
+    [7, 0, 3], [15, 23, 47, 4, 31, 50, 0]],
+    ids=["free_slot_and_a_page_edge", "a_fetch_edge", "the_last_row",
+         "walks_end_on_fetch_edges", "one_row_past_a_fetch_edge",
+         "one_page_and_a_free_slot", "last_fetches_full_and_partial_in_turn"])
 def test_latent_kernel_equals_dense_twin(pos):
-    """The walk (interpret mode), 2 pages a fetch of 8 rows, against the
-    dense gather: at page and fetch boundaries, with a free slot on the
-    scratch page, and with NaN in every row past each slot's position
-    and in the unclaimed pages."""
+    """The walk, 2 pages a fetch of 8 rows, against the dense gather:
+    at page and fetch boundaries, with a free slot on the scratch page,
+    and with NaN in every row past each slot's position and in the
+    unclaimed pages. A full fetch's copies go out as straight-line code
+    and are waited for once, a partial one's a page at a time; the
+    last case turns the buffers over with slots whose last fetch is
+    full and partial in turn, so that the next slot's first fetch is
+    started into either buffer, full or not. Run by the TPU
+    interpreter, which makes a copy when it is waited for: a wait that
+    covers fewer bytes than the copies leaves rows of NaN behind, and
+    one on a semaphore that no copy signals never returns."""
+    from jax.experimental.pallas import tpu as pltpu
     rng = np.random.default_rng(7)
-    n, h, lanes, d_lat, ps, pps = 3, 8, 256, 128, 8, 8
+    pos = np.asarray(pos, np.int32)
+    n, h, lanes, d_lat, ps, pps = len(pos), 8, 256, 128, 8, 8
     n_pages = 1 + n * pps
     q = jnp.asarray(rng.normal(size=(n, h, lanes)), jnp.float32)
     pool = np.full((n_pages, ps, lanes), np.nan, np.float32)
     tables = (1 + rng.permutation(n * pps).reshape(n, pps)).astype(np.int32)
-    pos = np.asarray(pos, np.int32)
-    tables[0] = 0 if pos[0] == 0 else tables[0]
+    tables[pos == 0] = 0
     for i in range(n):
         lane = rng.normal(size=(int(pos[i]) + 1, lanes))
         for r in range(int(pos[i]) + 1):
@@ -391,8 +448,9 @@ def test_latent_kernel_equals_dense_twin(pos):
     PM._LATENT_VMEM_BUDGET = 4 * ps * lanes * 4
     try:
         assert PM.latent_fetch_pages(pps, ps, lanes, jnp.float32) == 2
-        got = PM.paged_latent_decode_attention(*args, page_size=ps,
-                                               d_lat=d_lat, interpret=True)
+        got = _within(60, lambda: PM.paged_latent_decode_attention(
+            *args, page_size=ps, d_lat=d_lat,
+            interpret=pltpu.InterpretParams(detect_races=True)))
     finally:
         PM._LATENT_VMEM_BUDGET = old
     clean = np.nan_to_num(pool)
@@ -417,20 +475,39 @@ class _Pending:
         self.trace = rid
 
 
-def test_the_scheduler_serves_it(params):
+@pytest.fixture
+def two_page_fetches(monkeypatch):
+    """The latent walk copies 2 table entries (8 rows) at a time, so
+    that a lane of 64 rows makes full and partial fetches; the kernel is
+    traced afresh under it, and again after it."""
+    monkeypatch.setattr(PM, "_LATENT_VMEM_BUDGET", 4 * PS * PM.LANE * 4)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("impl", ["dense", "pallas_interpret"])
+def test_the_scheduler_serves_it(params, impl, request):
     """``decoder_for`` gives a ``HybridDecoder`` with no state a slot;
     two requests through ``DecodeScheduler`` at once come back with
     tokens that are the reference's greedy choice at every position
     (the served token's reference logit is the best, within ``TOL``),
-    and every page comes back."""
-    from mmlspark_tpu.serving.decode import DecodeScheduler
-    dec = decoder(params)
+    and every page comes back. Where the latent kernel walks the
+    pages, every step's ``decode.prepare`` counts its fetches a layer,
+    and the sum over the steps is the hand count; the dense twin
+    counts none."""
+    from mmlspark_tpu.core.tracing import Tracer
+    from mmlspark_tpu.serving.decode import DecodeScheduler, pass_view
+    if impl == "pallas_interpret":
+        request.getfixturevalue("two_page_fetches")
+    dec = decoder(params, attn_impl=impl)
     assert isinstance(dec, HybridDecoder)
     assert not dec.has_slot_state and not dec.has_prefix_prefill
     assert dec.warmup() == 2
     assert dec.prefill_facts(37) == {"tiles": 3, "prompt_tokens": 37,
                                      "latent_rows_expanded": 96}
-    sched = DecodeScheduler(dec).start()
+    tracer = Tracer()
+    sched = DecodeScheduler(dec, tracer=tracer).start()
     prompts, news = [TOKENS[:21], TOKENS[30:39]], [9, 12]
     pend = [_Pending({"prompt": [int(t) for t in p], "max_new_tokens": n},
                      f"r{i}") for i, (p, n) in enumerate(zip(prompts, news))]
@@ -452,3 +529,17 @@ def test_the_scheduler_serves_it(params):
     assert stats["slots_free"] == N_SLOTS
     assert stats["pages"]["free"] == dec.n_pages - 1
     assert int(dec.expert_routings.sum()) > 0
+    # a request of prompt P and n tokens steps at positions P .. P+n-2,
+    # reading pos // 4 + 1 entries, 2 a fetch: 21..28 read 6,6,6,7,7,7,
+    # 7,8 (29 fetches, 25 full); 9..19 read 3,3,3,4,4,4,4,5,5,5,5 (26,
+    # 19). With a slot free, no step runs ahead.
+    steps = [v for v in (pass_view(sp.attrs["phases"])
+                         for sp in tracer.recorder.scan("decode.pass"))
+             if "dispatch" in v["phases_ms"]]
+    assert len(steps) >= max(news) - 1
+    fetches = [(v.get("latent_fetches"), v.get("latent_fetches_full"))
+               for v in steps]
+    if impl == "dense":
+        assert set(fetches) == {(None, None)}
+    else:
+        assert [sum(f) for f in zip(*fetches)] == [29 + 26, 25 + 19]
